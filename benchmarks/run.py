@@ -35,6 +35,8 @@ def main(argv=None) -> None:
                     help="run only these benchmark modules")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (alexnet_table1, decomposition_fig6,
                             kernel_bench, network_sweep,
                             streaming_bench, throughput_table2)

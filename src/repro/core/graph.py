@@ -504,7 +504,7 @@ def fusible_chains(graph: NetworkGraph, kprogs,
       ``plan_buffers`` frees on) all sit inside the chain, so nothing
       the arena holds is ever needed in HBM; the greedy walk backtracks
       to the longest prefix with that property before emitting;
-    * **budget** — ``chain_vmem_bytes`` of the grown chain (activation
+    * **budget** — ``chain_plan_bytes`` of the grown chain (activation
       arena + shared accumulator + per-step windows) stays under
       ``vmem_budget`` (default ``DEFAULT_VMEM_BUDGET``).
 
@@ -526,7 +526,7 @@ def fusible_chains(graph: NetworkGraph, kprogs,
     (``streaming._chain_batch_block``).
     """
     from repro.core.schedule import (DEFAULT_VMEM_BUDGET, ChainNodeSpec,
-                                     chain_vmem_bytes)
+                                     chain_plan_bytes)
     budget = DEFAULT_VMEM_BUDGET if vmem_budget is None else vmem_budget
     if only is None:
         kprogs = conv_keyed(graph, kprogs, "kernel programs")
@@ -567,7 +567,7 @@ def fusible_chains(graph: NetworkGraph, kprogs,
             if s.residual_value is not None \
                     and s.residual_value not in values:
                 break
-            if chain_vmem_bytes(cur + [s], quantized,
+            if chain_plan_bytes(cur + [s], quantized,
                                 batch_block=batch_block) > budget:
                 break
             cur.append(s)

@@ -163,11 +163,29 @@ def requantize_i32(acc: jax.Array, m: jax.Array, shift: jax.Array,
     Deterministic integer ops only, shared verbatim by the Pallas kernel
     epilogue and the int32 reference model (bit-exact by construction).
     """
+    return requantize_clip(acc, m, shift, pre_shift,
+                           relu).astype(jnp.int8)
+
+
+def requantize_clip(acc: jax.Array, m: jax.Array, shift: jax.Array,
+                    pre_shift: int = 0, relu: bool = False) -> jax.Array:
+    """``requantize_i32`` before the final int8 cast: the clipped
+    values, still int32 — what kernel epilogues keep in their int32
+    scratch until the pooled tile is written."""
     v = rounding_rshift(acc, pre_shift) if pre_shift else acc
     v = v * m.astype(jnp.int32)
     v = rounding_rshift(v, jnp.asarray(shift, jnp.int32) - pre_shift)
     lo = 0 if relu else -INT8_QMAX
-    return jnp.clip(v, lo, INT8_QMAX).astype(jnp.int8)
+    return jnp.clip(v, lo, INT8_QMAX)
+
+
+def residual_add_clip(q: jax.Array, r: jax.Array, relu: bool) -> jax.Array:
+    """The int8 accumulation-buffer add before its int8 cast: int32 sum
+    of two same-scale operands, ReLU folded into the clip
+    (``wave_replay_q.kernel.residual_add_i8`` casts the result)."""
+    s = q.astype(jnp.int32) + r.astype(jnp.int32)
+    lo = 0 if relu else -INT8_QMAX
+    return jnp.clip(s, lo, INT8_QMAX)
 
 
 def requant_params(scale_ratio, acc_bound: int, bits_m: int = 7):

@@ -358,9 +358,11 @@ def compile_layer_waves(layer: ConvLayer, plan: Plan) -> WaveProgram:
 KERNEL_OP_COLS = 8
 (OP_IY, OP_IX, OP_TY, OP_TX, OP_C0, OP_WC0, OP_VR, OP_VC) = range(8)
 
-# Default VMEM budget for chain coarsening and megakernel re-planning:
-# half a TPU core's ~16 MB VMEM, leaving room for double-buffered
-# windows and the output block.
+# Default VMEM budget for chain coarsening and megakernel re-planning,
+# in element bytes as the planner counts them (``plan_bytes``). It is a
+# planning choice, not a device size: each launch states its own
+# scoped-VMEM limit from what it holds at the tiled layout
+# (``vmem_bytes``).
 DEFAULT_VMEM_BUDGET = 8 * 2 ** 20
 
 
@@ -466,10 +468,20 @@ class KernelProgram:
 
     @property
     def vmem_bytes(self) -> int:
-        """Per-grid-step fp32 working set: ``batch_block`` images'
-        accumulators + input-window chunks (+ residual blocks when the
-        epilogue adds them) plus the batch-shared weight chunk — what
-        ``vmem_budget`` bounds."""
+        """VMEM the fp32 launch holds, every buffer at its tiled layout
+        (``kernels/common.py::megakernel_vmem``, which also sets the
+        launch's scoped-VMEM limit)."""
+        from repro.kernels.common import megakernel_vmem
+        return megakernel_vmem(self).bytes
+
+    @property
+    def plan_bytes(self) -> int:
+        """The planner's per-grid-step working-set model, in element
+        bytes: ``batch_block`` images' accumulators + input-window
+        chunks (+ residual blocks when the epilogue adds them) plus the
+        batch-shared weight chunk — what ``vmem_budget`` bounds. It
+        leaves out the lane padding and double buffering that
+        ``vmem_bytes`` counts."""
         l = self.wave.program.layer
         return 4 * (self.batch_block
                     * (self.acc_h * self.acc_w * self.out_c_pad
@@ -666,7 +678,7 @@ def validate_kernel_program(kp: KernelProgram) -> None:
        (``n_chain * chain_chunk >= n_waves``, no overlap).
     2. Every input window, channel chunk, and weight slice lies inside
        the padded buffers — a stale offset would make the kernel's
-       unblocked DMA read out of bounds.
+       element-indexed DMA read out of bounds.
     3. Output block indices raster-tile the padded output exactly once
        per chain step, and the write masks cover the valid output
        exactly: per tile column the VR masks sum to out_h, per row VC
@@ -746,8 +758,8 @@ def compile_network_waves(layers: Sequence[ConvLayer],
 # cross-layer steering the fused kernel needs — one FLAT row per
 # (node, tile, chain step), int32, prefetched to SMEM:
 #   NODE, K      which chain node this step belongs to + its chain pos
-#   WOFF, BOFF   base offsets of this step's slice of the flat weight /
-#                bias (and requant) buffers
+#   WOFF, BOFF   rows of this step's weights / this node's bias (and
+#                requant) vectors in the stacked per-step buffers
 #   OY, OX       output block index for the kernel OUTPUT operand —
 #                (ty, tx) on the final node's rows, pinned to (0, 0)
 #                elsewhere so non-final steps touch one fixed block
@@ -926,22 +938,19 @@ def _chain_layout(specs: Sequence[ChainNodeSpec], quantized: bool):
         vals.append(_extent(s.out_value, i))
     arena = plan_arena(vals)
 
+    # the weights stack one row per (node, chain step) and the bias /
+    # requant vectors one row per node: WOFF/BOFF are row indices, so
+    # every per-step fetch is one whole row of a stacked buffer
     w_chunks = tuple(_graph_weight_chunk(s.kp, quantized) for s in specs)
     w_offsets, off = [], 0
-    for s, ch in zip(specs, w_chunks):
-        w_offsets.append(off)
-        off += s.kp.n_chain * ch
-    w_max = max(w_chunks)
-    # every WOFF window must fit: the last step of node i reads
-    # [off_i + (n_chain-1)*chunk_i, ... + w_max)
-    w_total = max(o + (s.kp.n_chain - 1) * ch + w_max
-                  for o, s, ch in zip(w_offsets, specs, w_chunks))
-    b_offsets, boff = [], 0
     for s in specs:
-        b_offsets.append(boff)
-        boff += s.kp.out_c_pad
+        w_offsets.append(off)
+        off += s.kp.n_chain
+    w_max = max(w_chunks)
+    w_total = off
+    b_offsets = tuple(range(len(specs)))
     b_max = max(s.kp.out_c_pad for s in specs)
-    b_total = b_offsets[-1] + b_max
+    b_total = len(specs)
 
     steps, lo = [], 0
     for s in specs:
@@ -969,10 +978,10 @@ class GraphKernelProgram:
     layout pad, and consumers window it back out — residual operands
     included, replacing the per-layer path's pad_residual round-trip.
 
-    Weights/bias/requant vectors for the whole chain ride in flat 1-D
-    operands; each grid step DMAs only its own slice (a ``w_max``-sized
-    window at the table's WOFF/BOFF), so per-step VMEM stays bounded by
-    the largest single step, not the whole chain.
+    Weights/bias/requant vectors for the whole chain ride in stacked
+    operands, one row per (node, chain step) / per node; each grid step
+    DMAs only its own row (the table's WOFF/BOFF), so per-step VMEM
+    stays bounded by the largest single step, not the whole chain.
     """
     nodes: Tuple[ChainNodeSpec, ...]
     input_value: str
@@ -982,12 +991,12 @@ class GraphKernelProgram:
     node_steps: Tuple[int, ...]         # first flat step of each node
     total_steps: int
     w_chunks: Tuple[int, ...]           # per-step weight elems, per node
-    w_offsets: Tuple[int, ...]
-    w_max: int
-    w_total: int
-    b_offsets: Tuple[int, ...]
-    b_max: int
-    b_total: int
+    w_offsets: Tuple[int, ...]          # first weight row of each node
+    w_max: int                          # elems of the largest weight row
+    w_total: int                        # weight rows (node, chain step)
+    b_offsets: Tuple[int, ...]          # bias row of each node
+    b_max: int                          # widest bias row
+    b_total: int                        # bias rows (one per node)
     table: Tuple[Tuple[int, ...], ...]
     # images per grid step (ISSUE 8): the fused kernel's grid becomes
     # (batch-block, flat step) — each batch block replays the whole
@@ -1017,11 +1026,19 @@ class GraphKernelProgram:
 
     @property
     def vmem_bytes(self) -> int:
-        """Per-step fp32 working-set model: arena slots, shared
-        accumulator, input window and output block scale per image
-        (``batch_block``); the flat weight/bias windows are
-        batch-shared. Deliberately precision-independent (4 B/elem)
-        so fp32 and int8 partition a graph identically."""
+        """VMEM the launch holds, every buffer at its tiled layout
+        (``kernels/wave_replay/graph.py::graph_kernel_vmem``, which also
+        sets the launch's scoped-VMEM limit)."""
+        from repro.kernels.wave_replay.graph import graph_kernel_vmem
+        return graph_kernel_vmem(self).bytes
+
+    @property
+    def plan_bytes(self) -> int:
+        """The partitioner's per-step working-set model, in element
+        bytes: arena slots, shared accumulator, input window and output
+        block scale per image (``batch_block``); the flat weight/bias
+        windows are batch-shared. Deliberately precision-independent
+        (4 B/elem) so fp32 and int8 partition a graph identically."""
         h0 = self.nodes[0].kp
         x_elems = (h0.pad_h * h0.pad_w * h0.in_c_kpad
                    if self.input_in_arena
@@ -1056,7 +1073,7 @@ class GraphKernelProgram:
                 f"table {self.total_steps}x{GRAPH_OP_COLS} SMEM")
 
 
-def chain_vmem_bytes(specs: Sequence[ChainNodeSpec],
+def chain_plan_bytes(specs: Sequence[ChainNodeSpec],
                      quantized: bool = False,
                      batch_block: int = 1) -> int:
     """Working-set estimate of a (possibly still-growing) chain.
@@ -1065,7 +1082,7 @@ def chain_vmem_bytes(specs: Sequence[ChainNodeSpec],
     still leak to later nodes, so it skips ``lower_graph_kernel``'s
     strict consumption checks but shares its exact layout math.
     ``batch_block`` scales the per-image terms (arena, accumulator,
-    input window, output block) like ``GraphKernelProgram.vmem_bytes``.
+    input window, output block) like ``GraphKernelProgram.plan_bytes``.
     """
     (_, input_in_arena, arena, _, _, w_max, _, _, b_max, _, _, _) = \
         _chain_layout(specs, quantized)
@@ -1155,7 +1172,7 @@ def lower_graph_kernel(specs: Sequence[ChainNodeSpec], *,
                 sy, sx, sc0 = (iy, ix, c0) if windowed_head else (0, 0, 0)
                 oy, ox = (ty, tx) if ni == last else (0, 0)
                 rows.append((sy, sx, ty, tx, sc0, 0, vr, vc,
-                             ni, k, w_offsets[ni] + k * w_chunks[ni],
+                             ni, k, w_offsets[ni] + k,
                              b_offsets[ni], oy, ox))
 
     gkp = GraphKernelProgram(
@@ -1180,8 +1197,8 @@ def validate_graph_kernel(gkp: GraphKernelProgram) -> None:
        (previous occupant dies strictly before the next is born) and
        every slot is at least as large as each value assigned to it;
        reader/producer extents fit inside the slot.
-    3. Flat-buffer offsets keep every WOFF/BOFF fetch window inside the
-       padded buffers.
+    3. WOFF/BOFF name each step's own row of the stacked weight and
+       bias buffers.
     4. Output steering: final-node rows raster-tile the output, all
        other rows pin the output block to (0, 0).
     """
@@ -1218,14 +1235,14 @@ def validate_graph_kernel(gkp: GraphKernelProgram) -> None:
                     raise LoweringError(
                         f"{s.name} row {r}: output steering "
                         f"({row[GOP_OY]}, {row[GOP_OX]}) != {want_oyx}")
-                if row[GOP_WOFF] + gkp.w_max > gkp.w_total:
+                if row[GOP_WOFF] != gkp.w_offsets[ni] + k:
                     raise LoweringError(
-                        f"{s.name} row {r}: weight window "
-                        f"{row[GOP_WOFF]}+{gkp.w_max} > {gkp.w_total}")
-                if row[GOP_BOFF] + gkp.b_max > gkp.b_total:
+                        f"{s.name} row {r}: weight row {row[GOP_WOFF]} "
+                        f"!= {gkp.w_offsets[ni] + k} of {gkp.w_total}")
+                if row[GOP_BOFF] != gkp.b_offsets[ni]:
                     raise LoweringError(
-                        f"{s.name} row {r}: bias window "
-                        f"{row[GOP_BOFF]}+{gkp.b_max} > {gkp.b_total}")
+                        f"{s.name} row {r}: bias row {row[GOP_BOFF]} "
+                        f"!= {gkp.b_offsets[ni]} of {gkp.b_total}")
                 r += 1
     occupants: dict = {}
     for v, si in zip(gkp.arena.values, gkp.arena.slots):

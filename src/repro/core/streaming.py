@@ -87,12 +87,15 @@ from repro.runtime.errors import PlanError
 
 def conv2d_direct(x: jax.Array, w: jax.Array, stride: int = 1,
                   pad: int = 0, groups: int = 1) -> jax.Array:
-    """x (B,H,W,Cin), w (K,K,Cin/groups,Cout) -> (B,Ho,Wo,Cout)."""
+    """x (B,H,W,Cin), w (K,K,Cin/groups,Cout) -> (B,Ho,Wo,Cout).
+
+    HIGHEST precision: on a TPU the default fp32 conv is one bf16 pass.
+    """
     return lax.conv_general_dilated(
         x, w, window_strides=(stride, stride),
         padding=[(pad, pad), (pad, pad)],
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        feature_group_count=groups)
+        feature_group_count=groups, precision=lax.Precision.HIGHEST)
 
 
 def maxpool_direct(x: jax.Array, window: int, stride: int = 0) -> jax.Array:
@@ -919,15 +922,15 @@ def _chain_batch_block(specs, quantized: bool,
                        vmem_budget: Optional[int], batch: int) -> int:
     """Largest images-per-step block whose whole-chain VMEM footprint
     (arena slots + accumulator + input/output blocks, all per-image)
-    fits ``vmem_budget``. ``chain_vmem_bytes`` is affine in the block
+    fits ``vmem_budget``. ``chain_plan_bytes`` is affine in the block
     size — weights and bias are batch-shared — so the bound solves in
     two evaluations. ``None`` budget takes the full batch."""
     bb = max(1, int(batch))
     if vmem_budget is None or bb == 1:
         return bb
-    from repro.core.schedule import chain_vmem_bytes
-    b1 = chain_vmem_bytes(specs, quantized=quantized, batch_block=1)
-    per = chain_vmem_bytes(specs, quantized=quantized, batch_block=2) - b1
+    from repro.core.schedule import chain_plan_bytes
+    b1 = chain_plan_bytes(specs, quantized=quantized, batch_block=1)
+    per = chain_plan_bytes(specs, quantized=quantized, batch_block=2) - b1
     if per <= 0:
         return bb
     fit = (vmem_budget - (b1 - per)) // per
@@ -1399,7 +1402,7 @@ def plan_for_vmem(layer: ConvLayer,
     not the paper's 128 KB SRAM — so the kernel replays the schedule the
     planner produces *for its own budget point*: the fewest (tile x
     chain) grid steps whose fp32 working set (``KernelProgram.
-    vmem_bytes``) fits, ties broken toward the smaller working set.
+    plan_bytes``) fits, ties broken toward the smaller working set.
     Feature splits stay at 1 — the kernel folds the feature axis into
     its matmul width. When nothing fits the budget (working sets shrink
     with more tiles/splits only down to the halo/weight floor), the
@@ -1431,7 +1434,7 @@ def plan_for_vmem(layer: ConvLayer,
                     relu=True, fuse_pool=fuse_pool, residual=residual,
                     vmem_budget=None if batch == 1 else vmem_budget,
                     batch_block=batch)
-                ws = kp.vmem_bytes
+                ws = kp.plan_bytes
                 n_bb = -(-batch // kp.batch_block)
                 key = (ws > vmem_budget,
                        n_bb * kp.n_tiles * kp.n_chain, ws)

@@ -2,7 +2,7 @@
 buffer, TPU-native (DESIGN.md §2).
 
 Dataflow mapping:
-  * row-block streaming with an unblocked-indexing halo window <- 2xN row
+  * row-block streaming with an element-indexed halo window <- 2xN row
     buffer
     (each grid step's input block carries its own K-stride halo rows, so
     the convolution never stalls at block boundaries — paper §3)
@@ -24,6 +24,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.common import element_block
 
 
 def _conv_kernel(x_ref, w_ref, o_ref, *, K: int, stride: int, R: int,
@@ -98,12 +100,11 @@ def conv2d_stream_raw(x: jax.Array, w: jax.Array, *, stride: int = 1,
                                        jnp.float32),
         grid=(B, n_rb, n_co, n_ci),
         in_specs=[
-            # halo-overlapping row windows need element (unblocked)
+            # halo-overlapping row windows need element (pl.Element)
             # indexing: offsets are in elements for every dim
-            pl.BlockSpec((1, R_in, W_pad, ci_b),
+            pl.BlockSpec(element_block((1, R_in, W_pad, ci_b)),
                          lambda b, r, co, ci: (b, r * R * stride, 0,
-                                               ci * ci_b),
-                         indexing_mode=pl.unblocked),
+                                               ci * ci_b)),
             pl.BlockSpec((K, K, ci_b, co_b),
                          lambda b, r, co, ci: (0, 0, ci, co)),
         ],

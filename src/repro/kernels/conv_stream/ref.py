@@ -13,4 +13,5 @@ def conv2d_ref(x: jax.Array, w: jax.Array, *, stride: int = 1,
         x.astype(jnp.float32), w.astype(jnp.float32),
         window_strides=(stride, stride),
         padding=[(pad, pad), (pad, pad)],
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=lax.Precision.HIGHEST)

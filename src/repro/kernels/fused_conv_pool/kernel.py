@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import pool_max_subsampled
+from repro.kernels.common import element_block, pool_max_subsampled
 
 
 def _kernel(x_ref, w_ref, o_ref, acc_ref, *, K: int, stride: int, R: int,
@@ -109,10 +109,9 @@ def fused_conv_pool_raw(x: jax.Array, w: jax.Array, *, stride: int = 1,
             (B, n_rb * RP, Wp_out, n_co * co_b), jnp.float32),
         grid=(B, n_rb, n_co, n_ci),
         in_specs=[
-            pl.BlockSpec((1, R_in, W_need, ci_b),
+            pl.BlockSpec(element_block((1, R_in, W_need, ci_b)),
                          lambda b, r, co, ci: (b, r * RP * ps * stride, 0,
-                                               ci * ci_b),
-                         indexing_mode=pl.unblocked),
+                                               ci * ci_b)),
             pl.BlockSpec((K, K, ci_b, co_b),
                          lambda b, r, co, ci: (0, 0, ci, co)),
         ],

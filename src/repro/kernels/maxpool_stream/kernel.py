@@ -3,7 +3,7 @@
 The paper's pooling module: a comparator + feedback register scanning the
 pool window as rows stream past, reconfigurable to kernel 2 or 3 with
 stride down to kernel-1 (AlexNet's overlapping 3/2). Row blocks stream
-through VMEM with an unblocked-indexing halo of (pool - stride) rows —
+through VMEM with an element-indexed halo of (pool - stride) rows —
 the scratchpad's buffered intermediate rows.
 """
 from __future__ import annotations
@@ -13,6 +13,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.common import element_block
 
 NEG = -3.0e38
 
@@ -52,9 +54,8 @@ def maxpool_stream_raw(x: jax.Array, *, pool: int, stride: int = 0,
         kern,
         out_shape=jax.ShapeDtypeStruct((B, n_rb * R, W_out, C), x.dtype),
         grid=(B, n_rb),
-        in_specs=[pl.BlockSpec((1, R_in, W_pad, C),
-                               lambda b, r: (b, r * R * ps, 0, 0),
-                               indexing_mode=pl.unblocked)],
+        in_specs=[pl.BlockSpec(element_block((1, R_in, W_pad, C)),
+                               lambda b, r: (b, r * R * ps, 0, 0))],
         out_specs=pl.BlockSpec((1, R, W_out, C), lambda b, r: (b, r, 0, 0)),
         interpret=interpret,
     )(x)

@@ -11,7 +11,7 @@ into an HBM-resident buffer.
 Control path: a static int32 operand table (``KernelProgram.table``,
 core/schedule.py) is scalar-prefetched to SMEM — the §3 command decoder
 stream. BlockSpec index maps read it to steer every DMA: the
-halo-inclusive input window origin (unblocked element offsets, so
+halo-inclusive input window origin (element offsets, so
 overlapping halos are *indexed*, never materialised as fresh copies the
 way the wave executor's vmapped gather stacks them), the wave's
 channel-group offsets into input/weights, and the output block index.
@@ -31,40 +31,43 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.schedule import (KERNEL_OP_COLS, OP_C0, OP_IX, OP_IY,
-                                 OP_TX, OP_TY, OP_VC, OP_VR, OP_WC0,
-                                 KernelProgram, batch_grid)
-from repro.kernels.common import pool_max_subsampled
+from repro.core.schedule import (KERNEL_OP_COLS, OP_IY, OP_TX, OP_TY,
+                                 OP_VC, OP_VR, KernelProgram, batch_grid)
+from repro.kernels.common import (at_tile_col, conv_rows, element_block,
+                                  lane_load, mask_tile, megakernel_geometry,
+                                  megakernel_vmem, pool_tile,
+                                  space_to_depth, space_to_depth_weights,
+                                  stage_lanes, strided)
 
 
 def _replay_kernel(tbl_ref, x_ref, w_ref, b_ref, *refs,
-                   K: int, stride: int, acc_h: int, acc_w: int,
-                   n_waves: int, pool: int, ps: int,
-                   blk_h: int, blk_w: int, relu: bool, fuse_pool: bool,
-                   residual: bool, groups: int):
+                   K: int, stride: int, col_step: int, acc_h: int,
+                   acc_w: int, n_waves: int, pool: int, ps: int,
+                   blk_h: int, blk_w: int, tiles_w: int, relu: bool,
+                   fuse_pool: bool, residual: bool, groups: int,
+                   staged: bool):
     """One grid step: batch block (program_id 0), tile t (id 1), chain
     position k (id 2). The batch axis is outermost, so each batch
     block's tiles replay their full partial-sum chains before the next
     block starts — the scratch accumulator is recycled across blocks.
 
-    With ``residual`` the positional refs gain one operand —
-    ``(r_ref, o_ref, acc_ref)`` instead of ``(o_ref, acc_ref)`` — the
-    residual activation block of this tile (same geometry as the output
-    block), added to the accumulator after bias, before ReLU: the
-    paper's accumulation-SRAM add (ISSUE 5).
+    ``refs`` are ``[r_ref] o_ref acc_ref [pool_ref] [xs_ref]``: with
+    ``residual`` the residual activation rows of this tile, added to
+    the accumulator after bias, before ReLU (the paper's
+    accumulation-SRAM add); with ``fuse_pool`` the lane-tiled pool
+    scratch; with ``staged`` (strided taps) the lane-tiled copy of one
+    image's window that strided loads read.
 
-    ``groups`` picks the compute body (ISSUE 10): 1 runs one dense MXU
-    matmul over the full fan; grouped layers keep their natural
-    ``(K, K, in_c/groups, out_c)`` weights — depthwise
-    (``in_c/groups == 1``) runs a K*K-tap VPU multiply-accumulate over
-    shifted input slices, other group counts run one gemm per group
-    over that group's fan slice. No block-diagonal zeros are ever
-    materialised.
+    The window ``x_ref`` spans the full buffer width; tile column ``j``
+    starts its taps at the static column ``j * col_step``.
+    ``conv_rows`` runs the grouped / depthwise / dense body
+    (kernels/common.py).
     """
-    if residual:
-        r_ref, o_ref, acc_ref = refs
-    else:
-        (o_ref, acc_ref), r_ref = refs, None
+    refs = list(refs)
+    r_ref = refs.pop(0) if residual else None
+    o_ref, acc_ref = refs.pop(0), refs.pop(0)
+    pool_ref = refs.pop(0) if fuse_pool else None
+    xs_ref = refs.pop(0) if staged else None
     t = pl.program_id(1)
     k = pl.program_id(2)
 
@@ -72,80 +75,56 @@ def _replay_kernel(tbl_ref, x_ref, w_ref, b_ref, *refs,
     def _init():                      # chain start: zero the psum bank
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]                    # (B, ih, iw, c_width) halo-inclusive
-    B, cin = x.shape[0], x.shape[-1]
-    fan = w_ref.shape[2]              # in_c // groups (== cin if dense)
-    out_c = w_ref.shape[3]
+    bb, cin = x_ref.shape[0], x_ref.shape[-1]
+    out_c = acc_ref.shape[-1]
 
-    def tap(ky, kx, c0=0, cw=None):
-        cw = cin if cw is None else cw
-        return jax.lax.slice(
-            x, (0, ky, kx, c0),
-            (B, ky + (acc_h - 1) * stride + 1,
-             kx + (acc_w - 1) * stride + 1, c0 + cw),
-            (1, stride, stride, 1))
+    def conv_image(col0, b, carry):
+        if staged:
+            stage_lanes(xs_ref, x_ref[b])
 
-    def im2col(c0, cw):
-        # flat fan order (ky, kx, c) — matches the weight reshape below
-        taps = [tap(ky, kx, c0, cw)
-                for ky in range(K) for kx in range(K)]
-        return jnp.concatenate(taps, -1).reshape(
-            B * acc_h * acc_w, K * K * cw)
+        def load(r, kx, c0, cw):
+            cols = strided(col0 + kx, acc_w, stride)
+            if staged:
+                return lane_load(xs_ref, (), r, cols, c0, cw)
+            return x_ref[b, r, cols, c0:c0 + cw]
 
-    if groups > 1 and fan == 1:
-        # depthwise: out channel o reads in channel o // opg — a pure
-        # elementwise MAC over the K*K shifted taps, no gemm at all
-        # (unrolling `groups` 1-wide gemms would be catastrophic here)
-        opg = out_c // groups
-        contrib = jnp.zeros((B, acc_h, acc_w, out_c), jnp.float32)
-        for ky in range(K):
-            for kx in range(K):
-                xt = tap(ky, kx)
-                if opg > 1:           # channel-multiplier fan-out
-                    xt = jnp.repeat(xt, opg, axis=-1)
-                contrib += xt * w_ref[ky, kx, 0, :]
-        acc_ref[...] += contrib
-    else:
-        if groups == 1:
-            w = w_ref[...].reshape(K * K * cin, out_c)
-            acc = jax.lax.dot_general(
-                im2col(0, cin), w, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        else:
-            # per-group gemms over the natural fan, each group's im2col
-            # built straight from its own x channel slice (slicing one
-            # shared patch matrix per group would copy the whole thing
-            # again) — the layer costs the true K*K*(Cin/g)*Cout flops
-            opg = out_c // groups
-            outs = []
-            for gi in range(groups):
-                wg = w_ref[:, :, :, gi * opg:(gi + 1) * opg].reshape(
-                    K * K * fan, opg)
-                outs.append(jax.lax.dot_general(
-                    im2col(gi * fan, fan), wg, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32))
-            acc = jnp.concatenate(outs, -1)
-        acc_ref[...] += acc.reshape(B, acc_h, acc_w, out_c)
+        def wtap(ky, kx, c0, cw, o0, ow):
+            return w_ref[ky, kx, c0:c0 + cw, o0:o0 + ow]
+
+        conv_rows(acc_ref, b, load, wtap, K=K, stride=stride,
+                  acc_h=acc_h, acc_w=acc_w, cin=cin, out_c=out_c,
+                  groups=groups)
+        return carry
+
+    def conv_col(j):
+        jax.lax.fori_loop(
+            0, bb, functools.partial(conv_image, j * col_step), 0)
+
+    at_tile_col(tbl_ref[k, t, OP_TX], tiles_w, conv_col)
 
     @pl.when(k == n_waves - 1)
     def _epilogue():                  # chain end: finish in VMEM, write once
-        a = acc_ref[...] + b_ref[0]
-        if residual:                  # accumulation-buffer add, pre-ReLU
-            a = a + r_ref[...]
-        if relu:
-            a = jnp.maximum(a, 0.0)
-        if fuse_pool:
-            # overlapping pools (ps < pool) re-derive their overlap
-            # rows in-block; shared with fused_conv_pool
-            a = pool_max_subsampled(a, pool=pool, stride=ps,
-                                    out_h=blk_h, out_w=blk_w)
-        # masked write: zero the uniform-grid padding lanes so the padded
-        # output is deterministic (VR/VC columns of the operand table)
-        rows = jax.lax.broadcasted_iota(jnp.int32, (blk_h, blk_w), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (blk_h, blk_w), 1)
-        mask = ((rows < tbl_ref[k, t, OP_VR])
-                & (cols < tbl_ref[k, t, OP_VC]))[None, :, :, None]
-        o_ref[...] = jnp.where(mask, a, 0.0)
+        vr, vc = tbl_ref[k, t, OP_VR], tbl_ref[k, t, OP_VC]
+
+        def finish(j, b, carry):
+            cols = slice(j * blk_w, (j + 1) * blk_w)
+            a = acc_ref[b] + b_ref[...]
+            if residual:              # accumulation-buffer add, pre-ReLU
+                a = a + r_ref[b, :, cols, :]
+            if relu:
+                a = jnp.maximum(a, 0.0)
+            if fuse_pool:
+                # overlapping pools (ps < pool) re-derive their overlap
+                # rows in-block, as strided loads of the parked tile
+                a = pool_tile(pool_ref, a, pool=pool, ps=ps,
+                              blk_h=blk_h, blk_w=blk_w)
+            # masked write: zero the uniform-grid padding lanes so the
+            # padded output is deterministic (VR/VC table columns)
+            o_ref[b, :, cols, :] = mask_tile(a, vr, vc)
+            return carry
+
+        at_tile_col(tbl_ref[k, t, OP_TX], tiles_w, lambda j: jax.lax.fori_loop(
+            0, bb, functools.partial(finish, j), 0))
 
 
 def wave_replay_raw(kp: KernelProgram, x: jax.Array, w: jax.Array,
@@ -160,7 +139,7 @@ def wave_replay_raw(kp: KernelProgram, x: jax.Array, w: jax.Array,
     (n_waves, n_tiles, 8) int32 operand table. Programs lowered with
     ``residual=True`` additionally take the residual activation at the
     padded output geometry (B, out_h_pad, out_w_pad, out_c_pad) fp32 —
-    each tile's block is DMA'd alongside the output block and added in
+    each tile's rows are DMA'd alongside the output rows and added in
     the epilogue. The batch axis rides the grid in blocks of
     ``kp.batch_block`` images (outermost axis); ragged batches are
     zero-padded to whole blocks here and cropped on return (zero
@@ -207,51 +186,57 @@ def wave_replay_raw(kp: KernelProgram, x: jax.Array, w: jax.Array,
         if kp.residual:
             residual = jnp.pad(
                 residual, ((0, n_bb * bb - B), (0, 0), (0, 0), (0, 0)))
+    # narrow strided layers fold the stride into channels (conv1:
+    # 227x227x3 -> 57x57x48), turning strided taps into plain ones
+    s, k_eff, stride, ih, full_w, c_eff, f_eff = megakernel_geometry(kp)
+    x = space_to_depth(x, s)
+    w = space_to_depth_weights(w, s)
+    vmem = megakernel_vmem(kp, bb=bb)
     in_specs = [
-        # halo windows via table-driven unblocked element offsets:
-        # overlap is indexed in place, never copied out
-        pl.BlockSpec((bb, kp.ih, kp.iw, kp.c_width),
-                     lambda bi, t, k, tbl: (bi * bb, tbl[k, t, OP_IY],
-                                            tbl[k, t, OP_IX],
-                                            tbl[k, t, OP_C0]),
-                     indexing_mode=pl.unblocked),
-        pl.BlockSpec((l.kernel, l.kernel, kp.fan_width, kp.out_c_pad),
-                     lambda bi, t, k, tbl: (0, 0, tbl[k, t, OP_WC0], 0),
-                     indexing_mode=pl.unblocked),
+        # halo windows: table-driven element offsets along the rows
+        # (overlap is indexed in place, never copied out); each window
+        # spans the full width, the tile's columns offset the taps
+        pl.BlockSpec(element_block((bb, ih, full_w, c_eff)),
+                     lambda bi, t, k, tbl: (
+                         bi * bb, tbl[k, t, OP_IY] // s, 0,
+                         k * c_eff if kp.n_chain > 1 else 0)),
+        pl.BlockSpec((k_eff, k_eff, f_eff, kp.out_c_pad),
+                     lambda bi, t, k, tbl: (0, 0, k, 0)),
         pl.BlockSpec((1, kp.out_c_pad), lambda bi, t, k, tbl: (0, 0)),
     ]
     operands = [table, x, w, b]
+    # output (and residual) blocks hold a tile row across every tile
+    # column; consecutive tiles of one row revisit the same block
+    out_block = (bb, kp.blk_h, kp.out_w_pad, kp.out_c_pad)
     if kp.residual:
-        # the residual reads the same blocked tiling the output writes
         in_specs.append(pl.BlockSpec(
-            (bb, kp.blk_h, kp.blk_w, kp.out_c_pad),
-            lambda bi, t, k, tbl: (bi, tbl[k, t, OP_TY],
-                                   tbl[k, t, OP_TX], 0)))
+            out_block, lambda bi, t, k, tbl: (bi, tbl[k, t, OP_TY], 0, 0)))
         operands.append(residual)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,        # the SMEM operand table
         grid=(n_bb, kp.n_tiles, kp.n_chain),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (bb, kp.blk_h, kp.blk_w, kp.out_c_pad),
-            lambda bi, t, k, tbl: (bi, tbl[k, t, OP_TY],
-                                   tbl[k, t, OP_TX], 0)),
-        # the psum SRAM bank: one tile's chain lives here, never in HBM
-        scratch_shapes=[pltpu.VMEM((bb, kp.acc_h, kp.acc_w, kp.out_c_pad),
-                                   jnp.float32)],
+            out_block, lambda bi, t, k, tbl: (bi, tbl[k, t, OP_TY], 0, 0)),
+        # the psum SRAM bank (one tile's chain lives here, never in
+        # HBM), then the lane-tiled pool and strided-window staging
+        scratch_shapes=[pltpu.VMEM(sh, dt) for sh, dt in vmem.scratch],
     )
     kern = functools.partial(
-        _replay_kernel, K=l.kernel, stride=l.stride,
+        _replay_kernel, K=k_eff, stride=stride,
+        col_step=kp.blk_w * kp.pool_stride * stride,
         acc_h=kp.acc_h, acc_w=kp.acc_w,
         n_waves=kp.n_chain, pool=kp.pool, ps=kp.pool_stride,
-        blk_h=kp.blk_h, blk_w=kp.blk_w, relu=kp.relu,
-        fuse_pool=kp.fuse_pool, residual=kp.residual, groups=kp.groups)
+        blk_h=kp.blk_h, blk_w=kp.blk_w, tiles_w=kp.tiles_w, relu=kp.relu,
+        fuse_pool=kp.fuse_pool, residual=kp.residual, groups=kp.groups,
+        staged=stride > 1)
     y = pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct(
             (n_bb * bb, kp.out_h_pad, kp.out_w_pad, kp.out_c_pad),
             jnp.float32),
         grid_spec=grid_spec,
+        compiler_params=vmem.compiler_params(l.name, interpret),
         interpret=interpret,
     )(*operands)
     return y[:B] if n_bb * bb != B else y

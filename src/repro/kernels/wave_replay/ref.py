@@ -13,7 +13,7 @@ def wave_replay_ref(layer, x, w, b=None, *, relu: bool = False,
         window_strides=(l.stride, l.stride),
         padding=[(l.pad, l.pad), (l.pad, l.pad)],
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        feature_group_count=l.groups)
+        feature_group_count=l.groups, precision=lax.Precision.HIGHEST)
     if b is not None:
         y = y + b.astype(jnp.float32)
     if residual is not None:          # accumulation-buffer add, pre-ReLU

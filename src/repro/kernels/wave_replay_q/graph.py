@@ -1,17 +1,17 @@
 """Whole-graph persistent int8 wave-replay kernel (ISSUE 6, int8 twin).
 
-The quantized sibling of ``kernels/wave_replay/graph.py``: ONE
-``pallas_call`` replays a fused chain of conv nodes with the int8
-datapath — int8 activation arena slots, the shared int32 psum bank for
-multi-step nodes (single-step nodes bypass it, exactly like the
-per-layer kernel), exact-fp32 sub-gemms, and the requantize-on-writeback
-epilogue whose residual add reads the shortcut's int8 slot at the
-calibrated output scale. Integer arithmetic is associative, so a fused
+The quantized sibling of ``kernels/wave_replay/graph.py``, sharing its
+node step: ONE ``pallas_call`` replays a fused chain of conv nodes with
+the int8 datapath — arena slots holding int8 values (as int32, the
+32-bit words the TPU's strided loads read), the shared int32 psum bank,
+exact-fp32 sub-gemms, and the requantize-on-writeback epilogue whose
+residual add reads the shortcut's slot at the calibrated output
+scale. Integer arithmetic is associative, so a fused
 chain's output is bit-identical to the per-layer int8 megakernel and to
 the int32 reference model.
 
-Requant vectors ride alongside the flat bias buffer: three int32 flat
-operands (bias, m, shift) share the table's BOFF offsets, padded
+Requant vectors ride alongside the stacked bias buffer: three int32
+stacked operands (bias, m, shift) share the table's BOFF rows, padded
 channels carrying m=0 / shift=31 so their lanes requantize to exact 0.
 """
 from __future__ import annotations
@@ -23,188 +23,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.quantization import requantize_i32
-from repro.core.schedule import (GRAPH_OP_COLS, GOP_BOFF, GOP_C0, GOP_IX,
-                                 GOP_IY, GOP_K, GOP_NODE, GOP_OX, GOP_OY,
-                                 GOP_TX, GOP_TY, GOP_VC, GOP_VR, GOP_WOFF,
+from repro.core.schedule import (GRAPH_OP_COLS, GOP_BOFF, GOP_OY, GOP_WOFF,
                                  GraphKernelProgram, batch_grid)
-from repro.kernels.common import pool_max_subsampled
+from repro.kernels.common import space_to_depth
+from repro.kernels.wave_replay.graph import (graph_kernel_vmem,
+                                             graph_replay_kernel,
+                                             stack_vector_rows,
+                                             stack_weight_rows,
+                                             stacked_shapes, x_block_spec)
 from repro.kernels.wave_replay.ops import pad_input
 from repro.kernels.wave_replay_q import ops as _ops
-from repro.kernels.wave_replay_q.kernel import (exact_channel_chunk,
-                                                q_weight_fan,
-                                                q_weight_full_fan,
-                                                residual_add_i8)
-
-
-def _q_node_step(tbl_ref, x_ref, wf_ref, bf_ref, mf_ref, sf_ref, o_ref,
-                 slots, acc_ref, gkp: GraphKernelProgram, ni: int,
-                 pre_shift: int, c_sub: int, t):
-    """Replay node ``ni``'s int8 per-layer grid step at flat step ``t``."""
-    spec = gkp.nodes[ni]
-    kp = spec.kp
-    l = kp.wave.program.layer
-    K, stride, groups = l.kernel, l.stride, l.groups
-    last = ni == len(gkp.nodes) - 1
-    k = tbl_ref[t, GOP_K]
-    ty = tbl_ref[t, GOP_TY]
-    tx = tbl_ref[t, GOP_TX]
-    ah, aw, oc = kp.acc_h, kp.acc_w, kp.out_c_pad
-    single = kp.n_chain == 1
-    step_in_c = l.in_c // groups if groups > 1 else kp.c_width
-    masked = kp.out_h_pad != kp.out_h or kp.out_w_pad != kp.out_w
-
-    if not last:
-        osi = gkp.arena.slot_of(spec.out_value)
-
-        @pl.when(t == gkp.node_steps[ni])
-        def _zero_slot():
-            slots[osi][...] = jnp.zeros_like(slots[osi])
-
-    if not single:
-        @pl.when(k == 0)
-        def _init():              # chain start: zero the int32 psum bank
-            acc_ref[:, :ah, :aw, :oc] = jnp.zeros_like(
-                acc_ref[:, :ah, :aw, :oc])
-
-    if ni == 0 and not gkp.input_in_arena:
-        x = x_ref[...]
-    else:
-        iv = gkp.arena.value(spec.in_value)
-        isi = gkp.arena.slot_of(spec.in_value)
-        iy = iv.pad[0] - l.pad + ty * (kp.blk_h * kp.pool_stride * stride)
-        ix = iv.pad[1] - l.pad + tx * (kp.blk_w * kp.pool_stride * stride)
-        c0 = k * kp.c_width if groups == 1 else 0
-        x = slots[isi][:, pl.ds(iy, kp.ih), pl.ds(ix, kp.iw),
-                       pl.ds(c0, kp.c_width)]
-    w = wf_ref[0:gkp.w_chunks[ni]].reshape(
-        K, K, q_weight_fan(kp), oc)
-    B = x.shape[0]
-    opg = oc // groups
-
-    if groups > 1 and step_in_c == 1:
-        # depthwise (ISSUE 10): K*K-tap elementwise int32 MAC, exactly
-        # as the per-layer int8 kernel — bit-identical to the per-group
-        # gemm view without unrolling `groups` 1-wide gemms
-        contrib = jnp.zeros((B, ah, aw, oc), jnp.int32)
-        for ky in range(K):
-            for kx in range(K):
-                xt = jax.lax.slice(
-                    x, (0, ky, kx, 0),
-                    (B, ky + (ah - 1) * stride + 1,
-                     kx + (aw - 1) * stride + 1, x.shape[3]),
-                    (1, stride, stride, 1)).astype(jnp.int32)
-                if opg > 1:       # channel-multiplier fan-out
-                    xt = jnp.repeat(xt, opg, axis=-1)
-                contrib += xt * w[ky, kx, 0, :].astype(jnp.int32)
-        step = contrib
-    else:
-        group_cols = []
-        for g in range(groups):                   # static per-group gemms
-            acc_g = None
-            for cc0 in range(0, step_in_c, c_sub):  # exact-fan chunks
-                cc1 = min(cc0 + c_sub, step_in_c)
-                cw = cc1 - cc0
-                xs = jax.lax.slice_in_dim(x, g * step_in_c + cc0,
-                                          g * step_in_c + cc1, axis=3)
-                rows = jnp.concatenate([
-                    jax.lax.slice(
-                        xs, (0, ky, 0, 0),
-                        (B, ky + (ah - 1) * stride + 1, xs.shape[2], cw),
-                        (1, stride, 1, 1))
-                    for ky in range(K)], -1)
-                pat = jnp.concatenate([
-                    jax.lax.slice(
-                        rows, (0, 0, kx, 0),
-                        (B, ah, kx + (aw - 1) * stride + 1, K * cw),
-                        (1, 1, stride, 1))
-                    for kx in range(K)], -1)
-                pat = pat.reshape(B * ah * aw,
-                                  K * K * cw).astype(jnp.float32)
-                wf = jax.lax.slice(w, (0, 0, cc0, g * opg),
-                                   (K, K, cc1, (g + 1) * opg))
-                wf = wf.transpose(1, 0, 2, 3).reshape(
-                    K * K * cw, opg).astype(jnp.float32)
-                part = jax.lax.dot_general(
-                    pat, wf, (((1,), (0,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32).astype(jnp.int32)
-                acc_g = part if acc_g is None else acc_g + part
-            group_cols.append(acc_g)
-        step = group_cols[0] if groups == 1 \
-            else jnp.concatenate(group_cols, -1)
-        step = step.reshape(B, ah, aw, oc)
-
-    def _finish(a):               # requantize-on-writeback, all in VMEM
-        a = a + bf_ref[0:oc]
-        residual = spec.residual_value is not None
-        q = requantize_i32(a, mf_ref[0:oc], sf_ref[0:oc], pre_shift,
-                           relu=kp.relu and not residual)
-        if residual:
-            rv = gkp.arena.value(spec.residual_value)
-            rsi = gkp.arena.slot_of(spec.residual_value)
-            r = slots[rsi][:, pl.ds(rv.pad[0] + ty * kp.blk_h, kp.blk_h),
-                           pl.ds(rv.pad[1] + tx * kp.blk_w, kp.blk_w),
-                           0:oc]
-            q = residual_add_i8(q, r, kp.relu)
-        if kp.fuse_pool:
-            q = pool_max_subsampled(q, pool=kp.pool, stride=kp.pool_stride,
-                                    out_h=kp.blk_h, out_w=kp.blk_w)
-        if masked:
-            rows2 = jax.lax.broadcasted_iota(jnp.int32,
-                                             (kp.blk_h, kp.blk_w), 0)
-            cols2 = jax.lax.broadcasted_iota(jnp.int32,
-                                             (kp.blk_h, kp.blk_w), 1)
-            mask = ((rows2 < tbl_ref[t, GOP_VR])
-                    & (cols2 < tbl_ref[t, GOP_VC]))[None, :, :, None]
-            q = jnp.where(mask, q, jnp.zeros_like(q))
-        if last:
-            o_ref[...] = q
-        else:
-            ov = gkp.arena.value(spec.out_value)
-            wc = min(oc, gkp.arena.slot_shapes[osi][2])
-            slots[osi][:, pl.ds(ov.pad[0] + ty * kp.blk_h, kp.blk_h),
-                       pl.ds(ov.pad[1] + tx * kp.blk_w, kp.blk_w),
-                       0:wc] = q[..., :wc]
-
-    if single:
-        _finish(step)             # psums never touch the scratch bank
-    else:
-        acc_ref[:, :ah, :aw, :oc] += step
-
-        @pl.when(k == kp.n_chain - 1)
-        def _epilogue():
-            _finish(acc_ref[:, :ah, :aw, :oc])
-
-
-def _graph_replay_q_kernel(tbl_ref, x_ref, wf_ref, bf_ref, mf_ref,
-                           sf_ref, o_ref, *scratch,
-                           gkp: GraphKernelProgram, pre_shifts, c_subs):
-    n_slots = len(gkp.arena.slot_shapes)
-    slots, acc_ref = scratch[:n_slots], scratch[n_slots]
-    # grid is (batch-block, flat step): t restarts at 0 for every batch
-    # block, so input staging and slot zeroing re-fire per block while
-    # the int8 arena / psum scratch is recycled across blocks
-    t = pl.program_id(1)
-    if gkp.input_in_arena:
-        iv = gkp.arena.value(gkp.input_value)
-        isi = gkp.arena.slot_of(gkp.input_value)
-        h0 = gkp.nodes[0].kp
-        pad0 = gkp.nodes[0].kp.wave.program.layer.pad
-        dy, dx = iv.pad[0] - pad0, iv.pad[1] - pad0
-
-        @pl.when(t == 0)
-        def _stage_input():
-            slots[isi][...] = jnp.zeros_like(slots[isi])
-            slots[isi][:, dy:dy + h0.pad_h, dx:dx + h0.pad_w,
-                       0:h0.in_c_kpad] = x_ref[...]
-    nd = tbl_ref[t, GOP_NODE]
-    for ni in range(len(gkp.nodes)):
-        @pl.when(nd == ni)
-        def _run(ni=ni):
-            _q_node_step(tbl_ref, x_ref, wf_ref, bf_ref, mf_ref, sf_ref,
-                         o_ref, slots, acc_ref, gkp, ni,
-                         pre_shifts[ni], c_subs[ni], t)
+from repro.kernels.wave_replay_q.kernel import exact_channel_chunk
 
 
 def wave_replay_graph_q_raw(gkp: GraphKernelProgram, xq: jax.Array,
@@ -215,10 +44,11 @@ def wave_replay_graph_q_raw(gkp: GraphKernelProgram, xq: jax.Array,
     """Launch one fused int8 chain as ONE persistent pallas_call.
 
     ``xq`` int8 pre-padded to the head program's buffer geometry;
-    ``wf`` flat (w_total,) int8 weights; ``bf``/``mf``/``sf`` flat
-    (b_total,) int32 bias/requant-multiplier/shift buffers sharing the
-    BOFF offsets; ``pre_shifts``/``fan_chunks`` one entry per chain
-    node (``LayerQuant`` statics). Returns the final node's padded int8
+    ``wf`` the stacked (w_total, rows, cols) int8 weights;
+    ``bf``/``mf``/``sf`` the stacked (b_total, 1, b_max) int32 bias /
+    requant-multiplier / shift rows sharing the BOFF rows;
+    ``pre_shifts``/``fan_chunks`` one entry per chain node
+    (``LayerQuant`` statics). Returns the final node's padded int8
     output.
     """
     if interpret is None:
@@ -230,6 +60,7 @@ def wave_replay_graph_q_raw(gkp: GraphKernelProgram, xq: jax.Array,
                          "the natural grouped layout)")
     h0, kl = gkp.nodes[0].kp, gkp.out_kp
     B = xq.shape[0]
+    (rows, b_max), geos = stacked_shapes(gkp)
     for spec in gkp.nodes:
         kp = spec.kp
         g = kp.wave.program
@@ -246,12 +77,13 @@ def wave_replay_graph_q_raw(gkp: GraphKernelProgram, xq: jax.Array,
         raise ValueError(
             f"int8 graph kernel input {xq.shape} != padded "
             f"({B}, {h0.pad_h}, {h0.pad_w}, {h0.in_c_kpad})")
-    if wf.shape != (gkp.w_total,):
-        raise ValueError(f"flat weights {wf.shape} != ({gkp.w_total},)")
+    if wf.shape != (gkp.w_total, rows, gkp.b_max):
+        raise ValueError(f"stacked weights {wf.shape} != "
+                         f"({gkp.w_total}, {rows}, {gkp.b_max})")
     for name, arr in (("bias_q", bf), ("m", mf), ("shift", sf)):
-        if arr.shape != (gkp.b_total,) or arr.dtype != jnp.int32:
-            raise ValueError(f"{name} must be int32 ({gkp.b_total},), "
-                             f"got {arr.dtype} {arr.shape}")
+        if arr.shape != (gkp.b_total, 1, b_max) or arr.dtype != jnp.int32:
+            raise ValueError(f"{name} must be int32 ({gkp.b_total}, 1, "
+                             f"{b_max}), got {arr.dtype} {arr.shape}")
     if table.shape != (gkp.total_steps, GRAPH_OP_COLS):
         raise ValueError(
             f"graph table {table.shape} != "
@@ -261,13 +93,13 @@ def wave_replay_graph_q_raw(gkp: GraphKernelProgram, xq: jax.Array,
         raise ValueError("pre_shifts/fan_chunks must have one entry "
                          "per chain node")
 
-    c_subs = []
-    for spec, fc in zip(gkp.nodes, fan_chunks):
+    quants = []
+    for spec, ps, fc in zip(gkp.nodes, pre_shifts, fan_chunks):
         l = spec.kp.wave.program.layer
         step_in_c = l.in_c // l.groups if l.groups > 1 \
             else spec.kp.c_width
-        c_subs.append(exact_channel_chunk(l.kernel) if fc is None
-                      else max(1, min(int(fc), step_in_c)))
+        quants.append((int(ps), exact_channel_chunk(l.kernel) if fc is None
+                       else max(1, min(int(fc), step_in_c))))
 
     # batch as the outermost grid axis (ISSUE 8): ragged batches are
     # zero-padded to whole blocks — int8 zero images quantize and
@@ -276,55 +108,41 @@ def wave_replay_graph_q_raw(gkp: GraphKernelProgram, xq: jax.Array,
     n_bb, bb = batch_grid(B, gkp.batch_block)
     if n_bb * bb != B:
         xq = jnp.pad(xq, ((0, n_bb * bb - B), (0, 0), (0, 0), (0, 0)))
-    if gkp.input_in_arena:
-        x_spec = pl.BlockSpec((bb, h0.pad_h, h0.pad_w, h0.in_c_kpad),
-                              lambda bi, t, tbl: (bi, 0, 0, 0))
-    else:
-        x_spec = pl.BlockSpec(
-            (bb, h0.ih, h0.iw, h0.c_width),
-            lambda bi, t, tbl: (bi * bb, tbl[t, GOP_IY],
-                                tbl[t, GOP_IX], tbl[t, GOP_C0]),
-            indexing_mode=pl.unblocked)
-    woff_spec = pl.BlockSpec((gkp.w_max,),
-                             lambda bi, t, tbl: (tbl[t, GOP_WOFF],),
-                             indexing_mode=pl.unblocked)
-    boff_spec = pl.BlockSpec((gkp.b_max,),
-                             lambda bi, t, tbl: (tbl[t, GOP_BOFF],),
-                             indexing_mode=pl.unblocked)
+    xq = space_to_depth(xq, geos[0].s2d)
+    vec_spec = pl.BlockSpec((1, 1, b_max),
+                            lambda bi, t, tbl: (tbl[t, GOP_BOFF], 0, 0))
+    out_block = (bb, kl.blk_h, kl.out_w_pad, kl.out_c_pad)
+    vmem = graph_kernel_vmem(gkp, bb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_bb, gkp.total_steps),
-        in_specs=[x_spec, woff_spec, boff_spec, boff_spec, boff_spec],
+        in_specs=[x_block_spec(gkp, bb),
+                  pl.BlockSpec((1, rows, gkp.b_max),
+                               lambda bi, t, tbl: (tbl[t, GOP_WOFF], 0, 0)),
+                  vec_spec, vec_spec, vec_spec],
         out_specs=pl.BlockSpec(
-            (bb, kl.blk_h, kl.blk_w, kl.out_c_pad),
-            lambda bi, t, tbl: (bi, tbl[t, GOP_OY], tbl[t, GOP_OX], 0)),
-        # int8 activation arena + the shared int32 psum bank (token
-        # buffer when every node is single-step)
-        scratch_shapes=[pltpu.VMEM((bb,) + s, jnp.int8)
-                        for s in gkp.arena.slot_shapes]
-        + [pltpu.VMEM(
-            (bb,) + gkp.acc_shape(multi_only=True)
-            if any(s.kp.n_chain > 1 for s in gkp.nodes)
-            else (1, 1, 1, 1), jnp.int32)],
+            out_block, lambda bi, t, tbl: (bi, tbl[t, GOP_OY], 0, 0)),
+        # int8-valued arena + the shared int32 psum bank and staging
+        scratch_shapes=[pltpu.VMEM(sh, dt) for sh, dt in vmem.scratch],
     )
     yq = pl.pallas_call(
-        functools.partial(_graph_replay_q_kernel, gkp=gkp,
-                          pre_shifts=tuple(pre_shifts),
-                          c_subs=tuple(c_subs)),
+        functools.partial(graph_replay_kernel, gkp=gkp, geos=geos,
+                          quants=tuple(quants)),
         out_shape=jax.ShapeDtypeStruct(
             (n_bb * bb, kl.out_h_pad, kl.out_w_pad, kl.out_c_pad),
             jnp.int8),
         grid_spec=grid_spec,
+        compiler_params=vmem.compiler_params(gkp.nodes[0].name, interpret),
         interpret=interpret,
     )(table, xq, wf, bf, mf, sf)
     return yq[:B] if n_bb * bb != B else yq
 
 
 def pack_graph_operands_q(gkp: GraphKernelProgram, qops):
-    """(wq, bq, m, shift) per chain node -> flat int8/int32 buffers.
+    """(wq, bq, m, shift) per chain node -> stacked int8/int32 buffers.
 
     Weights keep the per-layer kernel's layout: natural per-group fan
-    for grouped nodes (whole tensor = the single step's chunk), chain
+    for grouped nodes (whole tensor = the single step's row), chain
     chunk fan slices for ungrouped ones. Padded output channels carry
     m=0 / shift=31 so their requantized lanes are exact zeros — same as
     ``pad_operands_q``.
@@ -332,34 +150,16 @@ def pack_graph_operands_q(gkp: GraphKernelProgram, qops):
     if len(qops) != len(gkp.nodes):
         raise ValueError(f"{len(qops)} quantized operand tuples for "
                          f"{len(gkp.nodes)} chain nodes")
-    chunks, bvecs, mvecs, svecs = [], [], [], []
-    for spec, (wq, bq, m, shift) in zip(gkp.nodes, qops):
-        kp = spec.kp
-        g = kp.wave.program
-        l = g.layer
-        wp = jnp.pad(wq, ((0, 0), (0, 0),
-                          (0, q_weight_full_fan(kp) - wq.shape[2]),
-                          (0, g.out_c_pad - l.out_c)))
-        if l.groups > 1:
-            chunks.append(wp.reshape(-1))
-        else:
-            for kk in range(kp.n_chain):
-                chunks.append(
-                    wp[:, :, kk * kp.fan_width:(kk + 1) * kp.fan_width, :]
-                    .reshape(-1))
-        pad_c = g.out_c_pad - l.out_c
+    bvecs, mvecs, svecs = [], [], []
+    for spec, (_, bq, m, shift) in zip(gkp.nodes, qops):
+        pad_c = spec.kp.out_c_pad - spec.kp.wave.program.layer.out_c
         bvecs.append(jnp.pad(bq.astype(jnp.int32), (0, pad_c)))
         mvecs.append(jnp.pad(m.astype(jnp.int32), (0, pad_c)))
         svecs.append(jnp.pad(shift.astype(jnp.int32), (0, pad_c),
                              constant_values=31))
-    flat_w = jnp.concatenate(chunks)
-    flat_b = jnp.concatenate(bvecs)
-    flat_m = jnp.concatenate(mvecs)
-    flat_s = jnp.concatenate(svecs)
-    pad_b = gkp.b_total - flat_b.shape[0]
-    return (jnp.pad(flat_w, (0, gkp.w_total - flat_w.shape[0])),
-            jnp.pad(flat_b, (0, pad_b)), jnp.pad(flat_m, (0, pad_b)),
-            jnp.pad(flat_s, (0, pad_b), constant_values=31))
+    return (stack_weight_rows(gkp, [q[0] for q in qops], jnp.int8),
+            stack_vector_rows(gkp, bvecs), stack_vector_rows(gkp, mvecs),
+            stack_vector_rows(gkp, svecs, fill=31))
 
 
 def wave_replay_graph_q(gkp: GraphKernelProgram, xq: jax.Array, qops,

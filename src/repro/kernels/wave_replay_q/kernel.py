@@ -47,12 +47,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.quantization import (EXACT_FP32_FAN, INT8_QMAX,
-                                     requantize_i32)
-from repro.core.schedule import (KERNEL_OP_COLS, OP_C0, OP_IX, OP_IY,
-                                 OP_TX, OP_TY, OP_VC, OP_VR, OP_WC0,
-                                 KernelProgram, batch_grid)
-from repro.kernels.common import pool_max_subsampled
+from repro.core.quantization import (EXACT_FP32_FAN, requantize_clip,
+                                     residual_add_clip)
+from repro.core.schedule import (KERNEL_OP_COLS, OP_IY, OP_TX, OP_TY,
+                                 OP_VC, OP_VR, KernelProgram, batch_grid)
+from repro.kernels.common import (at_tile_col, conv_rows, element_block,
+                                  lane_load, mask_tile, megakernel_geometry,
+                                  megakernel_vmem, pool_tile,
+                                  space_to_depth, space_to_depth_weights,
+                                  stage_lanes, strided)
 
 
 def exact_channel_chunk(kernel: int) -> int:
@@ -74,146 +77,88 @@ def residual_add_i8(q: jax.Array, r: jax.Array,
     sum is plain int32 addition followed by the ReLU-folded int8 clip —
     deterministic integer ops shared verbatim by the kernel epilogue
     and the int32 reference model (bit-exact by construction)."""
-    s = q.astype(jnp.int32) + r.astype(jnp.int32)
-    lo = 0 if relu else -INT8_QMAX
-    return jnp.clip(s, lo, INT8_QMAX).astype(jnp.int8)
+    return residual_add_clip(q, r, relu).astype(jnp.int8)
 
 
 def _replay_q_kernel(tbl_ref, x_ref, w_ref, bq_ref, m_ref, s_ref, *refs,
-                     K: int, stride: int, acc_h: int,
+                     K: int, stride: int, col_step: int, acc_h: int,
                      acc_w: int, n_waves: int, pool: int, ps: int,
-                     blk_h: int, blk_w: int, relu: bool, fuse_pool: bool,
-                     groups: int, step_in_c: int, c_sub: int,
+                     blk_h: int, blk_w: int, tiles_w: int, relu: bool,
+                     fuse_pool: bool, groups: int, c_sub: int,
                      pre_shift: int, masked: bool, residual: bool):
     """One grid step: batch block (program_id 0), tile t (id 1), chain
     position k (id 2) — the batch axis outermost, like the fp32 kernel.
 
-    ``step_in_c`` is the input channels this step reduces *per group*
-    (= the chain chunk width for ungrouped layers, in_c/groups for
-    grouped ones, whose chains are single-step by plan construction);
-    ``c_sub`` caps the channels per exact-fp32 sub-gemm — either the
-    worst-case ``exact_channel_chunk`` bound, or the calibrated
-    weight-aware bound (``LayerQuant.fan_chunk``), which usually lets
-    the whole fan run as one gemm. Single-step chains (``n_waves == 1``
-    — every AlexNet layer after VMEM re-planning) bypass the scratch
-    accumulator entirely: the gemm result flows straight into the
-    requantize epilogue, saving three full passes over int32 psums.
-    ``masked`` is statically False when the tile grid covers the valid
-    output exactly, dropping the write-mask pass too. With ``residual``
-    the positional refs gain one operand — ``(r_ref, o_ref, acc_ref)``
-    instead of ``(o_ref, acc_ref)``: the int8 residual block at the
-    layer's calibrated OUTPUT scale, added after requantization
-    (``residual_add_i8``) with the ReLU folded into the final clip.
+    ``refs`` are ``[r_ref] o_ref acc_ref xs_ref ws_ref [pool_ref]``.
+    The int8 window (one image at a time) and the step's weights are
+    staged into fp32 scratch — exact, they hold int8 values — where the
+    shared ``conv_rows`` body reads its taps (``xs_ref`` lane-tiled, so
+    strided taps load from it too); ``c_sub`` caps the channels per
+    exact-fp32 dot — either the worst-case ``exact_channel_chunk``
+    bound, or the calibrated weight-aware bound (``LayerQuant.
+    fan_chunk``). The int32 ``acc_ref`` is the paper's 32-bit psum
+    bank. ``masked`` is statically False when the tile grid covers the
+    valid output exactly, dropping the write-mask pass. ``r_ref``
+    (``residual``) holds the int8 residual rows at the layer's
+    calibrated OUTPUT scale, added after requantization with the ReLU
+    folded into the final clip.
     """
-    if residual:
-        r_ref, o_ref, acc_ref = refs
-    else:
-        (o_ref, acc_ref), r_ref = refs, None
+    refs = list(refs)
+    r_ref = refs.pop(0) if residual else None
+    o_ref, acc_ref, xs_ref, ws_ref = refs[:4]
+    pool_ref = refs[4] if fuse_pool else None
     t = pl.program_id(1)
     k = pl.program_id(2)
-    single = n_waves == 1
 
-    if not single:
-        @pl.when(k == 0)
-        def _init():              # chain start: zero the int32 psum bank
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+    @pl.when(k == 0)
+    def _init():                  # chain start: zero the int32 psum bank
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]                # int8 (B, ih, iw, c_width) halo-inclusive
-    w = w_ref[...]                # int8 (K, K, w_fan, out_c_pad)
-    B = x.shape[0]
-    out_c_pad = o_ref.shape[-1]
-    opg = out_c_pad // groups
+    bb, cin = x_ref.shape[0], x_ref.shape[-1]
+    out_c = acc_ref.shape[-1]
+    ws_ref[...] = w_ref[...].astype(jnp.float32)
 
-    if groups > 1 and step_in_c == 1:
-        # depthwise (ISSUE 10): out channel o reads in channel o // opg.
-        # A K*K-tap elementwise int32 multiply-accumulate — int8 x int8
-        # products are exact in int32, and addition is associative, so
-        # this is bit-identical to the per-group gemm view while never
-        # unrolling `groups` (= in_c) 1-wide gemms.
-        contrib = jnp.zeros((B, acc_h, acc_w, out_c_pad), jnp.int32)
-        for ky in range(K):
-            for kx in range(K):
-                xt = jax.lax.slice(
-                    x, (0, ky, kx, 0),
-                    (B, ky + (acc_h - 1) * stride + 1,
-                     kx + (acc_w - 1) * stride + 1, x.shape[3]),
-                    (1, stride, stride, 1)).astype(jnp.int32)
-                if opg > 1:       # channel-multiplier fan-out
-                    xt = jnp.repeat(xt, opg, axis=-1)
-                contrib += xt * w[ky, kx, 0, :].astype(jnp.int32)
-        step = contrib
-    else:
-        group_cols = []
-        for g in range(groups):                   # static per-group gemms
-            acc_g = None
-            for c0 in range(0, step_in_c, c_sub):  # static exact-fan chunks
-                c1 = min(c0 + c_sub, step_in_c)
-                cw = c1 - c0
-                xs = jax.lax.slice_in_dim(x, g * step_in_c + c0,
-                                          g * step_in_c + c1, axis=3)
-                # two-stage im2col: K row slices then K column slices
-                # (2K + 2 ops instead of the K^2 + 1 per-tap slices the
-                # fp32 kernel issues — interpret-mode dispatch count is a
-                # real cost at K = 11). The fan lands in (kx, ky, c) order;
-                # the weight reshape below matches it.
-                rows = jnp.concatenate([
-                    jax.lax.slice(
-                        xs, (0, ky, 0, 0),
-                        (B, ky + (acc_h - 1) * stride + 1, xs.shape[2], cw),
-                        (1, stride, 1, 1))
-                    for ky in range(K)], -1)      # (B, acc_h, iw, K*cw)
-                pat = jnp.concatenate([
-                    jax.lax.slice(
-                        rows, (0, 0, kx, 0),
-                        (B, acc_h, kx + (acc_w - 1) * stride + 1, K * cw),
-                        (1, 1, stride, 1))
-                    for kx in range(K)], -1)      # (B, acc_h, acc_w, K*K*cw)
-                pat = pat.reshape(B * acc_h * acc_w,
-                                  K * K * cw).astype(jnp.float32)
-                # weight fan rows are per-group already (natural layout):
-                # the group structure lives only in x's channel axis;
-                # transpose to the patches' (kx, ky, c) fan order
-                wf = jax.lax.slice(w, (0, 0, c0, g * opg),
-                                   (K, K, c1, (g + 1) * opg))
-                wf = wf.transpose(1, 0, 2, 3).reshape(
-                    K * K * cw, opg).astype(jnp.float32)
-                part = jax.lax.dot_general(
-                    pat, wf, (((1,), (0,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32).astype(jnp.int32)
-                acc_g = part if acc_g is None else acc_g + part
-            group_cols.append(acc_g)
-        step = group_cols[0] if groups == 1 \
-            else jnp.concatenate(group_cols, -1)
-        step = step.reshape(B, acc_h, acc_w, out_c_pad)
+    def conv_image(col0, b, carry):
+        stage_lanes(xs_ref, x_ref[b].astype(jnp.float32))
 
-    def _finish(a):               # requantize-on-writeback, all in VMEM
-        a = a + bq_ref[0]
-        # the residual add runs pre-ReLU: requantize without the ReLU
-        # clip, add the int8 shortcut (same scale), then ReLU-clip
-        q = requantize_i32(a, m_ref[0], s_ref[0], pre_shift,
-                           relu=relu and not residual)
-        if residual:
-            q = residual_add_i8(q, r_ref[...], relu)
-        if fuse_pool:
-            q = pool_max_subsampled(q, pool=pool, stride=ps,
-                                    out_h=blk_h, out_w=blk_w)
-        if masked:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (blk_h, blk_w), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (blk_h, blk_w), 1)
-            mask = ((rows < tbl_ref[k, t, OP_VR])
-                    & (cols < tbl_ref[k, t, OP_VC]))[None, :, :, None]
-            q = jnp.where(mask, q, jnp.zeros_like(q))
-        o_ref[...] = q
+        def load(r, kx, c0, cw):
+            return lane_load(xs_ref, (), r, strided(col0 + kx, acc_w, stride),
+                             c0, cw)
 
-    if single:
-        _finish(step)             # psums never touch the scratch bank
-    else:
-        acc_ref[...] += step
+        def wtap(ky, kx, c0, cw, o0, ow):
+            return ws_ref[ky, kx, c0:c0 + cw, o0:o0 + ow]
 
-        @pl.when(k == n_waves - 1)
-        def _epilogue():
-            _finish(acc_ref[...])
+        conv_rows(acc_ref, b, load, wtap, K=K, stride=stride,
+                  acc_h=acc_h, acc_w=acc_w, cin=cin, out_c=out_c,
+                  groups=groups, exact_chunk=c_sub)
+        return carry
+
+    at_tile_col(tbl_ref[k, t, OP_TX], tiles_w, lambda j: jax.lax.fori_loop(
+        0, bb, functools.partial(conv_image, j * col_step), 0))
+
+    @pl.when(k == n_waves - 1)
+    def _epilogue():              # requantize-on-writeback, all in VMEM
+        vr, vc = tbl_ref[k, t, OP_VR], tbl_ref[k, t, OP_VC]
+
+        def finish(j, b, carry):
+            cols = slice(j * blk_w, (j + 1) * blk_w)
+            a = acc_ref[b] + bq_ref[...]
+            # the residual add runs pre-ReLU: requantize without the
+            # ReLU clip, add the int8 shortcut (same scale), then clip
+            q = requantize_clip(a, m_ref[...], s_ref[...], pre_shift,
+                                relu=relu and not residual)
+            if residual:
+                q = residual_add_clip(q, r_ref[b, :, cols, :], relu)
+            if fuse_pool:
+                q = pool_tile(pool_ref, q, pool=pool, ps=ps,
+                              blk_h=blk_h, blk_w=blk_w)
+            if masked:
+                q = mask_tile(q, vr, vc)
+            o_ref[b, :, cols, :] = q.astype(jnp.int8)
+            return carry
+
+        at_tile_col(tbl_ref[k, t, OP_TX], tiles_w, lambda j: jax.lax.fori_loop(
+            0, bb, functools.partial(finish, j), 0))
 
 
 def q_weight_fan(kp: KernelProgram) -> int:
@@ -257,7 +202,6 @@ def wave_replay_q_raw(kp: KernelProgram, xq: jax.Array, wq: jax.Array,
     g = kp.wave.program
     l = g.layer
     B = xq.shape[0]
-    w_fan = q_weight_fan(kp)
     if l.groups > 1:
         # grouped plans have single-step chains (planner invariant) and
         # group-aligned features, so out_c_pad == out_c and the in-body
@@ -315,62 +259,60 @@ def wave_replay_q_raw(kp: KernelProgram, xq: jax.Array, wq: jax.Array,
         if kp.residual:
             residual = jnp.pad(
                 residual, ((0, n_bb * bb - B), (0, 0), (0, 0), (0, 0)))
+    # the same stride folding as the fp32 kernel; channel-major folding
+    # keeps each c_sub channel chunk one contiguous range of s*s*c_sub
+    s, k_eff, stride, ih, full_w, c_eff, f_eff = megakernel_geometry(kp)
+    xq = space_to_depth(xq, s)
+    wq = space_to_depth_weights(wq, s)
+    vmem = megakernel_vmem(kp, quantized=True, bb=bb)
+    out_block = (bb, kp.blk_h, kp.out_w_pad, g.out_c_pad)
     in_specs = [
-        pl.BlockSpec((bb, kp.ih, kp.iw, kp.c_width),
-                     lambda bi, t, k, tbl: (bi * bb, tbl[k, t, OP_IY],
-                                            tbl[k, t, OP_IX],
-                                            tbl[k, t, OP_C0]),
-                     indexing_mode=pl.unblocked),
+        pl.BlockSpec(element_block((bb, ih, full_w, c_eff)),
+                     lambda bi, t, k, tbl: (
+                         bi * bb, tbl[k, t, OP_IY] // s, 0,
+                         k * c_eff if kp.n_chain > 1 else 0)),
         # natural per-group weights: grouped layers read the whole
-        # (single-step) tensor, ungrouped ones slice the chain
-        # chunk's fan rows exactly like the fp32 kernel
-        pl.BlockSpec((l.kernel, l.kernel, w_fan, g.out_c_pad),
-                     lambda bi, t, k, tbl: (0, 0, tbl[k, t, OP_WC0], 0),
-                     indexing_mode=pl.unblocked),
+        # (single-step) tensor, ungrouped ones the chain chunk's fan rows
+        pl.BlockSpec((k_eff, k_eff, f_eff, g.out_c_pad),
+                     lambda bi, t, k, tbl: (0, 0, k, 0)),
         pl.BlockSpec((1, g.out_c_pad), lambda bi, t, k, tbl: (0, 0)),
         pl.BlockSpec((1, g.out_c_pad), lambda bi, t, k, tbl: (0, 0)),
         pl.BlockSpec((1, g.out_c_pad), lambda bi, t, k, tbl: (0, 0)),
     ]
     operands = [table, xq, wq, bq, m, shift]
     if kp.residual:
-        # the int8 shortcut reads the blocked tiling the output writes
+        # the int8 shortcut reads the row blocks the output writes
         in_specs.append(pl.BlockSpec(
-            (bb, kp.blk_h, kp.blk_w, g.out_c_pad),
-            lambda bi, t, k, tbl: (bi, tbl[k, t, OP_TY],
-                                   tbl[k, t, OP_TX], 0)))
+            out_block, lambda bi, t, k, tbl: (bi, tbl[k, t, OP_TY], 0, 0)))
         operands.append(residual)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,        # the SMEM operand table
         grid=(n_bb, kp.n_tiles, kp.n_chain),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (bb, kp.blk_h, kp.blk_w, g.out_c_pad),
-            lambda bi, t, k, tbl: (bi, tbl[k, t, OP_TY],
-                                   tbl[k, t, OP_TX], 0)),
-        # the paper's 32-bit psum SRAM bank: one tile's chain lives
-        # here at accumulator precision, never in HBM (single-step
-        # chains bypass it, so allocate a token buffer for them)
-        scratch_shapes=[pltpu.VMEM(
-            (bb, kp.acc_h, kp.acc_w, g.out_c_pad) if kp.n_chain > 1
-            else (1, 1, 1, 1), jnp.int32)],
+            out_block, lambda bi, t, k, tbl: (bi, tbl[k, t, OP_TY], 0, 0)),
+        # the paper's 32-bit psum SRAM bank, plus the fp32 staging of
+        # one image's window and of the step's weights
+        scratch_shapes=[pltpu.VMEM(sh, dt) for sh, dt in vmem.scratch],
     )
     # write masks are only live where the uniform tile grid overhangs
     # the valid output; exact grids skip the mask pass statically
     masked = kp.out_h_pad != kp.out_h or kp.out_w_pad != kp.out_w
     kern = functools.partial(
-        _replay_q_kernel, K=l.kernel, stride=l.stride,
+        _replay_q_kernel, K=k_eff, stride=stride,
+        col_step=kp.blk_w * kp.pool_stride * stride,
         acc_h=kp.acc_h, acc_w=kp.acc_w,
         n_waves=kp.n_chain, pool=kp.pool, ps=kp.pool_stride,
-        blk_h=kp.blk_h, blk_w=kp.blk_w, relu=kp.relu,
-        fuse_pool=kp.fuse_pool, groups=l.groups,
-        step_in_c=step_in_c, c_sub=c_sub, pre_shift=pre_shift,
-        masked=masked, residual=kp.residual)
+        blk_h=kp.blk_h, blk_w=kp.blk_w, tiles_w=kp.tiles_w, relu=kp.relu,
+        fuse_pool=kp.fuse_pool, groups=l.groups, c_sub=c_sub * s * s,
+        pre_shift=pre_shift, masked=masked, residual=kp.residual)
     yq = pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct(
             (n_bb * bb, kp.out_h_pad, kp.out_w_pad, g.out_c_pad),
             jnp.int8),
         grid_spec=grid_spec,
+        compiler_params=vmem.compiler_params(l.name, interpret),
         interpret=interpret,
     )(*operands)
     return yq[:B] if n_bb * bb != B else yq

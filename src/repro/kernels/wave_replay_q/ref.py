@@ -40,8 +40,10 @@ def quant_layer_ref(layer: ConvLayer, xq: jax.Array, wq: jax.Array,
     int32-add the shortcut, then ReLU-clip (``residual_add_i8``).
     Returns int8 — post-pool dims when ``fuse_pool``."""
     l = layer
+    # int8 x int8 -> int32: every product and sum exact, on the TPU's
+    # integer MXU path as on the CPU
     acc = lax.conv_general_dilated(
-        xq.astype(jnp.int32), wq.astype(jnp.int32),
+        xq.astype(jnp.int8), wq.astype(jnp.int8),
         window_strides=(l.stride, l.stride),
         padding=[(l.pad, l.pad), (l.pad, l.pad)],
         dimension_numbers=("NHWC", "HWIO", "NHWC"),

@@ -31,6 +31,62 @@ from repro.models.module import init_params
 from repro.train.steps import make_decode_step, make_prefill_step
 
 
+def make_cnn_session(graph, weights, *, mode: str = "wave",
+                     precision: str = "fp32", max_batch: int = 8,
+                     sram_kb: int = 128, pool_backend: str = "xla",
+                     qnet=None, fallback=None, guard=None,
+                     autotune_cache=None, tracer=None,
+                     compile_retries: int = 2):
+    """The serving session ``--cnn`` builds: plan the graph at
+    ``sram_kb``, and for ``precision="int8"`` calibrate it on two random
+    frames (unless a calibrated ``qnet`` is given) and serve the
+    quantized megakernel. Returns ``(session, mode)`` — the mode int8
+    actually serves."""
+    from repro.launch.session import StreamingSession
+
+    if precision == "int8":
+        if mode not in ("megakernel", "graphkernel", "auto"):
+            print("--precision int8 runs the quantized megakernel; "
+                  f"overriding --mode {mode}")
+            mode = "megakernel"
+        if qnet is None:
+            from repro.quant import calibrate_graph
+            calib = jax.random.normal(jax.random.key(7),
+                                      (2,) + graph.in_shape)
+            qnet = calibrate_graph(graph, weights, calib)
+    sess = StreamingSession.for_graph(graph, weights,
+                                      sram_budget=sram_kb * 1024,
+                                      max_batch=max_batch,
+                                      mode=mode,
+                                      pool_backend=pool_backend,
+                                      precision=precision,
+                                      qnet=qnet,
+                                      fallback=fallback,
+                                      guard=guard,
+                                      autotune_cache=autotune_cache,
+                                      tracer=tracer,
+                                      compile_retries=compile_retries)
+    return sess, mode
+
+
+def serve_images(sess, imgs) -> dict:
+    """Serve ``imgs`` (N, H, W, C) as single-image requests: one padded
+    warm-up flush compiles the session's (only) executable, then every
+    image is submitted and the queue drained in ``max_batch`` flushes.
+    Returns the per-request outputs and the host-clock timings."""
+    t0 = time.perf_counter()
+    jax.block_until_ready(sess.result(sess.submit(imgs[0])))
+    compile_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    tickets = [sess.submit(imgs[i]) for i in range(imgs.shape[0])]
+    sess.flush()
+    outs = [sess.result(t) for t in tickets]
+    jax.block_until_ready(outs)
+    serve_s = time.perf_counter() - t0
+    return {"outs": outs, "compile_s": compile_s, "serve_s": serve_s,
+            "img_per_s": imgs.shape[0] / serve_s}
+
+
 def cnn_main(args):
     """Serve single-image requests through a compiled StreamingSession:
     the chosen network's graph (``--network alexnet | vgg16 | resnet18
@@ -39,57 +95,32 @@ def cnn_main(args):
     share one cached executable (paper §7). ResNet-18 serves with its
     residual adds fused into the megakernel epilogues and its
     projection shortcuts streamed as 1x1 convs; the MobileNets stream
-    their depthwise layers through the natural per-group kernel path. ``--precision int8`` calibrates the graph on a few random
-    batches and serves the quantized megakernel path (fixed-point
-    datapath, paper Table 2)."""
+    their depthwise layers through the natural per-group kernel path.
+    ``--precision int8`` calibrates the graph on a few random batches
+    and serves the quantized megakernel path (fixed-point datapath,
+    paper Table 2)."""
     from repro.core.model_zoo import network_graph
-    from repro.launch.session import StreamingSession
     from repro.models.cnn import init_graph_weights
     from repro.obs import Tracer, render_metrics, write_chrome_trace
 
     tracer = Tracer() if args.trace_out else None
     graph = network_graph(args.network)
     weights = init_graph_weights(graph, jax.random.key(0))
-    qnet = None
-    mode = args.mode
-    H, W, C = graph.in_shape
-    if args.precision == "int8":
-        from repro.quant import calibrate_graph
-        if mode not in ("megakernel", "graphkernel", "auto"):
-            print("--precision int8 runs the quantized megakernel; "
-                  f"overriding --mode {mode}")
-            mode = "megakernel"
-        calib = jax.random.normal(jax.random.key(7), (2, H, W, C))
-        qnet = calibrate_graph(graph, weights, calib)
-    sess = StreamingSession.for_graph(graph, weights,
-                                      sram_budget=args.sram_kb * 1024,
-                                      max_batch=args.batch,
-                                      mode=mode,
-                                      pool_backend=args.pool_backend,
-                                      precision=args.precision,
-                                      qnet=qnet,
-                                      fallback=args.fallback or None,
-                                      guard=args.guard or None,
-                                      autotune_cache=args.autotune_cache,
-                                      tracer=tracer)
+    sess, _ = make_cnn_session(
+        graph, weights, mode=args.mode, precision=args.precision,
+        max_batch=args.batch, sram_kb=args.sram_kb,
+        pool_backend=args.pool_backend, fallback=args.fallback or None,
+        guard=args.guard or None, autotune_cache=args.autotune_cache,
+        tracer=tracer)
     if sess.tuned is not None:
         print(f"autotuned plan ({sess.tuned.us_per_batch:.0f} us/batch): "
               + ", ".join(f"{n}={m}" for n, m in sess.tuned.node_modes))
     imgs = jax.random.normal(jax.random.key(99),
-                             (args.requests, H, W, C))
-    # warm-up: one padded flush compiles the (only) executable
-    t0 = time.perf_counter()
-    jax.block_until_ready(sess.result(sess.submit(imgs[0])))
-    print(f"compile+first flush: {time.perf_counter()-t0:.2f} s")
-
-    t0 = time.perf_counter()
-    tickets = [sess.submit(imgs[i]) for i in range(args.requests)]
-    sess.flush()
-    outs = [sess.result(t) for t in tickets]
-    jax.block_until_ready(outs[-1])
-    dt = time.perf_counter() - t0
-    print(f"served {args.requests} requests in {dt*1e3:.0f} ms "
-          f"({args.requests/dt:.1f} img/s), "
+                             (args.requests,) + graph.in_shape)
+    run = serve_images(sess, imgs)
+    print(f"compile+first flush: {run['compile_s']:.2f} s")
+    print(f"served {args.requests} requests in {run['serve_s']*1e3:.0f} ms "
+          f"({run['img_per_s']:.1f} img/s), "
           f"compiles={sess.compile_count}, batched calls={sess.calls}")
     print(sess.describe())
     if tracer is not None:
@@ -105,6 +136,8 @@ def cnn_main(args):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
     ap.add_argument("--batch", type=int, default=4)

@@ -576,14 +576,31 @@ class StreamingSession:
             "pending": len(self._pending),
             "executables": len(self._executables),
         }
+        h["node_modes"] = self.node_modes()
         if self.resolved is not None:
-            h["node_modes"] = dict(self.resolved.node_modes)
             h["degradation_events"] = [e.as_dict()
                                        for e in self.resolved.events]
         if self.tuned is not None:
             h["autotune"] = self.tuned.as_dict()
         h["metrics"] = _metrics.registry().snapshot()
         return h
+
+    def node_modes(self) -> Dict[str, str]:
+        """The executor each conv node actually runs: the resolved plan's
+        modes under fallback or autotuning; otherwise the session mode,
+        except that a graphkernel node left alone in its chain runs the
+        per-layer megakernel."""
+        if self.resolved is not None:
+            return dict(self.resolved.node_modes)
+        names = [n.name for n in self.graph.conv_nodes()]
+        if self.mode != "graphkernel":
+            return {n: self.mode for n in names}
+        from repro.core.streaming import graph_chain_programs
+        chains, _, _ = graph_chain_programs(
+            self.graph, self._progs, quantized=self.precision == "int8",
+            batch=self.max_batch)
+        return {n: "graphkernel" if len(c.convs) > 1 else "megakernel"
+                for c in chains for n in c.convs}
 
     def describe(self) -> str:
         lines = [f"StreamingSession[{self.graph.name}]: "

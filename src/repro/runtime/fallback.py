@@ -430,9 +430,9 @@ def resolve_graph(graph: NetworkGraph, programs, *,
                         programs[name], epi[name][0],
                         epi[name][1] is not None, vmem_budget, batch)
                     fault.fault_point("lower", name, m)
-                    if budget is not None and kp.vmem_bytes > budget:
+                    if budget is not None and kp.plan_bytes > budget:
                         raise BudgetExceeded(
-                            f"{name}: working set {kp.vmem_bytes} B "
+                            f"{name}: working set {kp.plan_bytes} B "
                             f"exceeds the {budget} B VMEM budget at "
                             f"mode {m!r}")
                     if m == "megakernel":

@@ -7,7 +7,6 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
@@ -24,20 +23,3 @@ def _reset_obs_state():
     _t.set_tracer(None)
     _m.set_registry(None)        # back to the default registry ...
     _m.reset_metrics()           # ... and wipe it
-
-_OPTBAR_GRAD = None
-
-
-def optimization_barrier_differentiable() -> bool:
-    """Whether the pinned jax can differentiate optimization_barrier
-    (train/losses.py pins the compute-dtype cast with it). Probed once;
-    shared by the xfail conditions in test_models_smoke/test_train_loop."""
-    global _OPTBAR_GRAD
-    if _OPTBAR_GRAD is None:
-        try:
-            jax.grad(lambda v: jax.lax.optimization_barrier(v).sum())(
-                jnp.ones((2,)))
-            _OPTBAR_GRAD = True
-        except NotImplementedError:
-            _OPTBAR_GRAD = False
-    return _OPTBAR_GRAD

@@ -70,8 +70,8 @@ def test_kernel_program_batch_block_scales_vmem():
     kp4 = graph_kernel_programs(g, progs, batch=4)["c2"]
     assert kp1.batch_block == 1
     if kp4.batch_block > 1:
-        assert kp4.vmem_bytes < kp4.batch_block * kp1.vmem_bytes
-    assert kp4.vmem_bytes >= kp1.vmem_bytes
+        assert kp4.plan_bytes < kp4.batch_block * kp1.plan_bytes
+    assert kp4.plan_bytes >= kp1.plan_bytes
 
 
 # ---------------------------------------------------------------------------

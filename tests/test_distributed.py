@@ -6,17 +6,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax.sharding
-import pytest
-
-# pre-existing seed failure, triaged (ISSUE 5 satellite): the pinned
-# jax wheel predates jax.sharding.AxisType, which every subprocess mesh
-# script imports — the tests exercise nothing until the jax pin moves
-pytestmark = pytest.mark.xfail(
-    condition=not hasattr(jax.sharding, "AxisType"),
-    reason="installed jax lacks jax.sharding.AxisType (explicit-mesh "
-           "API); subprocess mesh tests need a newer jax pin",
-    strict=False)
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 

@@ -14,9 +14,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-import pytest
 
-from conftest import optimization_barrier_differentiable
 from repro.configs import reduced_config
 from repro.configs.base import TrainConfig
 from repro.core.decomposition import ConvLayer
@@ -102,12 +100,6 @@ def test_decode_step_donates_kv_cache():
         f"expected at least the {n_cache_leaves} cache leaves")
 
 
-@pytest.mark.xfail(
-    condition=not optimization_barrier_differentiable(),
-    reason="installed jax cannot differentiate optimization_barrier "
-           "(train/losses.py pins the compute-dtype cast with it); "
-           "needs a newer jax pin",
-    strict=False)
 def test_train_step_donates_state():
     """train/loop.py rebinds the state every step; the jit must alias
     the param/moment buffers in place (what dryrun's estimator already

@@ -16,7 +16,7 @@ from repro.core.graph import (INPUT, GraphNode, NetworkGraph,
 from repro.core.model_zoo import (alexnet_graph, resnet18_graph,
                                   vgg16_graph)
 from repro.core.schedule import (DEFAULT_VMEM_BUDGET, GOP_NODE, GOP_WOFF,
-                                 ArenaValue, chain_vmem_bytes, plan_arena,
+                                 ArenaValue, chain_plan_bytes, plan_arena,
                                  validate_graph_kernel)
 from repro.core.streaming import (_coarsen_single_wave, compile_graph,
                                   graph_chain_programs, graph_forward_fn,
@@ -271,7 +271,7 @@ def test_whole_alexnet_is_one_kernel_launch():
     assert [len(c.convs) for c in chains] == [5]
     gkp = gkps[chains[0].convs[0]]
     validate_graph_kernel(gkp)
-    assert gkp.vmem_bytes <= ALEXNET_WHOLE_BUDGET
+    assert gkp.plan_bytes <= ALEXNET_WHOLE_BUDGET
     assert _count_launches(g, "graphkernel",
                            vmem_budget=ALEXNET_WHOLE_BUDGET) == 1
 

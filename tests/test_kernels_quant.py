@@ -133,9 +133,16 @@ if hypothesis is not None:
             wq = rng.integers(-128, 128, (K, N), np.int64).astype(np.int8)
         sx = 0.02
         sw = jnp.asarray(rng.uniform(1e-3, 2e-2, N), jnp.float32)
-        got = quant_matmul(jnp.asarray(xq), jnp.asarray(wq), sx, sw,
+        xq, wq = jnp.asarray(xq), jnp.asarray(wq)
+        # unit scales expose the kernel's int32 accumulator (|acc| <
+        # 2^24, exact in fp32): integer accumulation is bit-exact
+        acc = quant_matmul(xq, wq, 1.0, jnp.ones((N,), jnp.float32),
                            block_m=bm, block_n=bn, block_k=bk)
-        ref = quant_matmul_ref(jnp.asarray(xq), jnp.asarray(wq), sx, sw)
-        # both scale the SAME exact int32 accumulator by the same fp32
-        # factors -> bitwise equality, not tolerance
-        assert jnp.array_equal(got, ref)
+        assert jnp.array_equal(
+            acc, quant_matmul_acc_ref(xq, wq).astype(jnp.float32))
+        # the fp32 dequantize multiplies the same accumulator by sx*sw
+        # in another order than the reference: within a few ULP
+        got = quant_matmul(xq, wq, sx, sw,
+                           block_m=bm, block_n=bn, block_k=bk)
+        ref = quant_matmul_ref(xq, wq, sx, sw)
+        np.testing.assert_allclose(got, ref, rtol=2 ** -21, atol=0)

@@ -12,18 +12,24 @@ import pytest
 
 from repro.core.decomposition import (ALEXNET_STACK, ConvLayer, evaluate,
                                       plan_decomposition)
+from repro.core.model_zoo import network_graph
 from repro.core.schedule import (KERNEL_OP_COLS, OP_C0, OP_VC, OP_VR,
                                  KernelProgram, compile_layer,
                                  compile_network, lower_kernel_program,
                                  partition_waves, validate_kernel_program)
-from repro.core.streaming import (conv2d_direct, maxpool_direct,
+from repro.core.streaming import (compile_graph, conv2d_direct,
+                                  graph_kernel_programs, maxpool_direct,
                                   network_forward_fn, network_operands,
-                                  plan_for_vmem, run_layer_interpreted,
+                                  plan_for_vmem, plan_graph,
+                                  run_layer_interpreted,
                                   run_layer_megakernel, run_layer_streamed)
+from repro.kernels.common import VMEM_CAPACITY, megakernel_vmem, s2d_factor
 from repro.kernels.wave_replay import (expand_grouped, launch_count,
                                        reset_launch_count,
                                        wave_replay_layer, wave_replay_ref)
+from repro.kernels.wave_replay.kernel import wave_replay_raw
 from repro.launch.session import StreamingSession
+from repro.runtime.errors import BudgetExceeded
 
 try:
     import hypothesis
@@ -123,6 +129,28 @@ def test_megakernel_grouped_natural_layout():
     # ... but the megakernel's weight operand is the natural g-x smaller
     kp = lower_kernel_program(_wave(layer, plan))
     assert kp.fan_width == 4 and kp.w_in_kpad == 4
+
+
+@pytest.mark.parametrize("in_c,kernel,folded",
+                         [(3, 5, True), (40, 1, False), (40, 3, False)],
+                         ids=["narrow-folded", "projection-staged",
+                              "wide-staged"])
+def test_megakernel_strided_fold_or_staged(in_c, kernel, folded):
+    """Ungrouped strided layers fold the stride into channels only where
+    a step's folded channels fit one lane tile (a 3-channel stem); wider
+    ones (ResNet's 1x1 stride-2 projections) load strided taps from the
+    lane-tiled window, with no zero weights. Both match the direct
+    conv."""
+    layer = ConvLayer("s", 17, 17, in_c, 16, kernel, stride=2,
+                      pad=kernel // 2)
+    plan = evaluate(layer, 2, 2, 1, 1)
+    kp = lower_kernel_program(_wave(layer, plan))
+    assert (s2d_factor(layer, kp.c_width) == 2) == folded
+    x = jax.random.normal(jax.random.key(4), (2, 17, 17, in_c))
+    w, b = _weights(layer)
+    got = run_layer_streamed(layer, plan, x, w, b, mode="megakernel")
+    ref = conv2d_direct(x, w, 2, kernel // 2) + b
+    assert float(jnp.max(jnp.abs(got - ref))) < 1e-4
 
 
 def test_megakernel_masked_write_zeroes_grid_padding():
@@ -322,13 +350,45 @@ def test_validate_rejects_corrupted_table():
         validate_kernel_program(corrupted)
 
 
+def test_launch_vmem_counts_the_tiled_footprint():
+    """A launch's scoped-VMEM limit comes from ``vmem_bytes``, the tiled
+    footprint it holds (never below the planner's element count) and
+    never above the chip. A compiled launch that needs more VMEM than a
+    v5e core has is refused at trace time with the count in the
+    message: VGG-16's first layer at 224x224 and batch 8, whose 3- and
+    64-channel rows pad to 128 lanes."""
+    def serving_programs(net):
+        g = network_graph(net)
+        return graph_kernel_programs(
+            g, compile_graph(g, plan_graph(g, 128 * 1024)), batch=8)
+    alexnet, vgg = serving_programs("alexnet"), serving_programs("vgg16")
+    for kp in [*alexnet.values(), *vgg.values()]:
+        assert kp.vmem_bytes >= kp.plan_bytes
+    for name, kp in alexnet.items():
+        limit = megakernel_vmem(kp).compiler_params(
+            name, interpret=False).vmem_limit_bytes
+        assert kp.vmem_bytes <= limit <= VMEM_CAPACITY
+    kp = vgg["c1_1"]
+    assert kp.vmem_bytes > VMEM_CAPACITY
+    l = kp.wave.program.layer
+    args = [jax.ShapeDtypeStruct(s, jnp.float32) for s in (
+        (8, kp.pad_h, kp.pad_w, kp.in_c_kpad),
+        (l.kernel, l.kernel, kp.w_in_kpad, kp.out_c_pad),
+        (1, kp.out_c_pad))]
+    table = jax.ShapeDtypeStruct((kp.n_chain, kp.n_tiles, KERNEL_OP_COLS),
+                                 jnp.int32)
+    with pytest.raises(BudgetExceeded, match="c1_1: the kernel holds"):
+        jax.eval_shape(lambda x, w, b, t: wave_replay_raw(
+            kp, x, w, b, t, interpret=False), *args, table)
+
+
 def test_plan_for_vmem_prefers_fewest_steps():
     layer = ALEXNET_STACK[2]        # conv3: 128 KB plan needs 256 waves
     plan = plan_for_vmem(layer, 8 * 2 ** 20, False)
     kp = lower_kernel_program(_wave(layer, plan), relu=True,
                               vmem_budget=8 * 2 ** 20)
     assert kp.n_tiles * kp.n_chain < 256
-    assert kp.vmem_bytes <= 8 * 2 ** 20
+    assert kp.plan_bytes <= 8 * 2 ** 20
     # a tiny budget forces real decomposition again
     tight = plan_for_vmem(layer, 512 * 1024, False)
     kp_tight = lower_kernel_program(_wave(layer, tight), relu=True,

@@ -15,21 +15,6 @@ from repro.models.module import init_params
 from repro.train.steps import init_train_state, make_train_step
 
 
-from conftest import optimization_barrier_differentiable
-
-# pre-existing seed failure, triaged (ISSUE 5 satellite): the pinned
-# jax has no differentiation rule for optimization_barrier, which the
-# loss path uses to pin the bf16 cast before FSDP gathers
-# (src/repro/train/losses.py) — every test that takes grads dies.
-# Applied per grad-taking test (NOT module-wide), so the grad-free
-# tests keep failing loudly on real regressions.
-xfail_no_optbar_grad = pytest.mark.xfail(
-    condition=not optimization_barrier_differentiable(),
-    reason="installed jax cannot differentiate optimization_barrier "
-           "(train/losses.py pins the compute-dtype cast with it); "
-           "needs a newer jax pin",
-    strict=False)
-
 ASSIGNED_DIMS = {  # exact dims from the assignment table
     "gemma3_4b": (34, 2560, 8, 4, 10240, 262144),
     "command_r_35b": (40, 8192, 64, 8, 22528, 256000),
@@ -55,7 +40,6 @@ def test_full_config_dims_match_assignment(arch):
     assert len(cfg.layer_types) == cfg.n_layers
 
 
-@xfail_no_optbar_grad
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_smoke_forward_and_train_step(arch):
     cfg = dataclasses.replace(reduced_config(arch), compute_dtype="float32")

@@ -17,28 +17,11 @@ from repro.train.loop import train_lm
 from repro.train.steps import init_train_state, make_train_step
 
 
-from conftest import optimization_barrier_differentiable
-
-# pre-existing seed failure, triaged (ISSUE 5 satellite): the pinned
-# jax has no differentiation rule for optimization_barrier
-# (src/repro/train/losses.py uses it to pin the bf16 cast), so every
-# grad-taking training-loop test dies at the first step. Applied per
-# grad-taking test (NOT module-wide): the watchdog/restart-policy
-# tests take no grads and keep failing loudly on real regressions.
-xfail_no_optbar_grad = pytest.mark.xfail(
-    condition=not optimization_barrier_differentiable(),
-    reason="installed jax cannot differentiate optimization_barrier "
-           "(train/losses.py pins the compute-dtype cast with it); "
-           "needs a newer jax pin",
-    strict=False)
-
-
 def _cfg():
     return dataclasses.replace(reduced_config("qwen3_1p7b"),
                                compute_dtype="float32")
 
 
-@xfail_no_optbar_grad
 def test_loss_decreases_on_learnable_data():
     cfg = _cfg()
     _, hist = train_lm(cfg, TrainConfig(learning_rate=3e-3), num_steps=30,
@@ -46,7 +29,6 @@ def test_loss_decreases_on_learnable_data():
     assert hist[-1]["loss"] < hist[0]["loss"] * 0.9
 
 
-@xfail_no_optbar_grad
 def test_crash_restart_resumes_from_checkpoint(tmp_path):
     cfg = _cfg()
     tcfg = TrainConfig(learning_rate=1e-3, checkpoint_every=5)
@@ -67,7 +49,6 @@ def test_crash_restart_resumes_from_checkpoint(tmp_path):
     assert steps_after_restart == 12 - 5
 
 
-@xfail_no_optbar_grad
 def test_restart_matches_uninterrupted_run(tmp_path):
     """Determinism: crash+restore reproduces the uninterrupted loss curve."""
     cfg = _cfg()
@@ -86,7 +67,6 @@ def test_restart_matches_uninterrupted_run(tmp_path):
         [h["loss"] for h in clean[4:]], rtol=1e-4)
 
 
-@xfail_no_optbar_grad
 def test_grad_accumulation_matches_single_batch():
     cfg = _cfg()
     params = init_params(T.lm_defs(cfg), jax.random.key(0))
@@ -105,7 +85,6 @@ def test_grad_accumulation_matches_single_batch():
     assert abs(float(m1["loss"]) - float(m2["loss"])) < 1e-4
 
 
-@xfail_no_optbar_grad
 def test_int8_grad_compression_still_converges():
     cfg = _cfg()
     tcfg = TrainConfig(learning_rate=3e-3, accum_steps=2,
