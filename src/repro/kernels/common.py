@@ -1,6 +1,7 @@
 """Shared kernel-launch policy helpers and in-kernel building blocks."""
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 
@@ -24,10 +25,14 @@ class LaunchCounter:
     paper's launch-overhead argument cares about. ``record(...)``
     counts one launch (per-family local count + ``kernel_launches`` /
     ``kernel_launches.<family>`` in the current metrics registry) and
-    returns a ``cat="execute"`` span context to wrap the kernel build,
-    so the execute-phase span count in a trace equals the launch
-    counter by construction. The local count backs the historical
-    ``launch_count()`` / ``reset_launch_count()`` per-family API.
+    returns a context to wrap the kernel build: a ``cat="execute"``
+    span, so the execute-phase span count in a trace equals the launch
+    counter by construction, inside ``jax.named_scope("<kind>:<node>")``,
+    so every op built for the node (its pads, crops and the
+    ``pallas_call``) carries the node in its HLO ``op_name`` and a
+    device trace can charge its time to the node. The local count backs
+    the historical ``launch_count()`` / ``reset_launch_count()``
+    per-family API.
     """
 
     def __init__(self, family: str):
@@ -35,14 +40,22 @@ class LaunchCounter:
         self._count = 0
 
     def record(self, node: str, kind: str):
-        """Count one launch; returns a span context (no-op when tracing
-        is disabled) to wrap the kernel construction."""
+        """Count one launch; returns the named scope and span context
+        (the span a no-op when tracing is disabled) to wrap the kernel
+        construction."""
         self._count += 1
         reg = _metrics.registry()
         reg.counter("kernel_launches").inc()
         reg.counter(f"kernel_launches.{self.family}").inc()
-        return _trace.span(f"{kind}:{node}", cat="execute",
-                           family=self.family, node=node, kind=kind)
+        return self._scope(node, kind)
+
+    @contextlib.contextmanager
+    def _scope(self, node: str, kind: str):
+        name = f"{kind}:{node}"
+        with jax.named_scope(name), \
+                _trace.span(name, cat="execute", family=self.family,
+                            node=node, kind=kind):
+            yield
 
     def count(self) -> int:
         return self._count

@@ -125,8 +125,10 @@ def cnn_main(args):
     print(sess.describe())
     if tracer is not None:
         n = write_chrome_trace(args.trace_out, tracer)
+        dispatches = sum(s.name == "dispatch" for s in tracer.spans("run"))
         print(f"trace: {n} events -> {args.trace_out} "
-              f"(execute spans={tracer.span_count('execute')}); open in "
+              f"(trace-time execute spans={tracer.span_count('execute')}, "
+              f"dispatch spans={dispatches}); open in "
               f"chrome://tracing or ui.perfetto.dev")
     if args.metrics:
         print(render_metrics())
@@ -201,12 +203,14 @@ def main():
                          "retry counters")
     ap.add_argument("--trace-out", default=None,
                     help="write a Chrome/Perfetto trace_events JSON of "
-                         "the session (plan/lower/compile/execute spans, "
-                         "request lifecycle) to this path (--cnn)")
+                         "the session (plan/lower/compile spans, kernel "
+                         "launch spans, submit/flush/run_batch/result "
+                         "spans) to this path (--cnn)")
     ap.add_argument("--metrics", action="store_true",
                     help="after serving, print the metrics registry as "
                          "plain text: kernel launches, cache hit/miss, "
-                         "queue depth, latency histogram (--cnn)")
+                         "queue depth, queue-wait histogram, host syncs "
+                         "(--cnn)")
     ap.add_argument("--precision", choices=("fp32", "int8"),
                     default="fp32",
                     help="int8 calibrates the stack (PTQ, a few random "

@@ -342,7 +342,21 @@ class StreamingSession:
 
         The error names the expected spec — a serving boundary that
         answers garbage shapes with XLA trace errors (or worse, a
-        silently mis-addressed schedule) is not a boundary."""
+        silently mis-addressed schedule) is not a boundary. Recorded as
+        a ``check_input`` span; its finiteness test waits for the device
+        (one ``host_sync``)."""
+        with _trace.span("check_input", cat="request", batched=batched):
+            self._check_input(x, batched)
+
+    @staticmethod
+    def _host_sync():
+        """Count one wait of the host for the device's answer in
+        ``session.host_syncs``; returns its ``host_sync`` span context
+        to wrap the wait."""
+        _metrics.registry().counter("session.host_syncs").inc()
+        return _trace.span("host_sync", cat="request")
+
+    def _check_input(self, x, batched: bool) -> None:
         H, W, C = self.graph.in_shape
         spec = (f"(B, {H}, {W}, {C})" if batched else f"({H}, {W}, {C})")
         what = "run_batch" if batched else "submit"
@@ -360,8 +374,11 @@ class StreamingSession:
             raise ValueError(
                 f"{self.graph.name}.{what}: expected {spec} "
                 f"{self.graph.dtype} input, got dtype {dt}")
-        if jnp.issubdtype(dt, jnp.floating) \
-                and not bool(jnp.isfinite(x).all()):
+        if not jnp.issubdtype(dt, jnp.floating):
+            return
+        with self._host_sync():
+            finite = bool(jnp.isfinite(x).all())
+        if not finite:
             raise ValueError(
                 f"{self.graph.name}.{what}: input contains NaN/Inf — "
                 f"refusing to serve (expected finite {spec} "
@@ -381,14 +398,16 @@ class StreamingSession:
         immediately, so it can never poison later calls. With
         ``guard=`` set, the output is checked post-execution and a
         tripped batch re-runs on the reference path."""
-        if self.validate_inputs:
-            self.check_input(x, batched=True)
-        key = self._exec_key(x.shape, x.dtype)
         reg = _metrics.registry()
         attempts = 0
         with _trace.use_tracer(self.tracer), \
-                _trace.span("run_batch", cat="run", batch=int(x.shape[0]),
-                            mode=self.mode, graph=self.graph.name):
+                _trace.span("run_batch", cat="run", mode=self.mode,
+                            graph=self.graph.name) as sp:
+            if self.validate_inputs:
+                self.check_input(x, batched=True)
+            if sp is not None:
+                sp.attrs["batch"] = int(x.shape[0])
+            key = self._exec_key(x.shape, x.dtype)
             while True:
                 fresh = key not in self._executables
                 fn = self._executable(key)
@@ -397,8 +416,9 @@ class StreamingSession:
                     reg.counter("session.calls").inc()
                     # the first call of a fresh executable traces +
                     # compiles (jit is lazy) — attribute it to the
-                    # compile phase; steady-state calls are execution
-                    with _trace.span("compile" if fresh else "execute",
+                    # compile phase; a steady-state call returns once
+                    # the batch is enqueued on the device (dispatch)
+                    with _trace.span("compile" if fresh else "dispatch",
                                      cat="compile" if fresh else "run"):
                         y = fn(x, self.weights, self._ops)
                     break
@@ -420,11 +440,11 @@ class StreamingSession:
                 from repro.runtime.guard import guarded_output
                 weights = self.weights if self.precision == "fp32" \
                     else None
-                y, cause = guarded_output(self.resolved, y, x, weights,
-                                          self.guard,
-                                          raw_int8=self._guard_raw,
-                                          conv_fn=self._conv_fn,
-                                          conv_backend=self._conv_backend)
+                with self._host_sync():
+                    y, cause = guarded_output(
+                        self.resolved, y, x, weights, self.guard,
+                        raw_int8=self._guard_raw, conv_fn=self._conv_fn,
+                        conv_backend=self._conv_backend)
                 if cause is not None:
                     self.guard_trips += 1
                     reg.counter("session.guard_trips").inc()
@@ -448,12 +468,14 @@ class StreamingSession:
         is a per-request budget in seconds: a request still queued when
         it expires is dropped at the next flush and its ``result()``
         raises ``DeadlineExceeded``."""
-        if self.validate_inputs:
-            self.check_input(image, batched=False)
-        elif getattr(image, "ndim", None) != 3:
-            raise ValueError(f"submit() wants (H, W, C), got {image.shape}")
-        reg = _metrics.registry()
-        with _trace.use_tracer(self.tracer):
+        with _trace.use_tracer(self.tracer), \
+                _trace.span("submit", cat="request") as sp:
+            if self.validate_inputs:
+                self.check_input(image, batched=False)
+            elif getattr(image, "ndim", None) != 3:
+                raise ValueError(
+                    f"submit() wants (H, W, C), got {image.shape}")
+            reg = _metrics.registry()
             if self.max_pending is not None \
                     and len(self._pending) >= self.max_pending:
                 self.shed += 1
@@ -470,8 +492,9 @@ class StreamingSession:
             expiry = None if deadline is None else self._clock() + deadline
             self._pending.append((ticket, image, expiry, self._clock()))
             reg.gauge("session.queue_depth").set(len(self._pending))
-            _trace.event("enqueue", cat="request", ticket=ticket,
-                         queue_depth=len(self._pending))
+            if sp is not None:
+                sp.attrs.update(ticket=ticket,
+                                queue_depth=len(self._pending))
             if len(self._pending) >= self.max_batch:
                 self.flush()
         return ticket
@@ -481,13 +504,16 @@ class StreamingSession:
 
         Requests whose deadline already passed are dropped here —
         spending a batch slot on an answer nobody is waiting for only
-        delays the live requests behind it."""
+        delays the live requests behind it. Each live request's time in
+        the queue (submit to the start of this flush) goes to the
+        ``session.queue_wait_s`` histogram, and its sum and count to
+        the ``flush`` span (``wait_s_sum``, ``n``)."""
         if not self._pending:
             return
         reg = _metrics.registry()
         with _trace.use_tracer(self.tracer), \
                 _trace.span("flush", cat="request",
-                            pending=len(self._pending)):
+                            pending=len(self._pending)) as sp:
             now = self._clock()
             live = []
             for t, im, exp, sub in self._pending:
@@ -501,25 +527,30 @@ class StreamingSession:
                     live.append((t, im, sub))
             self._pending.clear()
             reg.gauge("session.queue_depth").set(0)
+            waits = [max(0.0, now - sub) for _, _, sub in live]
+            wait_hist = reg.histogram("session.queue_wait_s")
+            for w in waits:
+                wait_hist.observe(w)
+            if sp is not None:
+                sp.attrs.update(wait_s_sum=sum(waits), n=len(waits))
             if not live:
                 return
-            tickets = [t for t, _, _ in live]
-            imgs = jnp.stack([im for _, im, _ in live])
-            n = imgs.shape[0]
+            with _trace.span("stack", cat="request", n=len(live)):
+                imgs = jnp.stack([im for _, im, _ in live])
+                n = imgs.shape[0]
+                if n < self.max_batch:
+                    # zero-pad to the session batch so the same
+                    # executable serves partial flushes; padded rows are
+                    # discarded below
+                    fill = jnp.zeros(
+                        (self.max_batch - n,) + imgs.shape[1:], imgs.dtype)
+                    imgs = jnp.concatenate([imgs, fill])
             reg.histogram("session.batch_fill_ratio") \
                .observe(n / self.max_batch)
-            if n < self.max_batch:
-                # zero-pad to the session batch so the same executable
-                # serves partial flushes; padded rows are discarded below
-                fill = jnp.zeros((self.max_batch - n,) + imgs.shape[1:],
-                                 imgs.dtype)
-                imgs = jnp.concatenate([imgs, fill])
             out = self.run_batch(imgs)
-            done = self._clock()
-            lat = reg.histogram("session.request_latency_s")
-            for i, (t, _, sub) in enumerate(live):
-                self._results[t] = out[i]
-                lat.observe(max(0.0, done - sub))
+            with _trace.span("split", cat="request", n=len(live)):
+                for i, (t, _, _) in enumerate(live):
+                    self._results[t] = out[i]
 
     def result(self, ticket: int) -> jax.Array:
         """Fetch (and forget) one request's output; flushes if pending.
@@ -528,19 +559,20 @@ class StreamingSession:
         clients mid-flight must ``discard()`` abandoned tickets or the
         result map grows without bound. A ticket dropped past its
         deadline raises ``DeadlineExceeded``."""
-        if ticket not in self._results:
-            self.flush()
-        if ticket in self._expired:
-            self._expired.discard(ticket)
-            raise DeadlineExceeded(
-                f"ticket {ticket}: dropped — its deadline passed while "
-                f"queued")
-        if ticket not in self._results:
-            raise KeyError(
-                f"ticket {ticket}: unknown, already fetched, or discarded")
-        with _trace.use_tracer(self.tracer):
-            _trace.event("reply", cat="request", ticket=ticket)
-        return self._results.pop(ticket)
+        with _trace.use_tracer(self.tracer), \
+                _trace.span("result", cat="request", ticket=ticket):
+            if ticket not in self._results:
+                self.flush()
+            if ticket in self._expired:
+                self._expired.discard(ticket)
+                raise DeadlineExceeded(
+                    f"ticket {ticket}: dropped — its deadline passed while "
+                    f"queued")
+            if ticket not in self._results:
+                raise KeyError(
+                    f"ticket {ticket}: unknown, already fetched, or "
+                    f"discarded")
+            return self._results.pop(ticket)
 
     def discard(self, ticket: int) -> None:
         """Drop a pending or completed request without fetching it."""

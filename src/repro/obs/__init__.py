@@ -2,8 +2,8 @@
 Chrome/Perfetto export.
 
 - ``obs.trace``   — nested thread-safe spans around plan / lower /
-  compile / execute, kernel launches (trace time), autotune probes,
-  and serving request lifecycles; opt-in with a no-op fast path.
+  compile, kernel launches (trace time), autotune probes, and the
+  serving calls; opt-in with a no-op fast path.
 - ``obs.metrics`` — registry-scoped counters / gauges / fixed-bucket
   histograms replacing module-level global tallies.
 - ``obs.export``  — ``trace_events`` JSON (``serve.py --trace-out``)

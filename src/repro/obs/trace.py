@@ -4,9 +4,10 @@ The paper argues entirely in measured quantities — per-layer cycle
 counts, DRAM accesses per decomposition choice (Tables 1/2) — so the
 repro needs a first-class way to *see* where a forward pass spends its
 life. A ``Tracer`` records nested spans around the resolver stages
-(plan -> lower -> compile -> execute), per-node / per-fused-chain
-kernel launches (trace time, same semantics as the launch counters),
-autotune candidate probes, and serving request lifecycles. Spans carry
+(plan -> lower -> compile), per-node / per-fused-chain kernel
+launches (trace time, same semantics as the launch counters), autotune
+candidate probes, and the serving calls (submit, flush, run_batch,
+result, and the input checks and device waits inside them). Spans carry
 monotonic ``perf_counter_ns`` timestamps and structured attributes and
 export losslessly to Chrome/Perfetto ``trace_events`` JSON
 (obs/export.py).
@@ -16,7 +17,8 @@ module-level ``span(...)`` / ``event(...)`` helpers, which read one
 module global and return a shared ``nullcontext`` (or do nothing) when
 no tracer is active — the disabled path is a single load+compare, so
 leaving the hooks compiled in costs nothing measurable (gated <= 2%
-on the AlexNet megakernel smoke bench). Activate with
+on the AlexNet megakernel smoke bench, a CPU interpret-mode timing).
+Activate with
 ``set_tracer(t)`` or scoped via ``use_tracer(t)``; ``StreamingSession
 (tracer=...)`` and ``serve.py --trace-out`` do this for you.
 
@@ -35,14 +37,18 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 
 class Span:
-    """One closed-or-open interval: name, category, [start, end) ns."""
+    """One closed-or-open interval: name, category, [start, end) ns.
+
+    Made by ``Tracer.span`` and entered with ``with``: entering gives it
+    its id, parent, thread and start; leaving gives it its end."""
 
     __slots__ = ("id", "parent_id", "name", "cat", "start_ns", "end_ns",
-                 "tid", "attrs")
+                 "tid", "attrs", "_tracer", "_open_stack")
 
     def __init__(self, id: int, parent_id: Optional[int], name: str,
                  cat: str, start_ns: int, tid: int,
-                 attrs: Dict[str, object]):
+                 attrs: Dict[str, object],
+                 tracer: Optional["Tracer"] = None):
         self.id = id
         self.parent_id = parent_id
         self.name = name
@@ -51,6 +57,19 @@ class Span:
         self.end_ns: Optional[int] = None   # set at close
         self.tid = tid
         self.attrs = attrs
+        self._tracer = tracer
+        self._open_stack: Optional[list] = None
+
+    def __enter__(self) -> "Span":
+        return self._tracer._open(self)
+
+    def __exit__(self, kind, e, tb) -> bool:
+        if e is not None:
+            self.attrs["error"] = f"{kind.__name__}: {e}"
+        self.end_ns = time.perf_counter_ns()
+        stack, self._open_stack, self._tracer = self._open_stack, None, None
+        stack.pop()     # a closed span holds no tracer: no cycle to collect
+        return False
 
     @property
     def dur_ns(self) -> Optional[int]:
@@ -89,30 +108,27 @@ class Tracer:
             st = self._local.stack = []
         return st
 
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str = "", **attrs):
-        """Open a nested span; yields the ``Span`` so callers can attach
-        attributes mid-flight. Exceptions close the span with an
-        ``error`` attribute and propagate."""
+    def span(self, name: str, cat: str = "", **attrs) -> Span:
+        """A nested span to enter with ``with``; it yields the ``Span``
+        so callers can attach attributes mid-flight. Exceptions close
+        the span with an ``error`` attribute and propagate. A class
+        context, not a generator: it costs a few microseconds a span."""
+        return Span(0, None, name, cat, 0, 0, attrs, self)
+
+    def _open(self, sp: Span) -> Span:
         stack = self._stack()
-        parent = stack[-1].id if stack else None
-        sp = Span(next(self._ids), parent, name, cat,
-                  time.perf_counter_ns(), threading.get_ident(),
-                  dict(attrs))
+        sp.parent_id = stack[-1].id if stack else None
+        sp.id = next(self._ids)
+        sp.tid = threading.get_ident()
         with self._lock:
             if len(self._spans) >= self.max_spans:
                 self.dropped += 1
             else:
                 self._spans.append(sp)
         stack.append(sp)
-        try:
-            yield sp
-        except BaseException as e:
-            sp.attrs["error"] = f"{type(e).__name__}: {e}"
-            raise
-        finally:
-            sp.end_ns = time.perf_counter_ns()
-            stack.pop()
+        sp._open_stack = stack
+        sp.start_ns = time.perf_counter_ns()
+        return sp
 
     def event(self, name: str, cat: str = "", **attrs) -> None:
         """Record one instant event (no duration)."""

@@ -46,6 +46,20 @@ def test_spans_nest_and_carry_attrs():
     assert outer.start_ns <= inner.start_ns <= inner.end_ns <= outer.end_ns
 
 
+def test_span_is_recorded_when_entered():
+    """``Tracer.span`` hands back the span; entering it records it and
+    gives it its parent and start, leaving it gives it its end."""
+    t = Tracer()
+    with t.span("outer") as outer:
+        inner = t.span("inner", cat="run", n=2)
+        assert t.spans() == [outer] and inner.end_ns is None
+        with inner as got:
+            assert got is inner and inner.parent_id == outer.id
+            assert inner.end_ns is None
+    assert t.spans() == [outer, inner]
+    assert inner.attrs == {"n": 2} and inner.dur_ns >= 0
+
+
 def test_span_closes_with_error_attribute_on_exception():
     """A failing node still closes its span — with an ``error``
     attribute — so traces of failing runs are complete."""
@@ -153,10 +167,12 @@ def test_histogram_buckets_and_stats():
 def test_render_metrics_plain_text():
     reg = MetricsRegistry()
     reg.counter("kernel_launches.wave_replay").inc(7)
-    reg.histogram("session.request_latency_s").observe(0.002)
+    reg.counter("session.host_syncs").inc(9)
+    reg.histogram("session.queue_wait_s").observe(0.002)
     text = render_metrics(reg)
     assert "kernel_launches.wave_replay 7" in text
-    assert "session.request_latency_s count=1" in text
+    assert "session.host_syncs 9" in text
+    assert "session.queue_wait_s count=1" in text
 
 
 def test_launch_counter_shims_and_registry_feed():
@@ -332,16 +348,20 @@ def test_session_lifecycle_spans_and_health_metrics():
         h = sess.health()
         snap = reg.snapshot()
     # plan/lower spans from construction, run_batch + flush spans from
-    # serving, enqueue/reply instants per request — all on one tracer
+    # serving, a submit and a result span per request — all on one
+    # tracer, and no instants
     assert t.span_count("plan") >= 1
     assert t.span_count("lower") >= 1
     runs = [s.name for s in t.spans("run")]
     assert runs.count("run_batch") == 2
-    assert [s.name for s in t.spans("request")] == ["flush", "flush"]
-    enq = [e for e in t.events("request") if e["name"] == "enqueue"]
-    assert [e["attrs"]["ticket"] for e in enq] == [tk0, tk1, tk2]
-    replies = [e for e in t.events("request") if e["name"] == "reply"]
-    assert len(replies) == 3
+    assert [s.name for s in t.spans("request")
+            if s.name == "flush"] == ["flush", "flush"]
+    subs = [s for s in t.spans("request") if s.name == "submit"]
+    assert [s.attrs["ticket"] for s in subs] == [tk0, tk1, tk2]
+    assert [s.attrs["queue_depth"] for s in subs] == [1, 2, 1]
+    replies = [s for s in t.spans("request") if s.name == "result"]
+    assert [s.attrs["ticket"] for s in replies] == [tk0, tk1, tk2]
+    assert t.events("request") == []
     # first run_batch compiled, second hit the session executable cache
     kinds = [s.name for s in t.spans("compile")]
     assert kinds.count("compile") >= 1
@@ -351,8 +371,158 @@ def test_session_lifecycle_spans_and_health_metrics():
     fill = snap["histograms"]["session.batch_fill_ratio"]
     assert fill["count"] == 2
     assert fill["min"] == 0.5 and fill["max"] == 1.0
-    assert snap["histograms"]["session.request_latency_s"]["count"] == 3
+    assert snap["histograms"]["session.queue_wait_s"]["count"] == 3
+    assert "session.request_latency_s" not in snap["histograms"]
+    # one isfinite sync per submit and per batch
+    assert h["metrics"]["counters"]["session.host_syncs"] == 3 + 2
     assert snap["gauges"]["session.queue_depth"] == 0
+
+
+def _served(max_batch, batches, tracer=None, **kw):
+    """A tiny scan-mode session that served ``batches`` full batches of
+    single-image requests, every result fetched."""
+    from repro.launch.session import StreamingSession
+    g = _tiny_graph()
+    ws = init_graph_weights(g, jax.random.key(1))
+    sess = StreamingSession.for_graph(g, ws, sram_budget=64 * 1024,
+                                      max_batch=max_batch, mode="scan",
+                                      tracer=tracer, **kw)
+    imgs = jax.random.normal(jax.random.key(2),
+                             (max_batch * batches,) + g.in_shape)
+    tickets = [sess.submit(im) for im in imgs]
+    jax.block_until_ready([sess.result(tk) for tk in tickets])
+    return sess
+
+
+def _path(span, by_id):
+    names = []
+    while span is not None:
+        names.append(span.name)
+        span = by_id.get(span.parent_id)
+    return "/".join(reversed(names))
+
+
+@pytest.mark.parametrize("max_batch,syncs", [(8, 9), (1, 2)])
+def test_served_session_records_the_span_tree(max_batch, syncs):
+    """Serving records submit > check_input > host_sync per request and
+    flush > stack, run_batch > check_input > host_sync, dispatch, split
+    per batch: max_batch + 1 host syncs per batch, also in the
+    counter."""
+    t = Tracer()
+    with use_registry(MetricsRegistry()) as reg:
+        _served(max_batch, 3, tracer=t)
+    serving = [s for s in t.spans()
+               if s.cat in ("request", "run", "compile")]
+    by_id = {s.id: s for s in t.spans()}
+    paths = [_path(s, by_id) for s in serving]
+    per_request = ["submit", "submit/check_input",
+                   "submit/check_input/host_sync", "result"]
+    per_batch = ["submit/flush", "submit/flush/stack",
+                 "submit/flush/split", "submit/flush/run_batch",
+                 "submit/flush/run_batch/check_input",
+                 "submit/flush/run_batch/check_input/host_sync"]
+    for p in per_request:
+        assert paths.count(p) == 3 * max_batch, p
+    for p in per_batch:
+        assert paths.count(p) == 3, p
+    # the first batch compiles, the two after it dispatch
+    assert paths.count("submit/flush/run_batch/compile") == 1
+    assert paths.count("submit/flush/run_batch/dispatch") == 2
+    assert "execute" not in {s.name for s in serving}
+    assert set(paths) == set(per_request + per_batch) | {
+        "submit/flush/run_batch/compile", "submit/flush/run_batch/dispatch"}
+    syncs_seen = paths.count("submit/check_input/host_sync") \
+        + paths.count("submit/flush/run_batch/check_input/host_sync")
+    assert syncs_seen == 3 * syncs
+    assert reg.counter("session.host_syncs").value == 3 * syncs
+    # every span closed inside its parent
+    for s in serving:
+        parent = by_id.get(s.parent_id)
+        if parent is not None:
+            assert parent.start_ns <= s.start_ns <= s.end_ns \
+                <= parent.end_ns
+
+
+def test_untraced_serving_records_nothing(monkeypatch):
+    """With no tracer on the session or the process, serving opens no
+    span: ``span()`` hands back the one shared null context."""
+    def refuse(*a, **kw):
+        raise AssertionError("a span was opened with tracing off")
+
+    monkeypatch.setattr(obs_trace.Tracer, "span", refuse)
+    monkeypatch.setattr(obs_trace.Tracer, "event", refuse)
+    assert current_tracer() is None
+    sess = _served(2, 2)
+    assert sess.tracer is None and current_tracer() is None
+    assert obs_trace.span("submit", cat="request") is obs_trace._NULL_CM
+    assert obs_trace.span("host_sync") is obs_trace.span("dispatch")
+    # the always-on counters still count
+    assert obs_metrics.registry().counter("session.host_syncs").value \
+        == 2 * 3
+
+
+def test_queue_wait_counts_each_live_request_once():
+    """``session.queue_wait_s`` observes submit -> flush start for each
+    live request (not the expired one); the flush span carries the
+    same sum and count."""
+    now = [100.0]
+    t = Tracer()
+    with use_registry(MetricsRegistry()) as reg:
+        from repro.launch.session import StreamingSession
+        g = _tiny_graph()
+        sess = StreamingSession.for_graph(
+            g, init_graph_weights(g, jax.random.key(1)),
+            sram_budget=64 * 1024, max_batch=4, mode="scan", tracer=t,
+            clock=lambda: now[0])
+        imgs = jax.random.normal(jax.random.key(2), (3,) + g.in_shape)
+        sess.submit(imgs[0])                       # waits 0.5 s
+        now[0] += 0.25
+        sess.submit(imgs[1], deadline=0.1)         # expires
+        sess.submit(imgs[2])                       # waits 0.25 s
+        now[0] += 0.25
+        sess.flush()
+        hist = reg.histogram("session.queue_wait_s")
+        assert hist.count == 2
+        assert hist.sum == pytest.approx(0.75)
+        assert hist.snapshot()["max"] == pytest.approx(0.5)
+    flush, = [s for s in t.spans("request") if s.name == "flush"]
+    assert flush.attrs["n"] == 2
+    assert flush.attrs["wait_s_sum"] == pytest.approx(0.75)
+    assert sess.deadline_expired == 1
+
+
+def test_guard_check_is_a_host_sync():
+    """With ``guard`` set, the post-execution check waits on the device:
+    one more host_sync per batch, inside run_batch."""
+    t = Tracer()
+    with use_registry(MetricsRegistry()) as reg:
+        _served(2, 1, tracer=t, guard=True)
+    by_id = {s.id: s for s in t.spans()}
+    syncs = [_path(s, by_id) for s in t.spans() if s.name == "host_sync"]
+    assert syncs.count("submit/flush/run_batch/host_sync") == 1
+    assert len(syncs) == 2 + 1 + 1
+    assert reg.counter("session.host_syncs").value == 4
+
+
+def test_serving_hlo_names_each_conv_node():
+    """Every megakernel launch is built under ``named_scope("megakernel:
+    <node>")``: the serving executable's HLO carries each node in its
+    ``op_name`` metadata, which a device trace can read."""
+    from repro.core.decomposition import ConvLayer
+    from repro.launch.session import StreamingSession
+    layers = (ConvLayer("conv1", 8, 8, 3, 4, 3, stride=1, pad=1),
+              ConvLayer("conv2", 8, 8, 4, 4, 3, stride=1, pad=1))
+    g = chain_graph(layers, name="tiny_named")
+    sess = StreamingSession.for_graph(
+        g, init_graph_weights(g, jax.random.key(1)),
+        sram_budget=64 * 1024, max_batch=2, mode="megakernel")
+    x = jax.ShapeDtypeStruct((2,) + g.in_shape, jnp.float32)
+    key = sess._exec_key(x.shape, x.dtype)
+    lowered = sess._executable(key).lower(x, sess.weights, sess._ops)
+    text = lowered.as_text(debug_info=True)
+    assert "megakernel:conv1" in text and "megakernel:conv2" in text
+    hlo = lowered.compile().as_text()
+    assert 'op_name="jit(traced)/megakernel:conv2/' in hlo
 
 
 def test_executor_cache_metrics():
