@@ -25,6 +25,14 @@ Serving modes:
     independent single-image requests are coalesced into one
     ``max_batch``-sized compiled call (partial batches are zero-padded
     to keep the batch shape — and therefore the executable — stable).
+    The queue goes to the device once per batch, not per request: a
+    host (``numpy``) frame is checked on the host at ``submit`` and
+    stays there until its batch is whole; a batch of host frames is
+    stacked and padded in numpy and put on the device in one upload
+    (counted in ``session.host_batches``). A ``jax.Array`` frame is
+    checked on the device (one ``host_sync``) and its batch stacked
+    there. ``flush`` does not re-check the batch it built, and one
+    jitted unstack per output shape hands every request its row.
 
 DESIGN.md §2 maps this onto the paper's control path in detail.
 """
@@ -36,6 +44,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.decomposition import ConvLayer, plan_decomposition
 from repro.core.graph import NetworkGraph, chain_graph, conv_keyed
@@ -45,6 +54,11 @@ from repro.core.streaming import (compile_graph, graph_forward_fn,
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 from repro.runtime.errors import DeadlineExceeded, Overloaded
+
+
+# every row of a batch's output in one call; jit builds it once per
+# output shape (the warm-up's flush)
+_unstack = jax.jit(jnp.unstack)
 
 
 class StreamingSession:
@@ -263,12 +277,14 @@ class StreamingSession:
         self.compile_count = 0          # traces performed (the spy)
         self.calls = 0                  # compiled-executable invocations
         # micro-batch queue state:
-        # (ticket, image, expiry | None, submitted_at)
+        # (ticket, image (host or device), expiry | None, submitted_at)
         self._pending: List[
-            Tuple[int, jax.Array, Optional[float], float]] = []
+            Tuple[int, "np.ndarray | jax.Array", Optional[float],
+                  float]] = []
         self._results: Dict[int, jax.Array] = {}
         self._expired: set = set()
         self._next_ticket = 0
+        self._flushed = None            # the batch flush built, as it runs
 
     def _conv_dict(self, items, what: str):
         return conv_keyed(self.graph, items, what)
@@ -343,7 +359,8 @@ class StreamingSession:
         The error names the expected spec — a serving boundary that
         answers garbage shapes with XLA trace errors (or worse, a
         silently mis-addressed schedule) is not a boundary. Recorded as
-        a ``check_input`` span; its finiteness test waits for the device
+        a ``check_input`` span. A host (``numpy``) input is tested on
+        the host; a device input's finiteness test waits for the device
         (one ``host_sync``)."""
         with _trace.span("check_input", cat="request", batched=batched):
             self._check_input(x, batched)
@@ -367,7 +384,7 @@ class StreamingSession:
                 f"{self.graph.name}.{what}: expected {spec} "
                 f"{self.graph.dtype} input, got shape "
                 f"{tuple(getattr(x, 'shape', ()))}")
-        dt = jnp.asarray(x).dtype
+        dt = x.dtype
         ok = (jnp.issubdtype(dt, jnp.floating)
               or (self.precision == "int8" and dt == jnp.int8))
         if not ok:
@@ -376,8 +393,12 @@ class StreamingSession:
                 f"{self.graph.dtype} input, got dtype {dt}")
         if not jnp.issubdtype(dt, jnp.floating):
             return
-        with self._host_sync():
-            finite = bool(jnp.isfinite(x).all())
+        if isinstance(x, np.ndarray):
+            # nothing waits on the device: no host_sync
+            finite = bool(np.isfinite(x).all())
+        else:
+            with self._host_sync():
+                finite = bool(jnp.isfinite(x).all())
         if not finite:
             raise ValueError(
                 f"{self.graph.name}.{what}: input contains NaN/Inf — "
@@ -397,13 +418,15 @@ class StreamingSession:
         backoff; a failed compile is evicted from the executable cache
         immediately, so it can never poison later calls. With
         ``guard=`` set, the output is checked post-execution and a
-        tripped batch re-runs on the reference path."""
+        tripped batch re-runs on the reference path. The batch ``flush``
+        built is not checked again: each row was checked at ``submit``
+        and the pad is zeros."""
         reg = _metrics.registry()
         attempts = 0
         with _trace.use_tracer(self.tracer), \
                 _trace.span("run_batch", cat="run", mode=self.mode,
                             graph=self.graph.name) as sp:
-            if self.validate_inputs:
+            if self.validate_inputs and x is not self._flushed:
                 self.check_input(x, batched=True)
             if sp is not None:
                 sp.attrs["batch"] = int(x.shape[0])
@@ -535,22 +558,36 @@ class StreamingSession:
                 sp.attrs.update(wait_s_sum=sum(waits), n=len(waits))
             if not live:
                 return
-            with _trace.span("stack", cat="request", n=len(live)):
-                imgs = jnp.stack([im for _, im, _ in live])
-                n = imgs.shape[0]
-                if n < self.max_batch:
-                    # zero-pad to the session batch so the same
-                    # executable serves partial flushes; padded rows are
-                    # discarded below
-                    fill = jnp.zeros(
-                        (self.max_batch - n,) + imgs.shape[1:], imgs.dtype)
-                    imgs = jnp.concatenate([imgs, fill])
+            frames = [im for _, im, _ in live]
+            n = len(frames)
+            # zero-pad to the session batch so the same executable
+            # serves partial flushes; padded rows are discarded below
+            with _trace.span("stack", cat="request", n=n):
+                if all(isinstance(im, np.ndarray) for im in frames):
+                    # host frames: stack and pad on the host, one upload
+                    batch = np.empty((self.max_batch,) + frames[0].shape,
+                                     np.result_type(*frames))
+                    np.stack(frames, out=batch[:n])
+                    batch[n:] = 0
+                    imgs = jax.device_put(batch)
+                    reg.counter("session.host_batches").inc()
+                else:
+                    imgs = jnp.stack(frames)
+                    if n < self.max_batch:
+                        fill = jnp.zeros((self.max_batch - n,)
+                                         + imgs.shape[1:], imgs.dtype)
+                        imgs = jnp.concatenate([imgs, fill])
             reg.histogram("session.batch_fill_ratio") \
                .observe(n / self.max_batch)
-            out = self.run_batch(imgs)
-            with _trace.span("split", cat="request", n=len(live)):
-                for i, (t, _, _) in enumerate(live):
-                    self._results[t] = out[i]
+            self._flushed = imgs
+            try:
+                out = self.run_batch(imgs)
+            finally:
+                self._flushed = None
+            with _trace.span("split", cat="request", n=n):
+                rows = _unstack(out)
+                for (t, _, _), row in zip(live, rows):
+                    self._results[t] = row
 
     def result(self, ticket: int) -> jax.Array:
         """Fetch (and forget) one request's output; flushes if pending.
