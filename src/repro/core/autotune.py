@@ -37,7 +37,8 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.graph import INPUT, NetworkGraph, conv_keyed
+from repro.core.graph import (INPUT, NetworkGraph, conv_keyed,
+                              refuse_norm_gelu)
 from repro.core.schedule import DEFAULT_VMEM_BUDGET
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
@@ -195,6 +196,7 @@ def resolve_plan(graph: NetworkGraph, programs, node_modes,
                                       _normalize_mode)
     from repro.runtime.fallback import ResolvedGraph
 
+    refuse_norm_gelu(graph, "the autotuner")
     programs = conv_keyed(graph, programs, "programs")
     node_modes = OrderedDict(node_modes)
     quantized = precision == "int8"
@@ -208,8 +210,8 @@ def resolve_plan(graph: NetworkGraph, programs, node_modes,
         if quantized and m not in ("graphkernel", "megakernel"):
             raise ValueError(f"{n.name}: int8 has no {m!r} datapath")
         modes[n.name] = m
-    kprogs = {name: _graph_kernel_program(programs[name], epi[name][0],
-                                          epi[name][1] is not None,
+    kprogs = {name: _graph_kernel_program(programs[name], epi[name].act,
+                                          epi[name].residual is not None,
                                           vmem_budget, batch)
               for name, m in modes.items()
               if m in ("graphkernel", "megakernel")}
@@ -227,8 +229,8 @@ def resolve_plan(graph: NetworkGraph, programs, node_modes,
             continue
         specs = [ChainNodeSpec(name=k, kp=kprogs[k],
                                in_value=by_name[k].inputs[0],
-                               out_value=epi[k][2],
-                               residual_value=epi[k][1])
+                               out_value=epi[k].out,
+                               residual_value=epi[k].residual)
                  for k in c.convs]
         gkps[c.convs[0]] = lower_graph_kernel(
             specs, quantized=quantized,
@@ -296,6 +298,7 @@ def tune_graph(graph: NetworkGraph, programs, weights, x: jax.Array,
     the calibrated ``qgraph`` and ignores ``weights``. ``x`` fixes the
     batch shape the measurement is valid for (= the cache key's batch).
     """
+    refuse_norm_gelu(graph, "the autotuner")
     programs = conv_keyed(graph, programs, "programs")
     batch = int(x.shape[0])
     if cache is not None:

@@ -8,11 +8,12 @@ layer topology over one set of SRAM banks. This module is the software
 equivalent: the implicit ``Sequence[ConvLayer]`` contract the executors
 used to thread around is promoted to an explicit graph IR —
 
-  * **nodes** are ops: ``conv`` (a planned, streamed CONV(+POOL) layer,
-    optionally with a fused ReLU) and ``add`` (the residual
-    accumulation-buffer add, optionally with a fused ReLU). Projection
-    shortcuts are ordinary 1x1 ``conv`` nodes — the schedule treats
-    them exactly like any other streamed conv.
+  * **nodes** are ops: ``conv`` (a planned, streamed CONV(+POOL) layer),
+    ``add`` (the residual accumulation-buffer add) and ``norm`` (a
+    LayerNorm over the channel axis of one value, eps 1e-6), each
+    with an activation kind: ``"relu"``, ``"gelu"`` (exact, erf) or
+    ``None``. Projection shortcuts are ordinary 1x1 ``conv`` nodes —
+    the schedule treats them exactly like any other streamed conv.
   * **edges** are values: every node produces one named activation
     value; edges carry the activation shape (H, W, C) and dtype
     (``value_shapes`` / ``value_dtypes``). The reserved value
@@ -22,13 +23,17 @@ used to thread around is promoted to an explicit graph IR —
     schedule order, weights/operand tables key by *node name*, and
     calibration observes *graph values*, not list indices.
 
-Two analyses run on the IR:
+Three analyses run on the IR:
 
   * ``residual_fusion`` — which ``add`` nodes fold into the producing
     conv's megakernel epilogue (the paper's accumulation-SRAM add): an
     add fuses into its conv operand when that conv's output is consumed
     by the add alone, the conv has no ReLU of its own (the block's ReLU
     belongs to the add), and no pool sits between conv and add.
+  * ``norm_fusion`` — which ``norm`` nodes fold into their producer's
+    epilogue the same way: the producer (a conv, or a conv carrying a
+    fused add) sends its value to the norm alone and has no
+    activation or pool of its own.
   * ``BufferPlan`` (``plan_buffers``) — graph-aware HBM activation
     liveness: a value's buffer is freed the moment its last consumer
     has fired, so e.g. a ResNet identity shortcut holds exactly one
@@ -49,9 +54,13 @@ import functools
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 from repro.core.decomposition import ConvLayer
 
 INPUT = "input"          # the reserved network-input value name
+ACTIVATIONS = (None, "relu", "gelu")
+NORM_EPS = 1e-6          # every ``norm`` node's LayerNorm epsilon
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,18 +68,26 @@ class GraphNode:
     """One op of a NetworkGraph; produces the value named ``name``.
 
     ``op="conv"``: ``layer`` holds the planned ConvLayer (its fused
-    max-pool included); ``relu`` applies max(x, 0) after bias (and
-    before the pool, matching the streamed executors). ``op="add"``:
-    elementwise sum of exactly two same-shape, same-dtype operands —
-    the paper's accumulation-buffer add; ``relu`` applies after the
-    sum (the usual post-block ReLU).
+    max-pool included); ``act`` applies after bias (and before the
+    pool, matching the streamed executors). ``op="add"``: elementwise
+    sum of exactly two same-shape, same-dtype operands — the paper's
+    accumulation-buffer add; ``act`` applies after the sum (the usual
+    post-block ReLU). ``op="norm"``: LayerNorm of one value over its
+    channels (``NORM_EPS``), then a per-channel affine (the node's
+    ``(gamma, beta)`` weights; the identity where none are given),
+    then ``act``. ``act`` is one of ``ACTIVATIONS``: ``"relu"``,
+    ``"gelu"`` (the exact erf form) or ``None``.
     """
     name: str
-    op: str                          # "conv" | "add"
+    op: str                          # "conv" | "add" | "norm"
     inputs: Tuple[str, ...]
     layer: Optional[ConvLayer] = None
-    relu: bool = True
+    act: Optional[str] = "relu"
     dtype: Optional[str] = None      # output dtype override (None = graph's)
+
+    @property
+    def relu(self) -> bool:
+        return self.act == "relu"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,7 +114,7 @@ class NetworkGraph:
         the cache-key component that keeps two graphs sharing a layer
         geometry from colliding in the executor caches."""
         return (self.name, self.in_shape, self.dtype, self.output,
-                tuple((n.name, n.op, n.inputs, n.layer, n.relu, n.dtype)
+                tuple((n.name, n.op, n.inputs, n.layer, n.act, n.dtype)
                       for n in self.nodes))
 
     def node(self, name: str) -> GraphNode:
@@ -120,7 +137,7 @@ class NetworkGraph:
         for n in topological_schedule(self):
             src = ", ".join(n.inputs)
             lines.append(f"  {n.name} = {n.op}({src})"
-                         f"{' +relu' if n.relu else ''} "
+                         f"{' +' + n.act if n.act else ''} "
                          f"-> {shapes[n.name]}")
         return "\n".join(lines)
 
@@ -214,13 +231,20 @@ def validate_graph(g: NetworkGraph) -> None:
     3. add nodes: exactly two operands with identical shapes AND
        dtypes (the accumulation-buffer add has no broadcasting and no
        implicit casts);
-    4. every edge consumed: each value except the graph output feeds
+    4. norm nodes: exactly one operand and no layer (so no pool: a
+       norm is per pixel and keeps its operand's shape); every
+       node's ``act`` is one of ``ACTIVATIONS``;
+    5. every edge consumed: each value except the graph output feeds
        at least one node (a dangling value is almost always a
        mis-wired residual), and the output value exists.
     """
     by_name = _producers(g)
     known = {INPUT} | set(by_name)
     for n in g.nodes:
+        if n.act not in ACTIVATIONS:
+            raise GraphValidationError(
+                f"{g.name}: node {n.name!r} has unknown activation "
+                f"{n.act!r} (expected one of {ACTIVATIONS})")
         for v in n.inputs:
             if v not in known:
                 raise GraphValidationError(
@@ -239,6 +263,15 @@ def validate_graph(g: NetworkGraph) -> None:
                 raise GraphValidationError(
                     f"{g.name}: add node {n.name!r} wants exactly two "
                     f"operands, got {len(n.inputs)}")
+        elif n.op == "norm":
+            if len(n.inputs) != 1:
+                raise GraphValidationError(
+                    f"{g.name}: norm node {n.name!r} wants exactly one "
+                    f"operand, got {len(n.inputs)}")
+            if n.layer is not None:
+                raise GraphValidationError(
+                    f"{g.name}: norm node {n.name!r} carries a layer — "
+                    f"a norm has no conv and no pool")
         else:
             raise GraphValidationError(
                 f"{g.name}: unknown op {n.op!r} on node {n.name!r}")
@@ -258,7 +291,7 @@ def validate_graph(g: NetworkGraph) -> None:
                     f"{g.name}: conv node {n.name!r} reads "
                     f"{n.inputs[0]!r} of shape {got}, layer declares "
                     f"({l.in_h}, {l.in_w}, {l.in_c})")
-        else:
+        elif n.op == "add":
             a, b = n.inputs
             if shapes[a] != shapes[b]:
                 raise GraphValidationError(
@@ -307,8 +340,8 @@ def residual_fusion(g: NetworkGraph) -> ResidualFusion:
 
     * the operand is a conv node whose output is consumed by this add
       ONLY (otherwise the pre-add activation must exist in HBM anyway);
-    * that conv has no ReLU of its own (the block ReLU belongs after
-      the add) and no fused pool (pooling a pre-add activation would
+    * that conv has no activation of its own (the block's belongs
+      after the add) and no fused pool (pooling a pre-add activation would
       change shapes before the accumulation-buffer add);
     * the OTHER operand is already produced when the conv fires (the
       epilogue DMAs it as a kernel operand — a shortcut whose own chain
@@ -328,7 +361,7 @@ def residual_fusion(g: NetworkGraph) -> ResidualFusion:
         cands = []
         for v in n.inputs:
             p = by_name.get(v)
-            if (p is not None and p.op == "conv" and not p.relu
+            if (p is not None and p.op == "conv" and p.act is None
                     and p.layer.pool <= 1 and cons[v] == (n.name,)):
                 cands.append(v)
         for conv in sorted(set(cands), key=lambda v: -pos[v]):
@@ -339,6 +372,62 @@ def residual_fusion(g: NetworkGraph) -> ResidualFusion:
                 fused.append((n.name, (conv, other)))
                 break
     return ResidualFusion(fused=tuple(fused))
+
+
+@dataclasses.dataclass(frozen=True)
+class NormFusion:
+    """``fused[norm_name] = conv_name``: the norm runs inside
+    ``conv_name``'s kernel epilogue, after its bias and any fused
+    residual add; the norm's activation becomes the epilogue's and the
+    norm's value is produced by the conv's launch. Norms not in
+    ``fused`` execute as explicit per-pixel ops."""
+    fused: Tuple[Tuple[str, str], ...]
+
+    def as_dict(self) -> Dict[str, str]:
+        return dict(self.fused)
+
+    def norm_of_conv(self) -> Dict[str, str]:
+        """conv node name -> the norm its epilogue computes."""
+        return {conv: norm for norm, conv in self.fused}
+
+
+@functools.lru_cache(maxsize=256)
+def norm_fusion(g: NetworkGraph) -> NormFusion:
+    """A ``norm`` fuses into its producer's epilogue when the producer's
+    value goes to that norm alone and the producer has no activation
+    (the epilogue order is bias, residual, norm, activation, pool) and
+    no pool. The producer is a conv, or an add that ``residual_fusion``
+    already folds into a conv, whose epilogue then runs the norm after
+    the add."""
+    cons = value_consumers(g)
+    by_name = {n.name: n for n in g.nodes}
+    add_conv = {add: conv for add, (conv, _) in
+                residual_fusion(g).fused}
+    fused: List[Tuple[str, str]] = []
+    for n in topological_schedule(g):
+        if n.op != "norm":
+            continue
+        p = by_name.get(n.inputs[0])
+        if p is None or p.act is not None or cons[p.name] != (n.name,):
+            continue
+        if p.op == "conv" and p.layer.pool <= 1:
+            fused.append((n.name, p.name))
+        elif p.op == "add" and p.name in add_conv:
+            fused.append((n.name, add_conv[p.name]))
+    return NormFusion(fused=tuple(fused))
+
+
+def refuse_norm_gelu(g: NetworkGraph, executor: str) -> None:
+    """Raise, naming the node, if ``g`` has a ``norm`` node or a GELU
+    activation: only the fp32 megakernel (and the reference walks) run
+    them, and no other executor may fall back to dropping them."""
+    for n in topological_schedule(g):
+        if n.op == "norm" or n.act == "gelu":
+            what = "a norm" if n.op == "norm" else "a gelu activation"
+            raise GraphValidationError(
+                f"{g.name}: {executor} cannot run node {n.name!r} "
+                f"({what}); norm and gelu run on mode='megakernel' at "
+                f"precision='fp32' only")
 
 
 # ---------------------------------------------------------------------------
@@ -452,6 +541,29 @@ def conv_keyed(graph: NetworkGraph, items, what: str) -> "OrderedDict":
             f"nodes — pass a dict keyed by node name or one entry per "
             f"conv node in schedule order")
     return OrderedDict((n.name, it) for n, it in zip(convs, items))
+
+
+def graph_params(graph: NetworkGraph, weights) -> "OrderedDict":
+    """Every parameter the graph's ops take, keyed by node name: the
+    conv ``(w, b)`` pairs (``conv_keyed``), then a ``(gamma, beta)``
+    pair of shape (C,) per ``norm`` node — the mapping's entry for the
+    node where it has one, else the identity affine."""
+    params = conv_keyed(graph, weights, "weights")
+    shapes = value_shapes(graph)
+    for n in topological_schedule(graph):
+        if n.op != "norm":
+            continue
+        c = shapes[n.name][2]
+        p = weights.get(n.name) if isinstance(weights, dict) else None
+        if p is None:
+            p = (np.ones((c,), np.float32), np.zeros((c,), np.float32))
+        elif len(p) != 2 or any(tuple(a.shape) != (c,) for a in p):
+            raise GraphValidationError(
+                f"{graph.name}: norm node {n.name!r} wants (gamma, beta) "
+                f"of shape ({c},) each, got "
+                f"{[tuple(getattr(a, 'shape', ())) for a in p]}")
+        params[n.name] = p
+    return params
 
 
 def check_graph_input(graph: NetworkGraph, x) -> None:
@@ -595,7 +707,7 @@ def chain_graph(layers: Sequence[ConvLayer], name: str = "chain",
     prev = INPUT
     for l in layers:
         nodes.append(GraphNode(name=l.name, op="conv", inputs=(prev,),
-                               layer=l, relu=relu))
+                               layer=l, act="relu" if relu else None))
         prev = l.name
     return NetworkGraph(name=name,
                         in_shape=(layers[0].in_h, layers[0].in_w,

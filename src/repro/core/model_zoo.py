@@ -1,5 +1,5 @@
 """The other networks the paper claims to support ("able to support
-most popular CNNs"): VGG-16, ResNet-18, and the MobileNets.
+most popular CNNs"): VGG-16, ResNet-18, the MobileNets, and ConvNeXt-T.
 
 Two representations live here:
 
@@ -18,10 +18,13 @@ Two representations live here:
 """
 from __future__ import annotations
 
-from typing import List
+from typing import List, Sequence
+
+import jax.numpy as jnp
 
 from repro.core.decomposition import ALEXNET_STACK, ConvLayer
-from repro.core.graph import INPUT, GraphNode, NetworkGraph, chain_graph
+from repro.core.graph import (INPUT, GraphNode, NetworkGraph, chain_graph,
+                              value_consumers)
 
 # VGG-16 conv layers (Simonyan & Zisserman 2014), 224x224 input.
 VGG16_LAYERS = (
@@ -119,13 +122,13 @@ def resnet18_graph(in_hw: int = 224, width: int = 64,
         nodes.append(GraphNode(
             f"{tag}_c2", "conv", (f"{tag}_c1",),
             layer=ConvLayer(f"{tag}_c2", ho, ho, cout, cout, 3, pad=1),
-            relu=False))                       # block ReLU lives on the add
+            act=None))                         # block ReLU lives on the add
         if stride != 1 or cin != cout:
             nodes.append(GraphNode(
                 f"{tag}_proj", "conv", (prev,),
                 layer=ConvLayer(f"{tag}_proj", h, h, cin, cout, 1,
                                 stride=stride),
-                relu=False))
+                act=None))
             shortcut = f"{tag}_proj"
         else:
             shortcut = prev
@@ -220,7 +223,7 @@ def mobilenet_v2_graph(in_hw: int = 224, width: int = 32,
     1x1 expand (ReLU), 3x3 depthwise (ReLU), 1x1 *linear* project — with
     identity shortcuts when stride is 1 and channels match. The linear
     bottleneck means both the projection conv AND the residual add carry
-    ``relu=False``, exercising the megakernels' no-ReLU residual-fusion
+    ``act=None``, exercising the megakernels' no-ReLU residual-fusion
     epilogue. Channel widths scale by ``width / 32`` (32 = nameplate).
     """
     def sc(c: int) -> int:
@@ -258,12 +261,12 @@ def mobilenet_v2_graph(in_hw: int = 224, width: int = 32,
             nodes.append(GraphNode(
                 f"{tag}_proj", "conv", (f"{tag}_dw",),
                 layer=ConvLayer(f"{tag}_proj", ho, ho, ce, cout, 1),
-                relu=False))                   # linear bottleneck
+                act=None))                     # linear bottleneck
             out = f"{tag}_proj"
             if stride == 1 and c == cout:
                 nodes.append(GraphNode(f"{tag}_add", "add",
                                        (f"{tag}_proj", prev),
-                                       relu=False))
+                                       act=None))
                 out = f"{tag}_add"
             prev, h, c = out, ho, cout
     nodes.append(GraphNode(
@@ -271,6 +274,99 @@ def mobilenet_v2_graph(in_hw: int = 224, width: int = 32,
         layer=ConvLayer("head", h, h, c, sc(1280), 1)))
     return NetworkGraph(name=name, in_shape=(in_hw, in_hw, 3),
                         nodes=tuple(nodes), output="head")
+
+
+def convnext_t_graph(in_hw: int = 224,
+                     dims: Sequence[int] = (96, 192, 384, 768),
+                     depths: Sequence[int] = (3, 3, 9, 3),
+                     name: str = "convnext_t") -> NetworkGraph:
+    """ConvNeXt-T (Liu et al., "A ConvNet for the 2020s", CVPR 2022,
+    arXiv:2201.03545) without its head (global pool, LayerNorm,
+    fc1000).
+
+    Stem: a 4x4/4 conv, then a channel LayerNorm. Four stages of
+    ``depths`` blocks at ``dims`` channels; stages 2-4 are led by a
+    downsampling LayerNorm and a 2x2/2 conv. A block: a 7x7 depthwise
+    conv (pad 3, ``groups == C``), LayerNorm, a 1x1 conv to 4C with
+    exact GELU, a 1x1 conv back to C, and the residual add, with no
+    activation after it. The per-channel layer scale and the affine of
+    every norm that feeds a 1x1 or 2x2 conv fold into that conv's
+    weights (``convnext_fold``); the stem norm keeps its own.
+    """
+    dims, depths = tuple(dims), tuple(depths)
+    if len(dims) != 4 or len(depths) != 4:
+        raise ValueError(f"convnext_t: four stages, got dims {dims} "
+                         f"and depths {depths}")
+    h = _conv_out(in_hw, 4, 4, 0)
+    nodes: List[GraphNode] = [
+        GraphNode("stem", "conv", (INPUT,), act=None,
+                  layer=ConvLayer("stem", in_hw, in_hw, 3, dims[0], 4,
+                                  stride=4)),
+        GraphNode("stem_norm", "norm", ("stem",), act=None)]
+    prev, c = "stem_norm", dims[0]
+    for si, (cout, depth) in enumerate(zip(dims, depths), start=1):
+        if si > 1:
+            nodes.append(GraphNode(f"ds{si}_norm", "norm", (prev,),
+                                   act=None))
+            nodes.append(GraphNode(
+                f"ds{si}", "conv", (f"ds{si}_norm",), act=None,
+                layer=ConvLayer(f"ds{si}", h, h, c, cout, 2, stride=2)))
+            prev, h, c = f"ds{si}", _conv_out(h, 2, 2, 0), cout
+        if h < 1:
+            raise ValueError(f"convnext_t: input {in_hw} too small")
+        for bi in range(1, depth + 1):
+            t = f"s{si}b{bi}"
+            nodes += [
+                GraphNode(f"{t}_dw", "conv", (prev,), act=None,
+                          layer=ConvLayer(f"{t}_dw", h, h, c, c, 7, pad=3,
+                                          groups=c)),
+                GraphNode(f"{t}_norm", "norm", (f"{t}_dw",), act=None),
+                GraphNode(f"{t}_pw1", "conv", (f"{t}_norm",), act="gelu",
+                          layer=ConvLayer(f"{t}_pw1", h, h, c, 4 * c, 1)),
+                GraphNode(f"{t}_pw2", "conv", (f"{t}_pw1",), act=None,
+                          layer=ConvLayer(f"{t}_pw2", h, h, 4 * c, c, 1)),
+                GraphNode(f"{t}_add", "add", (f"{t}_pw2", prev),
+                          act=None)]
+            prev = f"{t}_add"
+    return NetworkGraph(name=name, in_shape=(in_hw, in_hw, 3),
+                        nodes=tuple(nodes), output=prev)
+
+
+def convnext_fold(graph: NetworkGraph, params) -> dict:
+    """The program's weights for ``convnext_t_graph`` from the published
+    parameterisation: ``params`` holds each conv's ``(w, b)``, each
+    norm's ``(gamma, beta)`` and each block's layer scale under
+    ``"<block>_scale"`` (shape (C,)); a missing norm or scale entry is
+    the identity.
+
+    A norm whose every consumer is an unpadded, ungrouped conv folds
+    into it: w'[.., c, o] = gamma[c] w[.., c, o] and b' = b + sum over
+    taps and c of beta[c] w[.., c, o], exact since every tap reads a
+    normalised pixel. A layer scale folds into its block's ``pw2``
+    (w' = w * scale, b' = b * scale). Other norms keep their entry."""
+    out = {n.name: tuple(params[n.name]) for n in graph.conv_nodes()}
+    by_name = {n.name: n for n in graph.nodes}
+    cons = value_consumers(graph)
+    for n in graph.nodes:
+        if n.op != "norm" or n.name not in params:
+            continue
+        gamma, beta = params[n.name]
+        readers = [by_name[c] for c in cons[n.name]]
+        if not all(r.op == "conv" and r.layer.pad == 0
+                   and r.layer.groups == 1 for r in readers):
+            out[n.name] = (gamma, beta)
+            continue
+        for r in readers:
+            w, b = out[r.name]
+            out[r.name] = (w * gamma[:, None],
+                           b + jnp.einsum("hwco,c->o", w, beta))
+    for n in graph.conv_nodes():
+        scale = params.get(n.name[:-len("_pw2")] + "_scale") \
+            if n.name.endswith("_pw2") else None
+        if scale is not None:
+            w, b = out[n.name]
+            out[n.name] = (w * scale, b * scale)
+    return out
 
 
 def network_graph(name: str, **kw) -> NetworkGraph:
@@ -289,4 +385,5 @@ NETWORKS = {
     "facedet": facedet_graph,
     "mobilenet_v1": mobilenet_v1_graph,
     "mobilenet_v2": mobilenet_v2_graph,
+    "convnext_t": convnext_t_graph,
 }

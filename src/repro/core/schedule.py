@@ -26,6 +26,7 @@ accumulation order (and therefore rounding) is identical.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -364,6 +365,9 @@ KERNEL_OP_COLS = 8
 # scoped-VMEM limit from what it holds at the tiled layout
 # (``vmem_bytes``).
 DEFAULT_VMEM_BUDGET = 8 * 2 ** 20
+# lanes of a TPU vector register: the last dim of a kernel block is a
+# multiple of this or the whole array dim
+LANE_TILE = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -374,8 +378,9 @@ class KernelProgram:
     (tile, wave): the wave (in-channel-group) axis is innermost, so a
     VMEM scratch accumulator plays the paper's partial-sum SRAM bank —
     it is zeroed when a tile's chain starts (wave 0) and carried across
-    the chain with **zero HBM round-trips**; the epilogue (bias + optional
-    ReLU + optional fused max-pool, masked write) runs on the last wave
+    the chain with **zero HBM round-trips**; the epilogue (bias, then an
+    optional residual add, channel LayerNorm, activation and fused
+    max-pool, then a masked write) runs on the last wave
     (kernels/wave_replay). The operand ``table`` is the §3 command
     stream: a static int32 array prefetched to SMEM whose rows steer
     every DMA (window origin, channel-group offsets, output block index,
@@ -405,7 +410,7 @@ class KernelProgram:
     intermediate never exists outside VMEM.
     """
     wave: WaveProgram
-    relu: bool
+    act: Optional[str]      # epilogue activation: "relu" | "gelu" | None
     fuse_pool: bool
     # residual epilogue (ISSUE 5): the kernel takes one extra operand —
     # a pre-computed activation of the layer's OWN output geometry —
@@ -445,6 +450,15 @@ class KernelProgram:
     # working sets; batch-aware lowering raises it until the per-step
     # VMEM working set fills the budget.
     batch_block: int = 1
+    # channel LayerNorm in the epilogue, after the residual add and
+    # before the activation: every output channel of a pixel lives in
+    # the step's accumulator (the feature axis folds into the matmul
+    # width), so the mean and variance are taken in VMEM, in fp32
+    norm: bool = False
+
+    @property
+    def relu(self) -> bool:
+        return self.act == "relu"
 
     def operand_table(self) -> np.ndarray:
         """(n_chain, n_tiles, 8) int32 SMEM operand table."""
@@ -495,7 +509,8 @@ class KernelProgram:
     def geometry(self):
         """The table is a pure function of these, so they key the cache."""
         return self.wave.geometry + (
-            "megakernel", self.relu, self.fuse_pool, self.residual,
+            "megakernel", self.act, self.norm, self.fuse_pool,
+            self.residual,
             self.pad_h, self.pad_w,
             self.in_c_kpad, self.w_in_kpad,
             self.ih, self.iw, self.acc_h, self.acc_w, self.blk_h, self.blk_w,
@@ -508,6 +523,8 @@ class KernelProgram:
         fused = f"+pool{self.pool}/{self.pool_stride}" if self.fuse_pool \
             else ""
         fused += "+residual" if self.residual else ""
+        fused += "+norm" if self.norm else ""
+        fused += f"+{self.act}" if self.act else ""
         chunk = f" (x{self.chain_chunk} waves/step)" \
             if self.chain_chunk > 1 else ""
         chunk += f" x{self.batch_block} imgs/step" \
@@ -537,13 +554,16 @@ def batch_grid(batch: int, batch_block: int) -> Tuple[int, int]:
 
 
 def lower_kernel_program(
-        wprog: WaveProgram, *, relu: bool = False, fuse_pool: bool = False,
-        residual: bool = False,
+        wprog: WaveProgram, *, act: Optional[str] = None,
+        fuse_pool: bool = False, residual: bool = False,
+        norm: bool = False,
         vmem_budget: "int | None" = DEFAULT_VMEM_BUDGET,
         batch_block: int = 1) -> KernelProgram:
     """Lower a WaveProgram to the megakernel's static operand tables.
 
-    ``relu`` bakes max(x, 0) into the epilogue; ``fuse_pool`` additionally
+    ``act`` ("relu", "gelu" or None) bakes the activation into the
+    epilogue; ``norm`` a channel LayerNorm before it (after any
+    residual add; incompatible with ``fuse_pool``); ``fuse_pool`` additionally
     max-pools the accumulator in VMEM (requires ``layer.pool > 1``) and
     re-derives the tile grid over the pooled output. ``residual`` adds
     an extra same-geometry operand to the accumulator after bias and
@@ -565,6 +585,16 @@ def lower_kernel_program(
         raise LoweringError(
             f"{l.name}: residual add cannot fuse with the pool epilogue "
             f"— the add runs on the conv-geometry accumulator")
+    if act not in (None, "relu", "gelu"):
+        raise LoweringError(f"{l.name}: unknown epilogue activation "
+                            f"{act!r}")
+    if norm and (fuse_pool or g.out_c_pad < l.out_c):
+        # the kernel's grid has no feature axis, so a step always holds
+        # out_c_pad >= out_c channels; a lowering that split them could
+        # not take a pixel's mean in one epilogue
+        raise LoweringError(
+            f"{l.name}: the channel norm needs every output channel of a "
+            f"pixel in one step, unpooled")
 
     if fuse_pool:
         ps = l.pool_stride or l.pool
@@ -616,6 +646,14 @@ def lower_kernel_program(
             chunk = min(wprog.n_waves,
                         (vmem_budget - acc_bytes) // per_wave)
         chunk = max(1, chunk)
+        # a step's channel block must be whole 128-lane tiles or the
+        # whole padded axis (one step): a chunk a lane tile or wider
+        # rounds down to whole tiles (768 one-channel waves: 257 -> 256)
+        span = wprog.c_width * chunk
+        if chunk < wprog.n_waves and span >= LANE_TILE \
+                and span % LANE_TILE:
+            step = LANE_TILE // math.gcd(LANE_TILE, wprog.c_width)
+            chunk = min(wprog.n_waves, max(step, chunk // step * step))
     n_chain = _ceil_div(wprog.n_waves, chunk)
     c_width = wprog.c_width * chunk
     # ungrouped layers run one dense matmul per step, so the weight fan
@@ -656,7 +694,8 @@ def lower_kernel_program(
         table.append(tuple(step_rows))
 
     kp = KernelProgram(
-        wave=wprog, relu=relu, fuse_pool=fuse_pool, residual=residual,
+        wave=wprog, act=act, norm=norm, fuse_pool=fuse_pool,
+        residual=residual,
         pad_h=pad_h, pad_w=pad_w,
         in_c_kpad=in_c_kpad, w_in_kpad=w_in_kpad,
         ih=ih, iw=iw,
@@ -1120,6 +1159,10 @@ def lower_graph_kernel(specs: Sequence[ChainNodeSpec], *,
     visible = {input_value}
     for i, s in enumerate(specs):
         l = s.kp.wave.program.layer
+        if s.kp.norm or s.kp.act == "gelu":
+            raise LoweringError(
+                f"{s.name}: the graph kernel's epilogue has no norm or "
+                f"gelu — run this node on the per-layer megakernel")
         if s.in_value not in visible:
             raise LoweringError(
                 f"{s.name}: input {s.in_value!r} not produced earlier "

@@ -63,7 +63,7 @@ import functools
 import itertools
 import weakref
 from collections import OrderedDict
-from typing import Callable, List, Optional, Sequence
+from typing import Callable, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -71,13 +71,14 @@ from jax import lax
 
 from repro.core.decomposition import (ConvLayer, Plan, evaluate,
                                       plan_decomposition, tile_grid)
-from repro.core.graph import (INPUT, NetworkGraph, chain_graph,
+from repro.core.graph import (INPUT, NORM_EPS, NetworkGraph, chain_graph,
                               check_graph_input, conv_keyed,
-                              fusible_chains, plan_buffers,
+                              fusible_chains, graph_params, norm_fusion,
+                              plan_buffers, refuse_norm_gelu,
                               residual_fusion, topological_schedule)
 from repro.core.schedule import (DEFAULT_VMEM_BUDGET as _VMEM_DEFAULT,
                                  ChainNodeSpec, KernelProgram, TileProgram,
-                                 WaveProgram, compile_layer,
+                                 WaveProgram, batch_grid, compile_layer,
                                  lower_graph_kernel, lower_kernel_program,
                                  partition_waves)
 from repro.obs import metrics as _metrics
@@ -103,6 +104,24 @@ def maxpool_direct(x: jax.Array, window: int, stride: int = 0) -> jax.Array:
     return lax.reduce_window(
         x, -jnp.inf, lax.max, (1, window, window, 1),
         (1, stride, stride, 1), "VALID")
+
+
+def activation_direct(y: jax.Array, act) -> jax.Array:
+    """A graph node's activation kind as plain XLA ops."""
+    if act == "relu":
+        return jnp.maximum(y, 0)
+    if act == "gelu":
+        return jax.nn.gelu(y, approximate=False)
+    return y
+
+
+def channel_norm_direct(y: jax.Array, gamma, beta) -> jax.Array:
+    """A ``norm`` node as plain XLA ops: LayerNorm over the channel
+    axis (``NORM_EPS``), then the per-channel affine."""
+    mean = jnp.mean(y, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(y - mean), axis=-1, keepdims=True)
+    return ((y - mean) * lax.rsqrt(var + NORM_EPS) * gamma.astype(y.dtype)
+            + beta.astype(y.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -495,7 +514,8 @@ def run_layer_megakernel(wprog: WaveProgram, x: jax.Array, w: jax.Array,
     _check_input(l, x)
     batch = x.shape[0]
     wprog = _coarsen_single_wave(wprog, fuse_pool, vmem_budget, batch)
-    kprog = _lower_kernel_cached(wprog, relu=relu, fuse_pool=fuse_pool,
+    kprog = _lower_kernel_cached(wprog, act="relu" if relu else None,
+                                 fuse_pool=fuse_pool,
                                  vmem_budget=vmem_budget,
                                  batch_block=batch)
     return _run_kernel_program(kprog, x, w, b)
@@ -583,7 +603,8 @@ def run_layer_megakernel_q(wprog: WaveProgram, x: jax.Array, quant,
     _check_input(l, x)
     batch = x.shape[0]
     wprog = _coarsen_single_wave(wprog, fuse_pool, vmem_budget, batch)
-    kprog = _lower_kernel_cached(wprog, relu=relu, fuse_pool=fuse_pool,
+    kprog = _lower_kernel_cached(wprog, act="relu" if relu else None,
+                                 fuse_pool=fuse_pool,
                                  vmem_budget=vmem_budget,
                                  batch_block=batch)
     # precision is an explicit key component: the int8 path accepts the
@@ -806,51 +827,91 @@ def compile_graph(graph: NetworkGraph,
                            for name, p in plans.items())
 
 
-def _graph_epilogues(graph: NetworkGraph):
-    """Per conv node: (epilogue_relu, residual_value | None, out_value).
+class Epilogue(NamedTuple):
+    """What one conv node's megakernel epilogue runs, in order: bias,
+    the add of ``residual`` (a value name) if any, the LayerNorm of
+    ``norm`` (a norm node name, whose ``(gamma, beta)`` the kernel
+    takes) if any, then ``act``; the launch produces value ``out``."""
+    act: Optional[str]
+    residual: Optional[str]
+    out: str
+    norm: Optional[str] = None
 
-    Residual-fused convs take the add's ReLU as their epilogue ReLU and
-    produce the ADD's value (the add node itself is skipped); all other
-    convs keep their own flags. Used by the megakernel paths — the
-    paper's accumulation-SRAM add lives in the kernel epilogue.
+    def parts(self, pool: bool) -> List[str]:
+        """The epilogue's ops by name, as the kernel span lists them."""
+        return (["bias"] + ["residual"] * (self.residual is not None)
+                + ["norm"] * (self.norm is not None)
+                + [self.act] * (self.act is not None)
+                + ["pool"] * pool)
+
+
+def _graph_epilogues(graph: NetworkGraph) -> "dict[str, Epilogue]":
+    """Per conv node, its ``Epilogue``.
+
+    Residual-fused convs produce the ADD's value (the add node itself
+    is skipped) and take the add's activation; a norm fused after
+    either (``norm_fusion``) produces the NORM's value and its
+    activation is the epilogue's; all other convs keep their own. Used
+    by the megakernel paths — the paper's accumulation-SRAM add lives
+    in the kernel epilogue.
     """
     rf = residual_fusion(graph)
     conv_res = rf.conv_residual()
     add_of = rf.add_of_conv()
+    norm_of = norm_fusion(graph).norm_of_conv()
     by_name = {n.name: n for n in graph.nodes}
     out = {}
     for n in graph.conv_nodes():
-        if n.name in conv_res:
-            add = by_name[add_of[n.name]]
-            out[n.name] = (add.relu, conv_res[n.name], add.name)
-        else:
-            out[n.name] = (n.relu, None, n.name)
+        last = by_name[add_of[n.name]] if n.name in conv_res else n
+        if n.name in norm_of:
+            last = by_name[norm_of[n.name]]
+        out[n.name] = Epilogue(last.act, conv_res.get(n.name), last.name,
+                               norm_of.get(n.name))
     return out
 
 
-def _graph_kernel_program(program: TileProgram, relu: bool,
+def _graph_kernel_program(program: TileProgram, act: Optional[str],
                           residual: bool,
                           vmem_budget: Optional[int],
-                          batch: int = 1) -> KernelProgram:
-    """Megakernel lowering for one graph conv node: the node's ReLU (or
-    its fused add's) in the epilogue, the layer's pool fused when it has
-    one, the residual operand when an add folds in, and the schedule
+                          batch: int = 1,
+                          norm: bool = False) -> KernelProgram:
+    """Megakernel lowering for one graph conv node: the node's
+    activation (or its fused add's or norm's) in the epilogue, the
+    layer's pool fused when it has one, the residual operand when an
+    add folds in, the channel norm when a norm does, and the schedule
     re-planned at the kernel's VMEM budget point (``plan_for_vmem``;
     ``None`` replays the given program 1:1). ``batch`` requests that
-    many images per grid step (clamped to the budget by the lowering)."""
+    many images per grid step (clamped to the budget by the
+    lowering)."""
     l = program.layer
     fuse = l.pool > 1
     if vmem_budget is None:
         return _lower_kernel_cached(_partition_waves_cached(program),
-                                    relu=relu, fuse_pool=fuse,
-                                    residual=residual, vmem_budget=None,
-                                    batch_block=batch)
+                                    act=act, fuse_pool=fuse,
+                                    residual=residual, norm=norm,
+                                    vmem_budget=None, batch_block=batch)
     plan = plan_for_vmem(l, vmem_budget, fuse, residual=residual,
                          batch=batch)
     return _lower_kernel_cached(
         _partition_waves_cached(compile_layer(l, plan)),
-        relu=relu, fuse_pool=fuse, residual=residual,
+        act=act, fuse_pool=fuse, residual=residual, norm=norm,
         vmem_budget=vmem_budget, batch_block=batch)
+
+
+def _epilogue_nodes(graph: NetworkGraph) -> set:
+    """The add and norm nodes that run inside a conv's epilogue."""
+    return (set(residual_fusion(graph).as_dict())
+            | set(norm_fusion(graph).as_dict()))
+
+
+def _outside_epilogues(graph: NetworkGraph) -> int:
+    """Norm and GELU ops the megakernel forward runs as XLA ops, outside
+    every conv epilogue: unfused norms, and GELUs on unfused adds and
+    norms."""
+    inside = _epilogue_nodes(graph)
+    return sum((n.op == "norm") + (n.act == "gelu")
+               for n in graph.nodes
+               if n.op != "conv" and n.name not in inside)
 
 
 def graph_kernel_programs(
@@ -858,18 +919,35 @@ def graph_kernel_programs(
         vmem_budget: Optional[int] = _VMEM_DEFAULT,
         batch: int = 1) -> "OrderedDict[str, KernelProgram]":
     """The megakernel lowering of a whole graph, exactly as the graph
-    forward replays it (per-node epilogue ReLU, fused pools, residual
-    operands, VMEM re-planning) — public so weight packers and accuracy
-    harnesses lower the same programs the forward replays."""
+    forward replays it (per-node epilogue activation, fused residual
+    adds and norms, fused pools, VMEM re-planning) — public so weight
+    packers and accuracy harnesses lower the same programs the forward
+    replays.
+
+    With a tracer active, each node's lowering is a ``cat="kernel"``
+    span (attrs ``node``, ``epilogue``, ``vmem_bytes``, ``grid_steps``)
+    inside this call's ``lower`` span, whose ``norm_gelu_outside`` attr
+    counts the norm and GELU ops left outside every epilogue."""
     programs = _conv_keyed(graph, programs, "programs")
     epi = _graph_epilogues(graph)
+    out = OrderedDict()
     with _trace.span(f"lower_kernels:{graph.name}", cat="lower",
-                     nodes=len(programs), batch=batch):
-        return OrderedDict(
-            (name, _graph_kernel_program(p, epi[name][0],
-                                         epi[name][1] is not None,
-                                         vmem_budget, batch))
-            for name, p in programs.items())
+                     nodes=len(programs), batch=batch) as top:
+        for name, p in programs.items():
+            e = epi[name]
+            with _trace.span(f"kernel:{name}", cat="kernel", node=name,
+                             epilogue=e.parts(p.layer.pool > 1)) as sp:
+                kp = out[name] = _graph_kernel_program(
+                    p, e.act, e.residual is not None, vmem_budget, batch,
+                    norm=e.norm is not None)
+                if sp is not None:
+                    n_bb, _ = batch_grid(batch, kp.batch_block)
+                    sp.attrs.update(vmem_bytes=kp.vmem_bytes,
+                                    grid_steps=n_bb * kp.n_tiles
+                                    * kp.n_chain)
+        if top is not None:
+            top.attrs.update(norm_gelu_outside=_outside_epilogues(graph))
+    return out
 
 
 def graph_chain_programs(graph: NetworkGraph, programs,
@@ -906,8 +984,8 @@ def graph_chain_programs(graph: NetworkGraph, programs,
                 continue
             specs = [ChainNodeSpec(name=name, kp=kprogs[name],
                                    in_value=by_name[name].inputs[0],
-                                   out_value=epi[name][2],
-                                   residual_value=epi[name][1])
+                                   out_value=epi[name].out,
+                                   residual_value=epi[name].residual)
                      for name in c.convs]
             gkps[c.convs[0]] = lower_graph_kernel(
                 specs, quantized=quantized,
@@ -949,6 +1027,8 @@ def graph_operands(graph: NetworkGraph, programs, mode: str = "wave",
     mode = _normalize_mode(mode)
     if mode == "interpret":
         raise ValueError("interpret mode has no operand tables")
+    if mode != "megakernel" or precision != "fp32":
+        refuse_norm_gelu(graph, f"mode={mode!r} precision={precision!r}")
     programs = _conv_keyed(graph, programs, "programs")
     if mode == "graphkernel":
         chains, kprogs, gkps = graph_chain_programs(
@@ -1016,6 +1096,8 @@ def graph_forward_fn(graph: NetworkGraph, programs,
     if precision not in ("fp32", "int8"):
         raise ValueError(f"unknown precision {precision!r} "
                          f"(expected fp32 | int8)")
+    if mode != "megakernel" or precision != "fp32":
+        refuse_norm_gelu(graph, f"mode={mode!r} precision={precision!r}")
     programs = _conv_keyed(graph, programs, "programs")
     sched = topological_schedule(graph)
     bplan = plan_buffers(graph)
@@ -1051,8 +1133,8 @@ def graph_forward_fn(graph: NetworkGraph, programs,
                    for name in kprogs}
         in_scale = float(qgraph.scales[INPUT])
         out_scale = float(qgraph.scales[graph.output])
-        fused_adds = {outv for _, resv, outv in epi.values()
-                      if resv is not None}
+        fused_adds = {e.out for e in epi.values()
+                      if e.residual is not None}
 
         def forward_q(x, weights, ops):
             check_graph_input(graph, x)       # trace-time, per shape
@@ -1071,16 +1153,16 @@ def graph_forward_fn(graph: NetworkGraph, programs,
                             fan_chunks=[statics[m][1] for m in c.convs],
                             table=ops[n.name])
                     else:
-                        relu_e, resv, outv = epi[n.name]
+                        e = epi[n.name]
                         wq, bq, m, s = weights[n.name]
                         ps, fc = statics[n.name]
-                        env[outv] = wave_replay_q_layer(
+                        env[e.out] = wave_replay_q_layer(
                             kprogs[n.name], env[n.inputs[0]],
                             wq, bq, m, s,
                             pre_shift=ps, fan_chunk=fc,
                             table=ops[n.name],
-                            residual=env[resv] if resv is not None
-                            else None)
+                            residual=env[e.residual]
+                            if e.residual is not None else None)
                 elif n.name not in fused_adds:
                     env[n.name] = residual_add_i8(
                         env[n.inputs[0]], env[n.inputs[1]], n.relu)
@@ -1105,8 +1187,12 @@ def graph_forward_fn(graph: NetworkGraph, programs,
             kprogs = graph_kernel_programs(graph, programs, vmem_budget,
                                            batch)
             chain_of, members, gkps = {}, set(), {}
-        fused_adds = {outv for _, resv, outv in epi.values()
-                      if resv is not None}
+        fused = _epilogue_nodes(graph)
+        n_norms = sum(n.op == "norm" for n in graph.nodes)
+        n_fused = len(norm_fusion(graph).fused)
+        reg = _metrics.registry()
+        reg.counter("graph.norms_fused").inc(n_fused)
+        reg.counter("graph.norms_unfused").inc(n_norms - n_fused)
 
         def forward_mega(x, weights, ops):
             check_graph_input(graph, x)       # trace-time, per shape
@@ -1122,16 +1208,22 @@ def graph_forward_fn(graph: NetworkGraph, programs,
                             [weights[m] for m in c.convs],
                             table=ops[n.name]).astype(x.dtype)
                     else:
-                        relu_e, resv, outv = epi[n.name]
+                        e = epi[n.name]
                         w, b = weights[n.name]
-                        env[outv] = wave_replay_layer(
+                        env[e.out] = wave_replay_layer(
                             kprogs[n.name], env[n.inputs[0]], w, b,
                             table=ops[n.name],
-                            residual=env[resv] if resv is not None
+                            residual=env[e.residual]
+                            if e.residual is not None else None,
+                            norm=weights[e.norm] if e.norm is not None
                             else None).astype(x.dtype)
-                elif n.name not in fused_adds:
-                    y = env[n.inputs[0]] + env[n.inputs[1]]
-                    env[n.name] = jnp.maximum(y, 0) if n.relu else y
+                elif n.name not in fused:
+                    if n.op == "norm":
+                        y = channel_norm_direct(env[n.inputs[0]],
+                                                *weights[n.name])
+                    else:
+                        y = env[n.inputs[0]] + env[n.inputs[1]]
+                    env[n.name] = activation_direct(y, n.act)
                 for v in bplan.frees[i]:        # liveness: drop dead refs
                     env.pop(v, None)
             return env[graph.output]
@@ -1190,12 +1282,14 @@ def run_graph_reference(graph: NetworkGraph, weights,
                         x: jax.Array) -> "OrderedDict[str, jax.Array]":
     """Direct (undecomposed) reference forward over the graph schedule,
     returning EVERY value (``"input"`` included): each conv value is
-    post-bias/ReLU/pool, each add value post-ReLU. The single oracle
-    the streamed executors are tested against AND the tensor set PTQ
+    post-bias/activation/pool, each add and norm value
+    post-activation; every op runs unfused. The single oracle the
+    streamed executors are tested against AND the tensor set PTQ
     calibration observes (quant/calibrate.py) — one walk, so the two
-    can never drift apart."""
+    can never drift apart. ``weights`` as ``graph_params`` reads
+    them."""
     check_graph_input(graph, x)
-    weights = _conv_keyed(graph, weights, "weights")
+    weights = graph_params(graph, weights)
     env = OrderedDict({INPUT: x})
     for n in topological_schedule(graph):
         if n.op == "conv":
@@ -1205,14 +1299,16 @@ def run_graph_reference(graph: NetworkGraph, weights,
                               l.stride, l.pad, groups=l.groups)
             if b is not None:
                 y = y + b.astype(x.dtype)
-            if n.relu:
-                y = jnp.maximum(y, 0)
+            y = activation_direct(y, n.act)
             if l.pool > 1:
                 y = maxpool_direct(y, l.pool, l.pool_stride or l.pool)
+        elif n.op == "norm":
+            y = activation_direct(
+                channel_norm_direct(env[n.inputs[0]], *weights[n.name]),
+                n.act)
         else:
-            y = env[n.inputs[0]] + env[n.inputs[1]]
-            if n.relu:
-                y = jnp.maximum(y, 0)
+            y = activation_direct(env[n.inputs[0]] + env[n.inputs[1]],
+                                  n.act)
         env[n.name] = y
     return env
 
@@ -1251,7 +1347,7 @@ def run_graph_streamed(graph: NetworkGraph, plans, x: jax.Array, weights,
     check_graph_input(graph, x)
     plans = _conv_keyed(graph, plans, "plans")
     if precision != "int8":
-        weights = _conv_keyed(graph, weights, "weights")
+        weights = graph_params(graph, weights)
     if mode == "interpret":
         if precision != "fp32":
             raise ValueError("interpret mode is fp32-only — the int8 "
@@ -1266,14 +1362,16 @@ def run_graph_streamed(graph: NetworkGraph, plans, x: jax.Array, weights,
                 w, b = weights[n.name]
                 y = run_layer_interpreted(l, plans[n.name],
                                           env[n.inputs[0]], w, b, conv_fn)
-                if n.relu:
-                    y = jnp.maximum(y, 0)
+                y = activation_direct(y, n.act)
                 if l.pool > 1:
                     y = maxpool_direct(y, l.pool, l.pool_stride or l.pool)
                 env[n.name] = y
+            elif n.op == "norm":
+                env[n.name] = activation_direct(channel_norm_direct(
+                    env[n.inputs[0]], *weights[n.name]), n.act)
             else:
                 y = env[n.inputs[0]] + env[n.inputs[1]]
-                env[n.name] = jnp.maximum(y, 0) if n.relu else y
+                env[n.name] = activation_direct(y, n.act)
             peak = max(peak, sum(int(v.nbytes) for v in env.values()))
             if bplan is not None:
                 for v in bplan.frees[i]:
@@ -1431,7 +1529,7 @@ def plan_for_vmem(layer: ConvLayer,
                     continue
                 kp = _lower_kernel_cached(
                     _partition_waves_cached(compile_layer(layer, p)),
-                    relu=True, fuse_pool=fuse_pool, residual=residual,
+                    act="relu", fuse_pool=fuse_pool, residual=residual,
                     vmem_budget=None if batch == 1 else vmem_budget,
                     batch_block=batch)
                 ws = kp.plan_bytes
@@ -1465,7 +1563,7 @@ def _network_kernel_program(
     """The linear-stack megakernel lowering: ReLU always fused, the
     layer's max-pool fused whenever it has one, no residual operand —
     ``_graph_kernel_program`` with a chain node's flags."""
-    return _graph_kernel_program(program, relu=True, residual=False,
+    return _graph_kernel_program(program, act="relu", residual=False,
                                  vmem_budget=vmem_budget, batch=batch)
 
 

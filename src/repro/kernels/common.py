@@ -204,6 +204,59 @@ def conv_rows(acc_ref, b, load, wtap, *, K: int, stride: int, acc_h: int,
     jax.lax.fori_loop(0, acc_h, row, 0)
 
 
+# erf(x) for float32 as XLA computes it: x * P(x^2) / Q(x^2) on |x| <
+# 3.8325..., where erf first rounds to +-1, and +-1 beyond. Mosaic has
+# no lowering for lax.erf, so the kernels evaluate it themselves.
+_ERF_CLAMP = 3.832506856900711
+_ERF_P = (0.00022905065861350646, 0.0034082910107109506,
+          0.050955695062380861, 0.18520832239976145, 1.128379143519084)
+_ERF_Q = (-1.1791602954361697e-7, 0.000023547966471313185,
+          0.0010179625278914885, 0.014070470171167667,
+          0.11098505178285362, 0.49746925110067538, 1.0)
+
+
+def erf_f32(x: jax.Array) -> jax.Array:
+    """Error function of a float32 value, from its rational form."""
+    xc = jnp.clip(x, -_ERF_CLAMP, _ERF_CLAMP)
+    x2 = xc * xc
+    p = jnp.full_like(x2, _ERF_P[0])
+    for c in _ERF_P[1:]:
+        p = p * x2 + c
+    q = jnp.full_like(x2, _ERF_Q[0])
+    for c in _ERF_Q[1:]:
+        q = q * x2 + c
+    return jnp.where(jnp.abs(x) >= _ERF_CLAMP,
+                     jnp.where(x < 0, -1.0, 1.0), xc * p / q)
+
+
+def activate(a: jax.Array, act) -> jax.Array:
+    """An epilogue activation: ``"relu"``, ``"gelu"`` (the exact erf
+    form, 0.5 x (1 + erf(x / sqrt 2))) or ``None``."""
+    if act == "relu":
+        return jnp.maximum(a, 0.0)
+    if act == "gelu":
+        return 0.5 * a * (1.0 + erf_f32(a * 0.7071067811865476))
+    return a
+
+
+def channel_norm(a: jax.Array, gamma: jax.Array, beta: jax.Array,
+                 n: int, eps: float) -> jax.Array:
+    """LayerNorm of every pixel of ``a`` (..., C) over its first ``n``
+    channels, in fp32, then the (1, C) affine. Channels past ``n`` are
+    padding: they take no part in the mean or variance, and leave as
+    ``beta`` there (zeros where the caller pads it with zeros)."""
+    if a.shape[-1] > n:
+        ch = jax.lax.broadcasted_iota(jnp.int32, a.shape, a.ndim - 1)
+        valid = ch < n
+        a = jnp.where(valid, a, 0.0)
+    mean = jnp.sum(a, axis=-1, keepdims=True) * (1.0 / n)
+    d = a - mean
+    if a.shape[-1] > n:
+        d = jnp.where(valid, d, 0.0)
+    var = jnp.sum(d * d, axis=-1, keepdims=True) * (1.0 / n)
+    return d * jax.lax.rsqrt(var + eps) * gamma + beta
+
+
 def lane_pieces(lo: int, hi: int, step: int):
     """Split channels ``[lo, hi)`` into ``(start, width)`` pieces of at
     most ``step`` that never cross a lane-tile boundary."""
@@ -377,6 +430,8 @@ def megakernel_vmem(kp, *, quantized: bool = False,
     out = ((bb, kp.blk_h, kp.out_w_pad, oc), io)
     blocks = [((bb, ih, full_w, c), io), ((k, k, fan, oc), io)]
     blocks += [((1, oc), acc_dt)] * (3 if quantized else 1) + [out]
+    if kp.norm:
+        blocks.append(((2, oc), jnp.float32))
     if kp.residual:
         blocks.append(out)
     acc = (bb, kp.acc_h, kp.acc_w, oc)

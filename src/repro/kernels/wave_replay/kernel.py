@@ -16,9 +16,10 @@ overlapping halos are *indexed*, never materialised as fresh copies the
 way the wave executor's vmapped gather stacks them), the wave's
 channel-group offsets into input/weights, and the output block index.
 
-Epilogue (last wave of each tile's chain): bias + optional ReLU +
-optional in-VMEM max-pool over the accumulator (re-deriving the
-(pool - stride)-row overlap per tile, like fused_conv_pool), then a
+Epilogue (last wave of each tile's chain): bias, then the optional
+residual add, channel LayerNorm and activation (ReLU or exact GELU),
+then the optional in-VMEM max-pool over the accumulator (re-deriving
+the (pool - stride)-row overlap per tile, like fused_conv_pool), then a
 masked write that zeroes the grid-padding lanes — the conv->pool
 intermediate and every partial sum live only in VMEM.
 """
@@ -33,8 +34,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.schedule import (KERNEL_OP_COLS, OP_IY, OP_TX, OP_TY,
                                  OP_VC, OP_VR, KernelProgram, batch_grid)
-from repro.kernels.common import (at_tile_col, conv_rows, element_block,
-                                  lane_load, mask_tile, megakernel_geometry,
+from repro.core.graph import NORM_EPS
+from repro.kernels.common import (activate, at_tile_col, channel_norm,
+                                  conv_rows, element_block, lane_load,
+                                  mask_tile, megakernel_geometry,
                                   megakernel_vmem, pool_tile,
                                   space_to_depth, space_to_depth_weights,
                                   stage_lanes, strided)
@@ -43,18 +46,21 @@ from repro.kernels.common import (at_tile_col, conv_rows, element_block,
 def _replay_kernel(tbl_ref, x_ref, w_ref, b_ref, *refs,
                    K: int, stride: int, col_step: int, acc_h: int,
                    acc_w: int, n_waves: int, pool: int, ps: int,
-                   blk_h: int, blk_w: int, tiles_w: int, relu: bool,
-                   fuse_pool: bool, residual: bool, groups: int,
-                   staged: bool):
+                   blk_h: int, blk_w: int, tiles_w: int, act,
+                   norm: bool, n_out: int, fuse_pool: bool,
+                   residual: bool, groups: int, staged: bool):
     """One grid step: batch block (program_id 0), tile t (id 1), chain
     position k (id 2). The batch axis is outermost, so each batch
     block's tiles replay their full partial-sum chains before the next
     block starts — the scratch accumulator is recycled across blocks.
 
-    ``refs`` are ``[r_ref] o_ref acc_ref [pool_ref] [xs_ref]``: with
-    ``residual`` the residual activation rows of this tile, added to
-    the accumulator after bias, before ReLU (the paper's
-    accumulation-SRAM add); with ``fuse_pool`` the lane-tiled pool
+    ``refs`` are ``[r_ref] [n_ref] o_ref acc_ref [pool_ref] [xs_ref]``:
+    with ``residual`` the residual activation rows of this tile, added
+    to the accumulator after bias, before the norm and activation (the
+    paper's accumulation-SRAM add); with ``norm`` the (2, C) gamma and
+    beta of the channel LayerNorm over the ``n_out`` real output
+    channels, which with GELU runs one output row at a time so its
+    temporaries stay a row wide; with ``fuse_pool`` the lane-tiled pool
     scratch; with ``staged`` (strided taps) the lane-tiled copy of one
     image's window that strided loads read.
 
@@ -65,6 +71,7 @@ def _replay_kernel(tbl_ref, x_ref, w_ref, b_ref, *refs,
     """
     refs = list(refs)
     r_ref = refs.pop(0) if residual else None
+    n_ref = refs.pop(0) if norm else None
     o_ref, acc_ref = refs.pop(0), refs.pop(0)
     pool_ref = refs.pop(0) if fuse_pool else None
     xs_ref = refs.pop(0) if staged else None
@@ -77,6 +84,7 @@ def _replay_kernel(tbl_ref, x_ref, w_ref, b_ref, *refs,
 
     bb, cin = x_ref.shape[0], x_ref.shape[-1]
     out_c = acc_ref.shape[-1]
+    rowwise = (norm or act == "gelu") and not fuse_pool
 
     def conv_image(col0, b, carry):
         if staged:
@@ -106,13 +114,30 @@ def _replay_kernel(tbl_ref, x_ref, w_ref, b_ref, *refs,
     def _epilogue():                  # chain end: finish in VMEM, write once
         vr, vc = tbl_ref[k, t, OP_VR], tbl_ref[k, t, OP_VC]
 
+        def finish_row(j, b, i, carry):
+            # bias, residual, norm, activation on output row i alone
+            cols = slice(j * blk_w, (j + 1) * blk_w)
+            a = acc_ref[b, i] + b_ref[...]
+            if residual:
+                a = a + r_ref[b, i, cols, :]
+            if norm:
+                a = channel_norm(a, n_ref[0:1, :], n_ref[1:2, :], n_out,
+                                 NORM_EPS)
+            a = activate(a, act)
+            col = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+            o_ref[b, i, cols, :] = jnp.where((i < vr) & (col < vc), a,
+                                             jnp.zeros_like(a))
+            return carry
+
         def finish(j, b, carry):
+            if rowwise:
+                return jax.lax.fori_loop(
+                    0, blk_h, functools.partial(finish_row, j, b), carry)
             cols = slice(j * blk_w, (j + 1) * blk_w)
             a = acc_ref[b] + b_ref[...]
             if residual:              # accumulation-buffer add, pre-ReLU
                 a = a + r_ref[b, :, cols, :]
-            if relu:
-                a = jnp.maximum(a, 0.0)
+            a = activate(a, act)
             if fuse_pool:
                 # overlapping pools (ps < pool) re-derive their overlap
                 # rows in-block, as strided loads of the parked tile
@@ -130,6 +155,7 @@ def _replay_kernel(tbl_ref, x_ref, w_ref, b_ref, *refs,
 def wave_replay_raw(kp: KernelProgram, x: jax.Array, w: jax.Array,
                     b: jax.Array, table: jax.Array,
                     residual: jax.Array | None = None,
+                    norm: jax.Array | None = None,
                     interpret: bool | None = None) -> jax.Array:
     """Launch the persistent megakernel for one layer.
 
@@ -140,7 +166,9 @@ def wave_replay_raw(kp: KernelProgram, x: jax.Array, w: jax.Array,
     ``residual=True`` additionally take the residual activation at the
     padded output geometry (B, out_h_pad, out_w_pad, out_c_pad) fp32 —
     each tile's rows are DMA'd alongside the output rows and added in
-    the epilogue. The batch axis rides the grid in blocks of
+    the epilogue. Programs lowered with ``norm=True`` take ``norm``, the
+    (2, out_c_pad) fp32 gamma and beta rows (zeros past ``out_c``). The
+    batch axis rides the grid in blocks of
     ``kp.batch_block`` images (outermost axis); ragged batches are
     zero-padded to whole blocks here and cropped on return (zero
     images convolve to exact zeros, so real rows are untouched).
@@ -177,6 +205,12 @@ def wave_replay_raw(kp: KernelProgram, x: jax.Array, w: jax.Array,
         raise ValueError(
             f"{l.name}: program lowered without residual=True cannot "
             f"take a residual operand")
+    if kp.norm != (norm is not None) or (
+            norm is not None and norm.shape != (2, kp.out_c_pad)):
+        raise ValueError(
+            f"{l.name}: program lowered with norm={kp.norm} got norm "
+            f"operand {None if norm is None else norm.shape}, wants "
+            f"{(2, kp.out_c_pad) if kp.norm else None}")
 
     # batch as a first-class grid axis (ISSUE 8): bb images per step,
     # padded to whole blocks (zeros accumulate exact 0.0) and cropped
@@ -212,6 +246,10 @@ def wave_replay_raw(kp: KernelProgram, x: jax.Array, w: jax.Array,
         in_specs.append(pl.BlockSpec(
             out_block, lambda bi, t, k, tbl: (bi, tbl[k, t, OP_TY], 0, 0)))
         operands.append(residual)
+    if kp.norm:
+        in_specs.append(pl.BlockSpec((2, kp.out_c_pad),
+                                     lambda bi, t, k, tbl: (0, 0)))
+        operands.append(norm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,        # the SMEM operand table
         grid=(n_bb, kp.n_tiles, kp.n_chain),
@@ -227,9 +265,9 @@ def wave_replay_raw(kp: KernelProgram, x: jax.Array, w: jax.Array,
         col_step=kp.blk_w * kp.pool_stride * stride,
         acc_h=kp.acc_h, acc_w=kp.acc_w,
         n_waves=kp.n_chain, pool=kp.pool, ps=kp.pool_stride,
-        blk_h=kp.blk_h, blk_w=kp.blk_w, tiles_w=kp.tiles_w, relu=kp.relu,
-        fuse_pool=kp.fuse_pool, residual=kp.residual, groups=kp.groups,
-        staged=stride > 1)
+        blk_h=kp.blk_h, blk_w=kp.blk_w, tiles_w=kp.tiles_w, act=kp.act,
+        norm=kp.norm, n_out=l.out_c, fuse_pool=kp.fuse_pool,
+        residual=kp.residual, groups=kp.groups, staged=stride > 1)
     y = pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct(

@@ -103,10 +103,20 @@ def pad_residual(kp: KernelProgram, r: jax.Array) -> jax.Array:
                     (0, g.out_c_pad - g.layer.out_c)))
 
 
+def pad_norm(kp: KernelProgram, gamma: jax.Array,
+             beta: jax.Array) -> jax.Array:
+    """A channel norm's affine as the kernel's (2, out_c_pad) operand:
+    gamma over beta, zeros in the padding channels."""
+    c = kp.wave.program.layer.out_c
+    ab = jnp.stack([gamma, beta]).astype(jnp.float32)
+    return jnp.pad(ab, ((0, 0), (0, kp.out_c_pad - c)))
+
+
 def wave_replay_layer(kp: KernelProgram, x: jax.Array, w: jax.Array,
                       b: jax.Array | None = None,
                       table: jax.Array | None = None,
                       residual: jax.Array | None = None,
+                      norm=None,
                       interpret: bool | None = None) -> jax.Array:
     """Execute one streamed CONV layer as ONE persistent pallas_call.
 
@@ -116,8 +126,10 @@ def wave_replay_layer(kp: KernelProgram, x: jax.Array, w: jax.Array,
     Programs lowered with ``residual=True`` take the residual
     activation (B, out_h, out_w, out_c) — added to the accumulator
     after bias, before ReLU (the paper's accumulation-SRAM add).
-    Returns the valid (B, out_h, out_w, out_c) output — pooled dims when
-    the program fuses its pool — as fp32.
+    Programs lowered with ``norm=True`` take ``norm``, the norm's
+    ``(gamma, beta)``, each of shape (out_c,). Returns the valid
+    (B, out_h, out_w, out_c) output — pooled dims when the program
+    fuses its pool — as fp32.
     """
     l = kp.wave.program.layer
     with launches.record(l.name, "megakernel"):
@@ -130,8 +142,12 @@ def wave_replay_layer(kp: KernelProgram, x: jax.Array, w: jax.Array,
         if kp.residual and residual is None:
             raise ValueError(f"{l.name}: program lowered with "
                              f"residual=True needs the residual operand")
+        if kp.norm and norm is None:
+            raise ValueError(f"{l.name}: program lowered with norm=True "
+                             f"needs the norm's (gamma, beta)")
         xp, wp, bias = pad_operands(kp, x, w, b)
         rp = pad_residual(kp, residual) if kp.residual else None
         y = wave_replay_raw(kp, xp, wp, bias, table, residual=rp,
+                            norm=pad_norm(kp, *norm) if kp.norm else None,
                             interpret=interpret)
     return y[:, :kp.out_h, :kp.out_w, :l.out_c]

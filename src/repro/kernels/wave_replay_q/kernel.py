@@ -202,6 +202,9 @@ def wave_replay_q_raw(kp: KernelProgram, xq: jax.Array, wq: jax.Array,
     g = kp.wave.program
     l = g.layer
     B = xq.shape[0]
+    if kp.norm or kp.act == "gelu":
+        raise ValueError(f"{l.name}: the int8 epilogue has no norm or "
+                         f"gelu")
     if l.groups > 1:
         # grouped plans have single-step chains (planner invariant) and
         # group-aligned features, so out_c_pad == out_c and the in-body

@@ -149,14 +149,17 @@ def main():
                     help="serve CNN image requests via StreamingSession")
     ap.add_argument("--network", default="alexnet",
                     choices=("alexnet", "vgg16", "resnet18", "facedet",
-                             "mobilenet_v1", "mobilenet_v2"),
+                             "mobilenet_v1", "mobilenet_v2",
+                             "convnext_t"),
                     help="which NetworkGraph to serve (--cnn): the "
                          "AlexNet chain, the VGG-16 stack, ResNet-18 "
                          "with residual adds + projection shortcuts, "
                          "the compact face-detection trunk (tiny frames, "
                          "the batch-throughput serving shape), or the "
                          "MobileNet-v1/v2 depthwise-separable stacks "
-                         "(the grouped per-group kernel path)")
+                         "(the grouped per-group kernel path), or the "
+                         "ConvNeXt-T trunk (norm and GELU epilogues; "
+                         "--mode megakernel only)")
     ap.add_argument("--requests", type=int, default=32,
                     help="number of single-image requests (--cnn)")
     ap.add_argument("--sram-kb", type=int, default=128,

@@ -47,7 +47,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.decomposition import ConvLayer, plan_decomposition
-from repro.core.graph import NetworkGraph, chain_graph, conv_keyed
+from repro.core.graph import (NetworkGraph, chain_graph, conv_keyed,
+                              graph_params)
 from repro.core.schedule import TileProgram
 from repro.core.streaming import (compile_graph, graph_forward_fn,
                                   graph_operands, plan_graph)
@@ -171,7 +172,7 @@ class StreamingSession:
                     "weights=None is only valid with precision='int8' "
                     "(where the calibrated qnet supplies them) — pass "
                     "the float (w, b) pairs")
-            self.weights = self._conv_dict(weights, "weights")
+            self.weights = graph_params(graph, weights)
         self.qnet = qnet
         self._qgraph = qgraph
         self._conv_fn, self._conv_backend = conv_fn, conv_backend

@@ -38,7 +38,8 @@ import numpy as np
 
 from repro.core.decomposition import ConvLayer
 from repro.core.graph import (INPUT, NetworkGraph, chain_graph,
-                              conv_keyed, topological_schedule)
+                              conv_keyed, refuse_norm_gelu,
+                              topological_schedule)
 from repro.core.quantization import INT8_QMAX, requant_params
 
 # bias magnitudes are clipped here when a pathological scale pair would
@@ -346,6 +347,7 @@ def calibrate_graph(graph: NetworkGraph, weights, calib,
     each conv node freezes with its input value's scale in and its own
     value's scale out.
     """
+    refuse_norm_gelu(graph, "calibrate_graph (int8)")
     weights = conv_keyed(graph, weights, "weights")
     if hasattr(calib, "ndim"):
         calib = [calib]

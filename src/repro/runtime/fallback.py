@@ -48,7 +48,7 @@ import jax.numpy as jnp
 
 from repro.core.graph import (INPUT, NetworkGraph, check_graph_input,
                               conv_keyed, fusible_chains, plan_buffers,
-                              topological_schedule)
+                              refuse_norm_gelu, topological_schedule)
 from repro.core.schedule import (DEFAULT_VMEM_BUDGET, ChainNodeSpec,
                                  lower_graph_kernel)
 from repro.core.streaming import (_call_cached, _chain_batch_block,
@@ -247,8 +247,8 @@ class ResolvedGraph:
         members = {m for c in self.chains for m in c.convs[1:]}
         # adds fused into an epilogue only where the conv still runs a
         # kernel mode; degraded convs hand the add back to the walk
-        fused_adds = {epi[n][2] for n, m in modes.items()
-                      if epi[n][1] is not None
+        fused_adds = {epi[n].out for n, m in modes.items()
+                      if epi[n].residual is not None
                       and m in ("graphkernel", "megakernel")}
 
         if self.precision == "int8":
@@ -282,7 +282,7 @@ class ResolvedGraph:
                             y = fault.apply_poison(k, y)
                         env[c.output_value] = y
                     elif m == "megakernel":
-                        relu_e, resv, outv = epi[n.name]
+                        resv, outv = epi[n.name].residual, epi[n.name].out
                         w, b = weights[n.name]
                         y = wave_replay_layer(
                             kprogs[n.name], env[n.inputs[0]], w, b,
@@ -351,7 +351,7 @@ class ResolvedGraph:
                             fan_chunks=[statics[k][1] for k in c.convs],
                             table=ops[n.name])
                     else:                     # megakernel (int8 floor)
-                        relu_e, resv, outv = epi[n.name]
+                        resv, outv = epi[n.name].residual, epi[n.name].out
                         wq, bq, m, s = weights[n.name]
                         ps, fc = statics[n.name]
                         env[outv] = wave_replay_q_layer(
@@ -396,6 +396,8 @@ def resolve_graph(graph: NetworkGraph, programs, *,
     if chain is None:
         chain = FallbackChain(INT8_MODE_ORDER if quantized else MODE_ORDER)
     start = chain.from_mode(mode)[0]
+    # the chain ends in executors that have no norm or gelu
+    refuse_norm_gelu(graph, "the fallback runtime")
     programs = conv_keyed(graph, programs, "programs")
     epi = _graph_epilogues(graph)
     modes: "OrderedDict[str, str]" = OrderedDict(
@@ -427,8 +429,8 @@ def resolve_graph(graph: NetworkGraph, programs, *,
                 if m in ("graphkernel", "megakernel"):
                     fault.fault_point("plan", name, m)
                     kp = _graph_kernel_program(
-                        programs[name], epi[name][0],
-                        epi[name][1] is not None, vmem_budget, batch)
+                        programs[name], epi[name].act,
+                        epi[name].residual is not None, vmem_budget, batch)
                     fault.fault_point("lower", name, m)
                     if budget is not None and kp.plan_bytes > budget:
                         raise BudgetExceeded(
@@ -474,8 +476,8 @@ def resolve_graph(graph: NetworkGraph, programs, *,
         try:
             specs = [ChainNodeSpec(name=k, kp=kprogs[k],
                                    in_value=by_name[k].inputs[0],
-                                   out_value=epi[k][2],
-                                   residual_value=epi[k][1])
+                                   out_value=epi[k].out,
+                                   residual_value=epi[k].residual)
                      for k in c.convs]
             gkp = lower_graph_kernel(
                 specs, quantized=quantized,

@@ -137,7 +137,7 @@ def repair_fp32(resolved: ResolvedGraph, x: jax.Array, weights,
                     # re-lower without the fused add for diagnosis
                     from repro.core.streaming import _graph_kernel_program
                     kp = _graph_kernel_program(
-                        resolved.programs[n.name], n.relu, False,
+                        resolved.programs[n.name], n.act, False,
                         resolved.vmem_budget)
                 y = wave_replay_layer(kp, xin, w, b).astype(x.dtype)
             else:

@@ -23,7 +23,7 @@ def conv_node(name, h, c_in, c_out, inputs, stride=1, relu=True, pool=1,
                      layer=ConvLayer(name, h, h, c_in, c_out, kernel,
                                      stride=stride, pad=pad, pool=pool,
                                      groups=groups),
-                     relu=relu)
+                     act="relu" if relu else None)
 
 
 # test_graph.py's original helper name, re-exported for its callers
@@ -52,13 +52,13 @@ def residual_graphs(draw):
             nodes.append(GraphNode(
                 f"b{bi}_proj", "conv", (prev,),
                 layer=ConvLayer(f"b{bi}_proj", h, h, c_in, c_out, 1,
-                                stride=stride), relu=False))
+                                stride=stride), act=None))
             short = f"b{bi}_proj"
         else:
             short = prev
         nodes.append(GraphNode(f"b{bi}_add", "add",
                                (f"b{bi}_c2", short),
-                               relu=draw(st.booleans())))
+                               act="relu" if draw(st.booleans()) else None))
         prev, c_in, h = f"b{bi}_add", c_out, ho
     return NetworkGraph("rand", (nodes[0].layer.in_h,
                                  nodes[0].layer.in_w, c),
@@ -100,12 +100,12 @@ def streaming_graphs(draw, allow_groups=True):
             nodes.append(GraphNode(
                 "r_proj", "conv", (prev,),
                 layer=ConvLayer("r_proj", h, h, c_in, c_out, 1,
-                                stride=stride), relu=False))
+                                stride=stride), act=None))
             short = "r_proj"
         else:
             short = prev
         nodes.append(GraphNode("r_add", "add", ("r_c2", short),
-                               relu=draw(st.booleans())))
+                               act="relu" if draw(st.booleans()) else None))
         prev, c_in, h = "r_add", c_out, ho
     else:
         # a linear stretch, optionally grouped / depthwise / pooled /

@@ -37,7 +37,7 @@ def _conv(name, h, c_in, c_out, inputs, stride=1, relu=True, pool=1,
                      layer=ConvLayer(name, h, h, c_in, c_out, kernel,
                                      stride=stride, pad=pad, pool=pool,
                                      groups=groups),
-                     relu=relu)
+                     act="relu" if relu else None)
 
 
 def _chain_pool_tail():
@@ -78,7 +78,7 @@ def _identity_block():
         _conv("stem", 8, 3, 8, (INPUT,)),
         _conv("c1", 8, 8, 8, ("stem",)),
         _conv("c2", 8, 8, 8, ("c1",), relu=False),
-        GraphNode("add", "add", ("c2", "stem"), relu=True),
+        GraphNode("add", "add", ("c2", "stem"), act="relu"),
     )
     return NetworkGraph("identity_block", (8, 8, 3), nodes, "add")
 
@@ -91,8 +91,8 @@ def _projection_block():
         _conv("c2", 8, 8, 8, ("c1",), relu=False),
         GraphNode("proj", "conv", ("stem",),
                   layer=ConvLayer("proj", 16, 16, 4, 8, 1, stride=2),
-                  relu=False),
-        GraphNode("add", "add", ("c2", "proj"), relu=True),
+                  act=None),
+        GraphNode("add", "add", ("c2", "proj"), act="relu"),
         _conv("head", 8, 8, 8, ("add",)),
     )
     return NetworkGraph("projection_block", (16, 16, 3), nodes, "head")

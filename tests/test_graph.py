@@ -24,7 +24,7 @@ except ImportError:  # dev-only dependency (requirements.txt)
         return GraphNode(name, "conv", inputs,
                          layer=ConvLayer(name, h, h, c_in, c_out, 3,
                                          stride=stride, pad=1, pool=pool),
-                         relu=relu)
+                         act="relu" if relu else None)
 
 
 def _block_graph():
@@ -234,10 +234,10 @@ def test_topology_key_distinguishes_same_geometry_graphs():
     l2 = ConvLayer("c2", 8, 8, 4, 4, 3, pad=1)
     chain = NetworkGraph("g", (8, 8, 4), (
         GraphNode("c1", "conv", (INPUT,), layer=l1),
-        GraphNode("c2", "conv", ("c1",), layer=l2, relu=False)), "c2")
+        GraphNode("c2", "conv", ("c1",), layer=l2, act=None)), "c2")
     resid = NetworkGraph("g", (8, 8, 4), (
         GraphNode("c1", "conv", (INPUT,), layer=l1),
-        GraphNode("c2", "conv", ("c1",), layer=l2, relu=False),
+        GraphNode("c2", "conv", ("c1",), layer=l2, act=None),
         GraphNode("add", "add", ("c2", INPUT))), "add")
     assert chain.topology_key != resid.topology_key
 

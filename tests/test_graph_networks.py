@@ -124,10 +124,10 @@ def test_graph_cache_no_collision_on_shared_layer_geometry():
     l2 = ConvLayer("c2", 12, 12, 4, 4, 3, pad=1)
     chain = NetworkGraph("g", (12, 12, 4), (
         GraphNode("c1", "conv", (INPUT,), layer=l1),
-        GraphNode("c2", "conv", ("c1",), layer=l2, relu=False)), "c2")
+        GraphNode("c2", "conv", ("c1",), layer=l2, act=None)), "c2")
     resid = NetworkGraph("g", (12, 12, 4), (
         GraphNode("c1", "conv", (INPUT,), layer=l1),
-        GraphNode("c2", "conv", ("c1",), layer=l2, relu=False),
+        GraphNode("c2", "conv", ("c1",), layer=l2, act=None),
         GraphNode("add", "add", ("c2", INPUT))), "add")
     plans = plan_graph(chain, BUDGET)
     ws = init_graph_weights(chain, jax.random.key(3))
@@ -157,7 +157,7 @@ def test_executor_cache_keys_mode_precision_and_degradation():
     l2 = ConvLayer("c2", 12, 12, 4, 4, 3, pad=1)
     g = NetworkGraph("g", (12, 12, 4), (
         GraphNode("c1", "conv", (INPUT,), layer=l1),
-        GraphNode("c2", "conv", ("c1",), layer=l2, relu=False)), "c2")
+        GraphNode("c2", "conv", ("c1",), layer=l2, act=None)), "c2")
     plans = plan_graph(g, BUDGET)
     ws = init_graph_weights(g, jax.random.key(3))
     x = jax.random.normal(jax.random.key(4), (1, 12, 12, 4))

@@ -22,7 +22,8 @@ BUDGET = 64 * 1024
 def _conv(name, h, c_in, c_out, inputs, relu=True):
     return GraphNode(name, "conv", inputs,
                      layer=ConvLayer(name, h, h, c_in, c_out, 3,
-                                     stride=1, pad=1), relu=relu)
+                                     stride=1, pad=1),
+                     act="relu" if relu else None)
 
 
 def _block():
@@ -30,7 +31,7 @@ def _block():
         _conv("stem", 8, 3, 8, (INPUT,)),
         _conv("c1", 8, 8, 8, ("stem",)),
         _conv("c2", 8, 8, 8, ("c1",), relu=False),
-        GraphNode("add", "add", ("c2", "stem"), relu=True),
+        GraphNode("add", "add", ("c2", "stem"), act="relu"),
     )
     g = NetworkGraph("identity_block", (8, 8, 3), nodes, "add")
     plans = plan_graph(g, BUDGET)

@@ -315,7 +315,7 @@ def test_kernel_lowering_sweep_plan_grid():
                             for budget in (None, 64 * 1024, 8 * 2 ** 20):
                                 _assert_kernel_invariants(
                                     lower_kernel_program(
-                                        wprog, relu=True, fuse_pool=fuse,
+                                        wprog, act="relu", fuse_pool=fuse,
                                         vmem_budget=budget))
                                 checked += 1
     assert checked > 50
@@ -385,13 +385,13 @@ def test_launch_vmem_counts_the_tiled_footprint():
 def test_plan_for_vmem_prefers_fewest_steps():
     layer = ALEXNET_STACK[2]        # conv3: 128 KB plan needs 256 waves
     plan = plan_for_vmem(layer, 8 * 2 ** 20, False)
-    kp = lower_kernel_program(_wave(layer, plan), relu=True,
+    kp = lower_kernel_program(_wave(layer, plan), act="relu",
                               vmem_budget=8 * 2 ** 20)
     assert kp.n_tiles * kp.n_chain < 256
     assert kp.plan_bytes <= 8 * 2 ** 20
     # a tiny budget forces real decomposition again
     tight = plan_for_vmem(layer, 512 * 1024, False)
-    kp_tight = lower_kernel_program(_wave(layer, tight), relu=True,
+    kp_tight = lower_kernel_program(_wave(layer, tight), act="relu",
                                     vmem_budget=None)
     assert kp_tight.n_tiles * kp_tight.n_chain > 1
 
@@ -425,7 +425,7 @@ if hypothesis is not None:
             return                      # no feasible plan at this budget
         wprog = _wave(layer, plan)
         _assert_kernel_invariants(lower_kernel_program(
-            wprog, relu=relu, vmem_budget=vmem))
+            wprog, act="relu" if relu else None, vmem_budget=vmem))
 
     @hypothesis.given(
         st.integers(8, 20), st.integers(8, 20),
@@ -460,7 +460,7 @@ def test_megakernel_residual_epilogue_matches_ref():
     layer = ConvLayer("res", 12, 12, 8, 8, 3, pad=1)
     plan = evaluate(layer, 2, 2, 1, 2)
     kp = lower_kernel_program(partition_waves(compile_layer(layer, plan)),
-                              relu=True, residual=True, vmem_budget=None)
+                              act="relu", residual=True, vmem_budget=None)
     x = jax.random.normal(jax.random.key(0), (2, 12, 12, 8))
     w = jax.random.normal(jax.random.key(1), (3, 3, 8, 8)) * 0.2
     b = jax.random.normal(jax.random.key(2), (8,)) * 0.1
@@ -475,7 +475,7 @@ def test_megakernel_residual_validation():
     plan = evaluate(layer, 1, 1, 1, 1)
     wprog = partition_waves(compile_layer(layer, plan))
     with pytest.raises(ValueError, match="residual add cannot fuse"):
-        lower_kernel_program(wprog, relu=True, fuse_pool=True,
+        lower_kernel_program(wprog, act="relu", fuse_pool=True,
                              residual=True)
     nopool = ConvLayer("resv2", 8, 8, 4, 4, 3, pad=1)
     kp = lower_kernel_program(

@@ -90,7 +90,7 @@ def test_stem_megakernel_fused_pool_matches():
                       pool=3, pool_stride=2)
     plan = plan_decomposition(small, 32 * 1024)
     kp = lower_kernel_program(partition_waves(compile_layer(small, plan)),
-                              relu=True, fuse_pool=True, vmem_budget=None)
+                              act="relu", fuse_pool=True, vmem_budget=None)
     x = jax.random.normal(jax.random.key(4), (1, 32, 32, 3))
     w = jax.random.normal(jax.random.key(5), (7, 7, 3, 8)) * 0.1
     got = wave_replay_layer(kp, x, w)

@@ -105,7 +105,7 @@ def test_int8_fused_relu_pool_epilogue_bit_exact():
     w, b = _weights(layer, scale=0.2)
     lq = calibrate_layer(layer, w, b, x)
     xq = quantize_int8_sym(x, lq.in_scale)
-    kp = lower_kernel_program(wprog, relu=True, fuse_pool=True)
+    kp = lower_kernel_program(wprog, act="relu", fuse_pool=True)
     got = wave_replay_q_from_quant(kp, xq, lq)
     ref = quant_layer_ref_from_quant(layer, xq, lq, relu=True,
                                      fuse_pool=True)
@@ -342,7 +342,7 @@ def test_q_megakernel_residual_bit_exact():
     layer = ConvLayer("qres", 12, 12, 8, 8, 3, pad=1)
     plan = evaluate(layer, 2, 2, 1, 2)
     kp = lower_kernel_program(partition_waves(compile_layer(layer, plan)),
-                              relu=True, residual=True, vmem_budget=None)
+                              act="relu", residual=True, vmem_budget=None)
     x = jax.random.normal(jax.random.key(0), (2, 12, 12, 8))
     w = jax.random.normal(jax.random.key(1), (3, 3, 8, 8)) * 0.2
     b = jax.random.normal(jax.random.key(2), (8,)) * 0.1

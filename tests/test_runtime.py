@@ -30,7 +30,7 @@ def _conv(name, h, c_in, c_out, inputs, relu=True, pool=1):
     return GraphNode(name, "conv", inputs,
                      layer=ConvLayer(name, h, h, c_in, c_out, 3,
                                      stride=1, pad=1, pool=pool),
-                     relu=relu)
+                     act="relu" if relu else None)
 
 
 def _identity_block():
@@ -38,7 +38,7 @@ def _identity_block():
         _conv("stem", 8, 3, 8, (INPUT,)),
         _conv("c1", 8, 8, 8, ("stem",)),
         _conv("c2", 8, 8, 8, ("c1",), relu=False),
-        GraphNode("add", "add", ("c2", "stem"), relu=True),
+        GraphNode("add", "add", ("c2", "stem"), act="relu"),
     )
     return NetworkGraph("identity_block", (8, 8, 3), nodes, "add")
 

@@ -6,9 +6,10 @@ scoped VMEM than a kernel states), at no chip time.
 Real widths: every AlexNet layer at the plans ``plan_graph`` gives (at
 batch 1 and at the serving batch 8, whose batch blocks change the
 kernel's shapes), the AlexNet fused chains, the int8 megakernel on an
-ungrouped strided and a grouped layer, a ResNet-18 residual layer and a
-MobileNet depthwise layer. Nothing runs, so results are checked by the
-interpret-mode tests; this file checks that each kernel compiles.
+ungrouped strided and a grouped layer, a ResNet-18 residual layer, a
+MobileNet depthwise layer, and ConvNeXt-T's norm and GELU epilogues.
+Nothing runs, so results are checked by the interpret-mode tests; this
+file checks that each kernel compiles.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every xdist worker imports this
@@ -19,8 +20,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.decomposition import plan_decomposition
 from repro.core.model_zoo import network_graph
-from repro.core.streaming import (compile_graph, graph_chain_programs,
+from repro.core.schedule import DEFAULT_VMEM_BUDGET, compile_layer
+from repro.core.streaming import (_graph_epilogues, _graph_kernel_program,
+                                  compile_graph, graph_chain_programs,
                                   graph_kernel_programs, plan_graph)
 from repro.kernels.wave_replay.graph import (stacked_shapes,
                                              wave_replay_graph_raw)
@@ -77,13 +81,10 @@ def _mega_args(kp, batch, sharding, dtype=jnp.float32):
 def _compile_mega(kp, batch, sharding):
     s, x, w, table, res = _mega_args(kp, batch, sharding)
     b = s((1, kp.out_c_pad))
-    if res is None:
-        _compile(lambda x, w, b, t: wave_replay_raw(
-            kp, x, w, b, t, interpret=False), x, w, b, table)
-    else:
-        _compile(lambda x, w, b, t, r: wave_replay_raw(
-            kp, x, w, b, t, residual=r, interpret=False),
-            x, w, b, table, res)
+    norm = s((2, kp.out_c_pad)) if kp.norm else None
+    _compile(lambda x, w, b, t, r, n: wave_replay_raw(
+        kp, x, w, b, t, residual=r, norm=n, interpret=False),
+        x, w, b, table, res, norm)
 
 
 @pytest.mark.parametrize("layer", ["conv1", "conv2", "conv3", "conv4",
@@ -158,4 +159,25 @@ def test_mobilenet_depthwise_layer_compiles(one_chip):
     kp = graph_kernel_programs(g, progs, batch=8)["dw2"]
     l = kp.wave.program.layer
     assert l.groups == l.in_c == 64 and l.stride == 2
+    _compile_mega(kp, 8, one_chip)
+
+
+@pytest.mark.parametrize("node,act,residual", [
+    ("s1b1_dw", None, False),      # 56 px, 96 channels, 7x7 depthwise
+    ("s1b1_pw1", "gelu", False),   # 96 -> 384, exact GELU
+    ("s3b9_pw2", None, True),      # 1536 -> 384, add, then ds4's norm
+    ("s4b1_pw1", "gelu", False),   # 768 -> 3072 at 7 px: chained fan-in
+])
+def test_convnext_epilogue_layer_compiles(one_chip, node, act, residual):
+    """ConvNeXt-T's widest kernels at the serving batch, each lowered as
+    the graph forward lowers it (the one node alone: the whole graph
+    takes half a minute to lower here)."""
+    g = network_graph("convnext_t")
+    e = _graph_epilogues(g)[node]
+    layer = g.node(node).layer
+    prog = compile_layer(layer, plan_decomposition(layer, SRAM))
+    kp = _graph_kernel_program(prog, e.act, e.residual is not None,
+                               DEFAULT_VMEM_BUDGET, 8,
+                               norm=e.norm is not None)
+    assert (kp.act, kp.residual, kp.norm) == (act, residual, act is None)
     _compile_mega(kp, 8, one_chip)
