@@ -493,17 +493,23 @@ class KernelProgram:
         """The planner's per-grid-step working-set model, in element
         bytes: ``batch_block`` images' accumulators + input-window
         chunks (+ residual blocks when the epilogue adds them) plus the
-        batch-shared weight chunk — what ``vmem_budget`` bounds. It
-        leaves out the lane padding and double buffering that
+        batch-shared weight chunk, and where the fp32 launch folds the
+        taps (``kernels/common.py::tap_fold_factor``) the batch-shared
+        row operand of q * q * c_eff channels — what ``vmem_budget``
+        bounds. It leaves out the lane padding and double buffering that
         ``vmem_bytes`` counts."""
+        from repro.kernels.common import tap_fold_factor
         l = self.wave.program.layer
+        q = tap_fold_factor(l, self.c_width, self.n_chain)
+        row = (self.acc_w * q * q * self.c_width * l.stride ** 2
+               if q > 1 else 0)
         return 4 * (self.batch_block
                     * (self.acc_h * self.acc_w * self.out_c_pad
                        + self.ih * self.iw * self.c_width
                        + (self.blk_h * self.blk_w * self.out_c_pad
                           if self.residual else 0))
                     + l.kernel * l.kernel * self.fan_width
-                    * self.out_c_pad)
+                    * self.out_c_pad + row)
 
     @property
     def geometry(self):

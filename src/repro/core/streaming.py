@@ -129,7 +129,8 @@ def channel_norm_direct(y: jax.Array, gamma, beta) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 # single policy point for all Pallas launches (kernels import it too)
-from repro.kernels.common import pallas_interpret_default  # noqa: E402
+from repro.kernels.common import (megakernel_fold,  # noqa: E402
+                                  pallas_interpret_default)
 
 
 # partition_waves is pure on a hashable frozen TileProgram; memoizing it
@@ -917,7 +918,8 @@ def _outside_epilogues(graph: NetworkGraph) -> int:
 def graph_kernel_programs(
         graph: NetworkGraph, programs,
         vmem_budget: Optional[int] = _VMEM_DEFAULT,
-        batch: int = 1) -> "OrderedDict[str, KernelProgram]":
+        batch: int = 1,
+        quantized: bool = False) -> "OrderedDict[str, KernelProgram]":
     """The megakernel lowering of a whole graph, exactly as the graph
     forward replays it (per-node epilogue activation, fused residual
     adds and norms, fused pools, VMEM re-planning) — public so weight
@@ -925,9 +927,12 @@ def graph_kernel_programs(
     replays.
 
     With a tracer active, each node's lowering is a ``cat="kernel"``
-    span (attrs ``node``, ``epilogue``, ``vmem_bytes``, ``grid_steps``)
-    inside this call's ``lower`` span, whose ``norm_gelu_outside`` attr
-    counts the norm and GELU ops left outside every epilogue."""
+    span (attrs ``node``, ``epilogue``, ``fold``, ``vmem_bytes``,
+    ``grid_steps``) inside this call's ``lower`` span, whose
+    ``norm_gelu_outside`` attr counts the norm and GELU ops left outside
+    every epilogue. ``fold`` is how the node's per-layer launch folds
+    its input (``megakernel_fold``): the int8 launch (``quantized``)
+    never folds taps."""
     programs = _conv_keyed(graph, programs, "programs")
     epi = _graph_epilogues(graph)
     out = OrderedDict()
@@ -942,7 +947,8 @@ def graph_kernel_programs(
                     norm=e.norm is not None)
                 if sp is not None:
                     n_bb, _ = batch_grid(batch, kp.batch_block)
-                    sp.attrs.update(vmem_bytes=kp.vmem_bytes,
+                    sp.attrs.update(fold=megakernel_fold(kp, quantized),
+                                    vmem_bytes=kp.vmem_bytes,
                                     grid_steps=n_bb * kp.n_tiles
                                     * kp.n_chain)
         if top is not None:
@@ -973,7 +979,8 @@ def graph_chain_programs(graph: NetworkGraph, programs,
     programs = _conv_keyed(graph, programs, "programs")
     with _trace.span(f"lower_chains:{graph.name}", cat="lower",
                      batch=batch, quantized=quantized) as sp:
-        kprogs = graph_kernel_programs(graph, programs, vmem_budget, batch)
+        kprogs = graph_kernel_programs(graph, programs, vmem_budget, batch,
+                                       quantized)
         chains = fusible_chains(graph, kprogs, vmem_budget=vmem_budget,
                                 quantized=quantized)
         epi = _graph_epilogues(graph)
@@ -1126,7 +1133,7 @@ def graph_forward_fn(graph: NetworkGraph, programs,
             members = {name for c in chains for name in c.convs[1:]}
         else:
             kprogs = graph_kernel_programs(graph, programs, vmem_budget,
-                                           batch)
+                                           batch, quantized=True)
             chain_of, members, gkps = {}, set(), {}
         statics = {name: (qgraph.quants[name].pre_shift,
                           qgraph.quants[name].fan_chunk)
@@ -1193,6 +1200,9 @@ def graph_forward_fn(graph: NetworkGraph, programs,
         reg = _metrics.registry()
         reg.counter("graph.norms_fused").inc(n_fused)
         reg.counter("graph.norms_unfused").inc(n_norms - n_fused)
+        reg.counter("graph.taps_folded").inc(sum(
+            megakernel_fold(kp) == "taps" for name, kp in kprogs.items()
+            if name not in members and name not in gkps))
 
         def forward_mega(x, weights, ops):
             check_graph_input(graph, x)       # trace-time, per shape

@@ -109,6 +109,40 @@ def s2d_factor(layer, c_width: int) -> int:
     return 1
 
 
+def tap_fold_factor(layer, c_width: int, n_chain: int) -> int:
+    """Taps per side the fp32 per-layer launch folds into one
+    contraction after the stride fold (1 = none).
+
+    The stride fold leaves a narrow strided layer with q x q taps of
+    few channels each: the ResNet stem (7x7/2 over 3) ran 16 dots of 12
+    channels per output row. Where the stride fold applies, one grid
+    step reads every input channel (``n_chain`` 1) and the
+    ``q * q * c_eff`` folded channels fit two lane tiles, the kernel
+    sets each row's q * q tap loads side by side in lanes and runs one
+    dot over them (``conv_rows(fold=True)``): 192 channels for the
+    stem. The zero taps the stride fold adds stay zero weight rows; 147
+    and 192 channels pad to the same two lane tiles. Stride-1 layers
+    keep their taps, so the graph kernel, which chains them, sums in
+    the per-layer launch's order bit for bit.
+    """
+    s = s2d_factor(layer, c_width)
+    q = -(-layer.kernel // s)
+    if s > 1 and q > 1 and n_chain == 1 \
+            and q * q * c_width * s * s <= 2 * LANES:
+        return q
+    return 1
+
+
+def megakernel_fold(kp, quantized: bool = False) -> str:
+    """How a per-layer launch of ``kp`` folds its input: ``"taps"``
+    (``tap_fold_factor``, the fp32 launch only), ``"stride"``
+    (``s2d_factor``) or ``"none"``."""
+    l = kp.wave.program.layer
+    if not quantized and tap_fold_factor(l, kp.c_width, kp.n_chain) > 1:
+        return "taps"
+    return "stride" if s2d_factor(l, kp.c_width) > 1 else "none"
+
+
 def space_to_depth(x: jax.Array, s: int) -> jax.Array:
     """(B, H, W, C) -> (B, ceil(H/s), ceil(W/s), C*s*s), channel order
     (c, py, px): input pixel (s*i + py, s*j + px, c) lands at
@@ -137,9 +171,19 @@ def space_to_depth_weights(w: jax.Array, s: int) -> jax.Array:
     return w.reshape(ks, ks, C * s * s, O)
 
 
+def fold_taps_weights(w: jax.Array, q: int) -> jax.Array:
+    """(q, q, C, O) -> (1, 1, q*q*C, O): the taps' weights in the
+    order ``conv_rows(fold=True)`` lays the taps' loads out, (ky, kx,
+    c)."""
+    if q == 1:
+        return w
+    return w.reshape(1, 1, -1, w.shape[-1])
+
+
 def conv_rows(acc_ref, b, load, wtap, *, K: int, stride: int, acc_h: int,
               acc_w: int, cin: int, out_c: int, groups: int,
-              exact_chunk: "int | None" = None) -> None:
+              exact_chunk: "int | None" = None,
+              fold: bool = False) -> None:
     """Accumulate one grid step's convolution into ``acc_ref[b]``.
 
     The kernel body shared by every wave-replay kernel. Output row ``i``
@@ -157,6 +201,11 @@ def conv_rows(acc_ref, b, load, wtap, *, K: int, stride: int, acc_h: int,
     each dot covers at most that many channels, so every fp32 partial
     sum is an exact integer, and the parts sum in int32 — bit-exact
     against the int32 reference.
+
+    ``fold`` (fp32, ungrouped, ``tap_fold_factor``) concatenates a
+    row's K*K tap loads in lanes, order (ky, kx, c), and contracts
+    them in one dot with ``wtap(0, 0, 0, K*K*cin, 0, out_c)``, weights
+    laid out by ``fold_taps_weights``.
     """
     fan = cin // groups
     opg = out_c // groups
@@ -187,6 +236,11 @@ def conv_rows(acc_ref, b, load, wtap, *, K: int, stride: int, acc_h: int,
                 if exact:
                     a = a.astype(jnp.int32)
                 acc_ref[b, i, 0:acc_w, c0 * opg:(c0 + cw) * opg] += a
+        elif fold:
+            xt = jnp.concatenate([load(r0 + ky, kx, 0, cin)
+                                  for ky in range(K) for kx in range(K)], -1)
+            acc_ref[b, i, 0:acc_w, :] += dot(
+                xt, wtap(0, 0, 0, K * K * cin, 0, out_c))
         else:
             for g in range(groups):
                 a = None
@@ -419,16 +473,20 @@ def megakernel_vmem(kp, *, quantized: bool = False,
                     bb: "int | None" = None) -> LaunchVmem:
     """The VMEM a per-layer megakernel launch of ``kp`` holds, at
     ``bb`` images per grid step (default ``kp.batch_block``): fp32
-    (``kernels/wave_replay``) or int8 (``kernels/wave_replay_q``, whose
-    window and weights are staged as fp32 and whose psum bank is
-    int32). The launchers take their scratch shapes from here."""
+    (``kernels/wave_replay``, its taps folded where
+    ``tap_fold_factor`` says) or int8
+    (``kernels/wave_replay_q``, whose window and weights are staged as
+    fp32 and whose psum bank is int32). The launchers take their
+    scratch shapes from here."""
     bb = kp.batch_block if bb is None else bb
     _, k, stride, ih, full_w, c, fan = megakernel_geometry(kp)
+    q = 1 if quantized else tap_fold_factor(kp.wave.program.layer,
+                                            kp.c_width, kp.n_chain)
     oc = kp.out_c_pad
     io = jnp.int8 if quantized else jnp.float32
     acc_dt = jnp.int32 if quantized else jnp.float32
     out = ((bb, kp.blk_h, kp.out_w_pad, oc), io)
-    blocks = [((bb, ih, full_w, c), io), ((k, k, fan, oc), io)]
+    blocks = [((bb, ih, full_w, c), io), (weight_block(kp, q), io)]
     blocks += [((1, oc), acc_dt)] * (3 if quantized else 1) + [out]
     if kp.norm:
         blocks.append(((2, oc), jnp.float32))
@@ -443,8 +501,20 @@ def megakernel_vmem(kp, *, quantized: bool = False,
         scratch.append(pool_scratch(kp.acc_h, kp.acc_w, oc, acc_dt))
     if not quantized and stride > 1:
         scratch.append(staged)
-    return LaunchVmem(tuple(blocks), tuple(scratch),
-                      ((acc[1:], acc_dt),) * 3)
+    temps = ((acc[1:], acc_dt),) * 3
+    if q > 1:                 # one row's taps side by side in lanes
+        temps += (((kp.acc_w, q * q * c), jnp.float32),)
+    return LaunchVmem(tuple(blocks), tuple(scratch), temps)
+
+
+def weight_block(kp, q: int = 1) -> tuple:
+    """The weight block of a per-layer launch of ``kp``: the stride-
+    folded (K, K, fan, out_c_pad), or with its q x q taps folded into
+    one contraction (``tap_fold_factor``) (1, 1, q*q*fan, out_c_pad)."""
+    _, k, _, _, _, _, fan = megakernel_geometry(kp)
+    if q > 1:
+        return (1, 1, q * q * fan, kp.out_c_pad)
+    return (k, k, fan, kp.out_c_pad)
 
 
 def pool_max_subsampled(a: jax.Array, *, pool: int, stride: int,

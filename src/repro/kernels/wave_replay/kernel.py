@@ -36,11 +36,12 @@ from repro.core.schedule import (KERNEL_OP_COLS, OP_IY, OP_TX, OP_TY,
                                  OP_VC, OP_VR, KernelProgram, batch_grid)
 from repro.core.graph import NORM_EPS
 from repro.kernels.common import (activate, at_tile_col, channel_norm,
-                                  conv_rows, element_block, lane_load,
-                                  mask_tile, megakernel_geometry,
-                                  megakernel_vmem, pool_tile,
-                                  space_to_depth, space_to_depth_weights,
-                                  stage_lanes, strided)
+                                  conv_rows, element_block,
+                                  fold_taps_weights, lane_load, mask_tile,
+                                  megakernel_geometry, megakernel_vmem,
+                                  pool_tile, space_to_depth,
+                                  space_to_depth_weights, stage_lanes,
+                                  strided, tap_fold_factor, weight_block)
 
 
 def _replay_kernel(tbl_ref, x_ref, w_ref, b_ref, *refs,
@@ -48,7 +49,8 @@ def _replay_kernel(tbl_ref, x_ref, w_ref, b_ref, *refs,
                    acc_w: int, n_waves: int, pool: int, ps: int,
                    blk_h: int, blk_w: int, tiles_w: int, act,
                    norm: bool, n_out: int, fuse_pool: bool,
-                   residual: bool, groups: int, staged: bool):
+                   residual: bool, groups: int, staged: bool,
+                   fold: bool):
     """One grid step: batch block (program_id 0), tile t (id 1), chain
     position k (id 2). The batch axis is outermost, so each batch
     block's tiles replay their full partial-sum chains before the next
@@ -62,7 +64,8 @@ def _replay_kernel(tbl_ref, x_ref, w_ref, b_ref, *refs,
     channels, which with GELU runs one output row at a time so its
     temporaries stay a row wide; with ``fuse_pool`` the lane-tiled pool
     scratch; with ``staged`` (strided taps) the lane-tiled copy of one
-    image's window that strided loads read.
+    image's window that strided loads read. ``fold`` runs each row's
+    K*K taps as one dot (``tap_fold_factor``).
 
     The window ``x_ref`` spans the full buffer width; tile column ``j``
     starts its taps at the static column ``j * col_step``.
@@ -101,7 +104,7 @@ def _replay_kernel(tbl_ref, x_ref, w_ref, b_ref, *refs,
 
         conv_rows(acc_ref, b, load, wtap, K=K, stride=stride,
                   acc_h=acc_h, acc_w=acc_w, cin=cin, out_c=out_c,
-                  groups=groups)
+                  groups=groups, fold=fold)
         return carry
 
     def conv_col(j):
@@ -221,10 +224,13 @@ def wave_replay_raw(kp: KernelProgram, x: jax.Array, w: jax.Array,
             residual = jnp.pad(
                 residual, ((0, n_bb * bb - B), (0, 0), (0, 0), (0, 0)))
     # narrow strided layers fold the stride into channels (conv1:
-    # 227x227x3 -> 57x57x48), turning strided taps into plain ones
-    s, k_eff, stride, ih, full_w, c_eff, f_eff = megakernel_geometry(kp)
+    # 227x227x3 -> 57x57x48), turning strided taps into plain ones;
+    # those whose taps then fit two lane tiles run each row's taps as
+    # one dot (the ResNet stem: 16 taps of 12 channels -> 192)
+    s, k_eff, stride, ih, full_w, c_eff, _ = megakernel_geometry(kp)
+    q = tap_fold_factor(l, kp.c_width, kp.n_chain)
     x = space_to_depth(x, s)
-    w = space_to_depth_weights(w, s)
+    w = fold_taps_weights(space_to_depth_weights(w, s), q)
     vmem = megakernel_vmem(kp, bb=bb)
     in_specs = [
         # halo windows: table-driven element offsets along the rows
@@ -234,7 +240,7 @@ def wave_replay_raw(kp: KernelProgram, x: jax.Array, w: jax.Array,
                      lambda bi, t, k, tbl: (
                          bi * bb, tbl[k, t, OP_IY] // s, 0,
                          k * c_eff if kp.n_chain > 1 else 0)),
-        pl.BlockSpec((k_eff, k_eff, f_eff, kp.out_c_pad),
+        pl.BlockSpec(weight_block(kp, q),
                      lambda bi, t, k, tbl: (0, 0, k, 0)),
         pl.BlockSpec((1, kp.out_c_pad), lambda bi, t, k, tbl: (0, 0)),
     ]
@@ -267,7 +273,8 @@ def wave_replay_raw(kp: KernelProgram, x: jax.Array, w: jax.Array,
         n_waves=kp.n_chain, pool=kp.pool, ps=kp.pool_stride,
         blk_h=kp.blk_h, blk_w=kp.blk_w, tiles_w=kp.tiles_w, act=kp.act,
         norm=kp.norm, n_out=l.out_c, fuse_pool=kp.fuse_pool,
-        residual=kp.residual, groups=kp.groups, staged=stride > 1)
+        residual=kp.residual, groups=kp.groups, staged=stride > 1,
+        fold=q > 1)
     y = pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct(

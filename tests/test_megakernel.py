@@ -12,23 +12,30 @@ import pytest
 
 from repro.core.decomposition import (ALEXNET_STACK, ConvLayer, evaluate,
                                       plan_decomposition)
-from repro.core.model_zoo import network_graph
-from repro.core.schedule import (KERNEL_OP_COLS, OP_C0, OP_VC, OP_VR,
-                                 KernelProgram, compile_layer,
-                                 compile_network, lower_kernel_program,
-                                 partition_waves, validate_kernel_program)
-from repro.core.streaming import (compile_graph, conv2d_direct,
+from repro.core.model_zoo import (alexnet_graph, convnext_t_graph,
+                                  network_graph, resnet18_graph)
+from repro.core.schedule import (DEFAULT_VMEM_BUDGET, KERNEL_OP_COLS,
+                                 OP_C0, OP_VC, OP_VR, KernelProgram,
+                                 compile_layer, compile_network,
+                                 lower_kernel_program, partition_waves,
+                                 validate_kernel_program)
+from repro.core.streaming import (_graph_kernel_program, compile_graph,
+                                  conv2d_direct, graph_forward_fn,
                                   graph_kernel_programs, maxpool_direct,
                                   network_forward_fn, network_operands,
                                   plan_for_vmem, plan_graph,
                                   run_layer_interpreted,
                                   run_layer_megakernel, run_layer_streamed)
-from repro.kernels.common import VMEM_CAPACITY, megakernel_vmem, s2d_factor
+from repro.kernels.common import (VMEM_CAPACITY, LaunchVmem,
+                                  megakernel_fold, megakernel_geometry,
+                                  megakernel_vmem, s2d_factor)
 from repro.kernels.wave_replay import (expand_grouped, launch_count,
                                        reset_launch_count,
                                        wave_replay_layer, wave_replay_ref)
 from repro.kernels.wave_replay.kernel import wave_replay_raw
 from repro.launch.session import StreamingSession
+from repro.obs import Tracer, use_tracer
+from repro.obs import metrics as obs_metrics
 from repro.runtime.errors import BudgetExceeded
 
 try:
@@ -131,25 +138,42 @@ def test_megakernel_grouped_natural_layout():
     assert kp.fan_width == 4 and kp.w_in_kpad == 4
 
 
-@pytest.mark.parametrize("in_c,kernel,folded",
-                         [(3, 5, True), (40, 1, False), (40, 3, False)],
-                         ids=["narrow-folded", "projection-staged",
-                              "wide-staged"])
-def test_megakernel_strided_fold_or_staged(in_c, kernel, folded):
+@pytest.mark.parametrize(
+    "in_c,kernel,pool,batch,fold",
+    [(3, 5, 1, 2, "taps"), (40, 1, 1, 2, "none"), (40, 3, 1, 2, "none"),
+     (8, 5, 1, 2, "stride"), (3, 7, 3, 3, "taps")],
+    ids=["narrow-folded", "projection-staged", "wide-staged",
+         "stride-folded", "stem-taps-folded"])
+def test_megakernel_strided_fold_or_staged(in_c, kernel, pool, batch, fold):
     """Ungrouped strided layers fold the stride into channels only where
-    a step's folded channels fit one lane tile (a 3-channel stem); wider
-    ones (ResNet's 1x1 stride-2 projections) load strided taps from the
-    lane-tiled window, with no zero weights. Both match the direct
-    conv."""
-    layer = ConvLayer("s", 17, 17, in_c, 16, kernel, stride=2,
-                      pad=kernel // 2)
+    a step's folded channels fit one lane tile (a 3- or 8-channel
+    input); wider ones (ResNet's 1x1 stride-2 projections) load strided
+    taps from the lane-tiled window, with no zero weights. Where the
+    taps left after the stride fold fit two lane tiles of channels
+    (3 channels: 5x5/2 to 9 x 12 = 108, the 7x7/2 stem to 16 x 12 =
+    192), the fp32 launch runs each row's taps as one dot. The stem case
+    fuses its 3/2 max-pool and pads a ragged batch of 3 to two blocks
+    of 2. All match the direct conv."""
+    layer = ConvLayer("s", 29 if pool > 1 else 17, 29 if pool > 1 else 17,
+                      in_c, 16, kernel, stride=2, pad=kernel // 2,
+                      pool=pool, pool_stride=2 if pool > 1 else 0)
     plan = evaluate(layer, 2, 2, 1, 1)
-    kp = lower_kernel_program(_wave(layer, plan))
-    assert (s2d_factor(layer, kp.c_width) == 2) == folded
-    x = jax.random.normal(jax.random.key(4), (2, 17, 17, in_c))
+    kp = lower_kernel_program(_wave(layer, plan),
+                              act="relu" if pool > 1 else None,
+                              fuse_pool=pool > 1, vmem_budget=None,
+                              batch_block=2)
+    assert (s2d_factor(layer, kp.c_width) == 2) == (fold != "none")
+    assert megakernel_fold(kp) == fold
+    assert megakernel_fold(kp, quantized=True) == \
+        ("none" if fold == "none" else "stride")
+    x = jax.random.normal(jax.random.key(4),
+                          (batch, layer.in_h, layer.in_w, in_c))
     w, b = _weights(layer)
-    got = run_layer_streamed(layer, plan, x, w, b, mode="megakernel")
+    got = wave_replay_layer(kp, x, w, b)
     ref = conv2d_direct(x, w, 2, kernel // 2) + b
+    if pool > 1:
+        ref = maxpool_direct(jnp.maximum(ref, 0), pool, 2)
+    assert got.shape == ref.shape
     assert float(jnp.max(jnp.abs(got - ref))) < 1e-4
 
 
@@ -380,6 +404,92 @@ def test_launch_vmem_counts_the_tiled_footprint():
     with pytest.raises(BudgetExceeded, match="c1_1: the kernel holds"):
         jax.eval_shape(lambda x, w, b, t: wave_replay_raw(
             kp, x, w, b, t, interpret=False), *args, table)
+
+
+def _serving_kernel_programs(g, batch, quantized=False):
+    """The per-node programs a session lowers for ``g`` at ``batch``,
+    with each node's ``fold`` span attribute."""
+    t = Tracer()
+    with use_tracer(t):
+        kprogs = graph_kernel_programs(
+            g, compile_graph(g, plan_graph(g, 128 * 1024)), batch=batch,
+            quantized=quantized)
+    return kprogs, {s.attrs["node"]: s.attrs["fold"]
+                    for s in t.spans("kernel")}
+
+
+@pytest.mark.parametrize("net,first,stride_geometry", [
+    ("resnet18", "stem", (2, 4, 1, 114, 114, 12, 12)),
+    ("alexnet", "conv1", (4, 3, 1, 57, 57, 48, 48)),
+    ("convnext_t", "stem", (4, 1, 1, 56, 56, 48, 48)),
+])
+def test_tap_fold_engages_only_on_the_resnet_stem(net, first,
+                                                  stride_geometry):
+    """Of the three benchmark graphs at the serving batch 8, the fp32
+    launch folds the taps of ResNet-18's stem alone (16 taps of 12
+    channels after the stride fold: 192). AlexNet's conv1 (9 taps of
+    48: 432 channels) and ConvNeXt-T's stem (one tap after its 4x4/4
+    fold) keep the stride fold alone, at the geometry it gave before
+    the tap fold. The int8 launch never folds taps: the stem's int8
+    window stays the stride-folded one. The forward builder counts the
+    folded nodes in ``graph.taps_folded``."""
+    g = {"resnet18": lambda: resnet18_graph(224, 64),
+         "alexnet": alexnet_graph, "convnext_t": convnext_t_graph}[net]()
+    progs = compile_graph(g, plan_graph(g, 128 * 1024))
+    with obs_metrics.use_registry(obs_metrics.MetricsRegistry()) as reg:
+        graph_forward_fn(g, progs, mode="megakernel", batch=8)
+        assert reg.counter("graph.taps_folded").value == \
+            (net == "resnet18")
+    kprogs, folds = _serving_kernel_programs(g, 8)
+    assert [n for n, f in folds.items() if f == "taps"] == \
+        (["stem"] if net == "resnet18" else [])
+    kp = kprogs[first]
+    assert folds[first] == ("taps" if net == "resnet18" else "stride")
+    assert megakernel_geometry(kp) == stride_geometry
+    _, k, _, _, _, _, fan = stride_geometry
+    assert megakernel_vmem(kp).blocks[1] == (
+        ((1, 1, k * k * fan, 64) if net == "resnet18"
+         else (k, k, fan, kp.out_c_pad)), jnp.float32)
+    # the planner's program is the one the stride fold alone gave
+    assert (kp.n_tiles, kp.n_chain, kp.batch_block, kp.c_width) == \
+        {"resnet18": (1, 1, 2, 3), "alexnet": (1, 1, 4, 3),
+         "convnext_t": (1, 1, 4, 3)}[net]
+    q_kprogs, q_folds = _serving_kernel_programs(g, 8, quantized=True)
+    assert "taps" not in q_folds.values()
+    assert q_folds[first] == "stride"
+    window = megakernel_vmem(q_kprogs[first], quantized=True).blocks[0]
+    bb = q_kprogs[first].batch_block
+    assert window == ((bb,) + stride_geometry[3:6], jnp.int8)
+
+
+@pytest.mark.parametrize("batch,bb", [(1, 1), (8, 2)])
+def test_folded_stem_vmem_counts_256_lanes(batch, bb):
+    """The folded stem's row operand, its 16 taps of 12 channels side by
+    side in lanes (111, 192), is counted at two lane tiles (256 lanes)
+    in the launch's VMEM, beside the stride-folded window and the
+    (192, 64) weights, and at 192 channels in the planner's
+    ``plan_bytes``; the batch block the clamp picks gives a launch
+    under a v5e core's VMEM, with a scoped limit that covers it (CPU
+    count). The node alone, lowered as the graph forward lowers it."""
+    layer = resnet18_graph(224, 64).node("stem").layer
+    kp = _graph_kernel_program(
+        compile_layer(layer, plan_decomposition(layer, 128 * 1024)),
+        "relu", False, DEFAULT_VMEM_BUDGET, batch)
+    assert kp.batch_block == bb and megakernel_fold(kp) == "taps"
+    vmem = megakernel_vmem(kp)
+    assert vmem.blocks[:2] == (((bb, 114, 114, 12), jnp.float32),
+                               ((1, 1, 192, 64), jnp.float32))
+    row = ((111, 192), jnp.float32)
+    assert vmem.temps[-1] == row
+    assert LaunchVmem((), (), (row,)).bytes == 112 * 256 * 4
+    unfolded = megakernel_vmem(kp, quantized=True)
+    assert len(vmem.temps) == len(unfolded.temps) + 1
+    # the planner counts the row operand at 192 channels, batch-shared
+    assert kp.plan_bytes == 4 * (bb * (111 * 111 * 64 + 227 * 227 * 3)
+                                 + 7 * 7 * 3 * 64 + 111 * 192)
+    assert kp.vmem_bytes == vmem.bytes < VMEM_CAPACITY
+    limit = vmem.compiler_params("stem", interpret=False).vmem_limit_bytes
+    assert kp.vmem_bytes <= limit <= VMEM_CAPACITY
 
 
 def test_plan_for_vmem_prefers_fewest_steps():

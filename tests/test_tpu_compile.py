@@ -6,8 +6,9 @@ scoped VMEM than a kernel states), at no chip time.
 Real widths: every AlexNet layer at the plans ``plan_graph`` gives (at
 batch 1 and at the serving batch 8, whose batch blocks change the
 kernel's shapes), the AlexNet fused chains, the int8 megakernel on an
-ungrouped strided and a grouped layer, a ResNet-18 residual layer, a
-MobileNet depthwise layer, and ConvNeXt-T's norm and GELU epilogues.
+ungrouped strided and a grouped layer, a ResNet-18 residual layer, the
+ResNet-18 stem with its taps folded, a MobileNet depthwise layer, and
+ConvNeXt-T's norm and GELU epilogues.
 Nothing runs, so results are checked by the interpret-mode tests; this
 file checks that each kernel compiles.
 
@@ -26,6 +27,7 @@ from repro.core.schedule import DEFAULT_VMEM_BUDGET, compile_layer
 from repro.core.streaming import (_graph_epilogues, _graph_kernel_program,
                                   compile_graph, graph_chain_programs,
                                   graph_kernel_programs, plan_graph)
+from repro.kernels.common import megakernel_fold
 from repro.kernels.wave_replay.graph import (stacked_shapes,
                                              wave_replay_graph_raw)
 from repro.kernels.wave_replay.kernel import wave_replay_raw
@@ -152,6 +154,20 @@ def test_resnet18_strided_layer_compiles(one_chip, layer):
     kp = graph_kernel_programs(g, progs, batch=8)[layer]
     assert kp.wave.program.layer.stride == 2 and kp.c_width == 64
     _compile_mega(kp, 8, one_chip)
+
+
+@pytest.mark.parametrize("batch", [1, 8])
+def test_resnet18_stem_compiles(one_chip, batch):
+    """The 7x7/2 stem over 3 channels, its 16 stride-folded taps folded
+    into one 192-channel contraction, with the 3/2 pool fused, at both
+    serving batches (the node alone, lowered as the graph forward
+    lowers it)."""
+    layer = network_graph("resnet18").node("stem").layer
+    prog = compile_layer(layer, plan_decomposition(layer, SRAM))
+    kp = _graph_kernel_program(prog, "relu", False, DEFAULT_VMEM_BUDGET,
+                               batch)
+    assert megakernel_fold(kp) == "taps" and kp.fuse_pool
+    _compile_mega(kp, batch, one_chip)
 
 
 def test_mobilenet_depthwise_layer_compiles(one_chip):
